@@ -18,9 +18,7 @@ import jax as _jax
 # so when the launcher declared a multi-process world via the JAX_* env
 # contract, form it now — before the imports below initialize XLA.
 from ._bootstrap import maybe_init_jax_distributed as _mijd
-from ._bootstrap import shim_jax_compat as _sjc
 
-_sjc()
 _mijd()
 
 from .framework import flags as _flags
@@ -35,16 +33,31 @@ from .framework import flags as _flags
 # cpu. Unset JAX_PLATFORMS keeps the cache: that is the normal TPU
 # deployment (jax auto-detects the chip), exactly the case the cache
 # exists to amortize.
+#
+# Placement (one rule, _paths.jax_cache_dir): where
+# JAX_COMPILATION_CACHE_DIR is set the program sets NO directory in code
+# — jax reads the variable itself, so a launcher can place the cache
+# from outside; otherwise it is the checkout's fixed .cache/jax (the
+# path is part of the cache key: never ~, a temporary name, a pid or a
+# time).
 _plat = _os.environ.get("JAX_PLATFORMS", "").lower()
 if _flags.flag_value("use_persistent_compilation_cache") and \
         "cpu" not in _plat:
-    try:
+    if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         _cache_dir = _flags.flag_value("compilation_cache_dir")
         _os.makedirs(_cache_dir, exist_ok=True)
         _jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        _jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass
+    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    # A Pallas kernel's Mosaic payload is serialized WITH its MLIR
+    # locations, and jax's cache-key canonicalization cannot strip what
+    # sits inside a custom call's backend_config. With full Python
+    # tracebacks in those locations (jax's default, ten frames), moving
+    # a line in ANY caller of a kernel — a model file, a driver script —
+    # re-keys every program that contains one: a 327 s train-step
+    # compile missed a warm cache for exactly that reason (PERF.md
+    # "Bring-up on the chip"). Innermost frame only keeps the key to
+    # the kernel's own source.
+    _jax.config.update("jax_include_full_tracebacks_in_locations", False)
 
 from .core.tensor import Tensor, Parameter  # noqa: F401,E402
 from .core.tensor_types import (  # noqa: F401,E402

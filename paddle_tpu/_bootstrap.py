@@ -15,52 +15,6 @@ import warnings
 _formed = False
 
 
-def shim_jax_compat() -> None:
-    """Bridge jax API renames so one tree runs on every jax this repo
-    meets (the build image pins 0.4.x; dev trees run newer). Today:
-    ``jax.shard_map`` graduated from ``jax.experimental.shard_map`` —
-    on older jax, surface the experimental symbol at its new home so
-    both ``jax.shard_map(...)`` and ``from jax import shard_map`` work.
-    """
-    import jax
-    if not hasattr(jax, "shard_map"):
-        try:
-            from jax.experimental.shard_map import shard_map as _sm
-        except ImportError:
-            _sm = None  # neither spelling exists; use sites fail loudly
-        if _sm is not None:
-            def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                          axis_names=None, check_vma=None, **kw):
-                """New-API adapter over experimental shard_map:
-                `axis_names` (the manual axes) maps to its complement
-                `auto`, `check_vma` to `check_rep`."""
-                if check_vma is not None and "check_rep" not in kw:
-                    kw["check_rep"] = check_vma
-                if axis_names is not None and mesh is not None \
-                        and "auto" not in kw:
-                    auto = frozenset(mesh.axis_names) - \
-                        frozenset(axis_names)
-                    if auto:
-                        kw["auto"] = auto
-                return _sm(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
-            jax.shard_map = shard_map
-    # jax.export: on 0.4.x the submodule exists but plain attribute
-    # access trips the deprecation registry until it is imported
-    try:
-        import jax.export  # noqa: F401
-    except ImportError:
-        pass
-    # pallas-TPU: CompilerParams was named TPUCompilerParams on 0.4.x
-    try:
-        from jax.experimental.pallas import tpu as _pltpu
-        if not hasattr(_pltpu, "CompilerParams") and \
-                hasattr(_pltpu, "TPUCompilerParams"):
-            _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-    except ImportError:
-        pass
-
-
 def maybe_init_jax_distributed(strict: bool = False) -> bool:
     """Form the jax.distributed world if the env declares one.
 
@@ -87,13 +41,6 @@ def maybe_init_jax_distributed(strict: bool = False) -> bool:
             "python -m paddle_tpu.distributed.launch, or export the "
             "full JAX_* contract")
     import jax
-    try:
-        # jax 0.4.x ships CPU cross-process collectives but defaults to
-        # the unimplemented stub — newer jax defaults to gloo; select it
-        # explicitly where the knob exists so multi-host-on-CPU works
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
     try:
         jax.distributed.initialize(coordinator_address=coord,
                                    num_processes=n,

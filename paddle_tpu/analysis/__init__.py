@@ -37,7 +37,7 @@ systematic way to inspect it BEFORE it reaches hardware:
   `tools/tpuprof.py` gates CI on a noise-tolerant dispatch-time
   ratchet + measured anchors in tools/tpuprof_baseline.json.
 - report:        the shared --json artifact + terminal-record contract
-  the CLIs emit (tools/_have_result.py predicate).
+  the CLIs emit (one terminal JSON record).
 
 CLIs: python tools/tpulint.py [--update-baseline] [--json out.json]
       python tools/tpucost.py [--update-baseline] [--json out.json]

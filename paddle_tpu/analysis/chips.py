@@ -11,9 +11,10 @@ tpucost roofline.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict
+from typing import Dict, Tuple
 
-__all__ = ["ChipSpec", "CHIP_SPECS", "DEFAULT_CHIP"]
+__all__ = ["ChipSpec", "CHIP_SPECS", "DEFAULT_CHIP",
+           "chip_for_device_kind"]
 
 
 @dataclass(frozen=True)
@@ -25,14 +26,34 @@ class ChipSpec:
     hbm_bandwidth: float
     hbm_capacity: float
     ici_gbps: float = 0.0    # aggregate inter-chip Gbit/s (0 = n/a)
+    # lower-cased substrings of jax's ``device.device_kind`` naming it
+    device_kinds: Tuple[str, ...] = ()
 
 
 CHIP_SPECS: Dict[str, ChipSpec] = {
-    # v5-lite (v5e): the chip the landed 33.6%-MFU 125M anchor ran on
+    # v5-lite (v5e): the installed chip (Google Cloud "TPU v5e" docs)
     "v5lite": ChipSpec("v5lite", peak_flops=197e12, hbm_bandwidth=819e9,
-                       hbm_capacity=16 * 2**30, ici_gbps=1600),
+                       hbm_capacity=16 * 2**30, ici_gbps=1600,
+                       device_kinds=("v5 lite", "v5e")),
     # v5p: the north-star pod chip (tools/northstar_model.py)
     "v5p": ChipSpec("v5p", peak_flops=459e12, hbm_bandwidth=2765e9,
-                    hbm_capacity=95 * 2**30, ici_gbps=4800),
+                    hbm_capacity=95 * 2**30, ici_gbps=4800,
+                    device_kinds=("v5p",)),
 }
+# the target the STATIC cost model (tpucost, northstar) prices when no
+# device exists; a measurement on a live device never defaults — it
+# resolves its device through chip_for_device_kind, which raises
 DEFAULT_CHIP = "v5lite"
+
+
+def chip_for_device_kind(kind: str) -> ChipSpec:
+    """The table row for a live device's ``device_kind``. A device that
+    is not in the table is an error, never a default: an MFU computed
+    against an assumed peak is a wrong number with a right name."""
+    k = kind.lower()
+    for spec in CHIP_SPECS.values():
+        if any(sub in k for sub in spec.device_kinds):
+            return spec
+    raise KeyError(
+        f"device_kind {kind!r} is not in analysis/chips.py CHIP_SPECS — "
+        "add its published peaks (with source) before measuring on it")

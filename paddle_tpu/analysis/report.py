@@ -3,16 +3,14 @@
 tools/tpulint.py and tools/tpucost.py share one output contract:
 
 - `--json <path>` writes the FULL findings/inventory record atomically
-  (.part + rename, so a mid-write kill never leaves a truncated file
-  that tools/_have_result.py would have to reject byte-wise);
-- the LAST stdout line is always one JSON record — the
-  tools/_have_result.py terminal-record predicate tpu_suite2.sh's
-  self-skip and tpu_watch2.sh's give-up logic both key on. A failing
+  (.part + rename, so a mid-write kill never leaves a truncated
+  file);
+- the LAST stdout line is always one terminal JSON record. A failing
   gate is a GOOD record with "gate": "fail" (the measurement landed;
   CI failing is the point), an analyzer crash is {"error": ...}.
 
-One definition here instead of a copy per CLI — the suite/watcher
-protocol only works if every tool agrees on what a landed record is.
+One definition here instead of a copy per CLI — callers can only gate
+on the artifact if every tool agrees on what a landed record is.
 """
 from __future__ import annotations
 

@@ -35,7 +35,7 @@ across heterogeneous hosts.
 Env knobs:
   PADDLE_TPU_EXEC_STORE      1|0 — enable the store (default 1)
   PADDLE_TPU_EXEC_STORE_DIR  directory (default
-                             ~/.cache/paddle_tpu_exec_store)
+                             <checkout>/.cache/exec_store)
 """
 from __future__ import annotations
 
@@ -48,6 +48,7 @@ import warnings
 from dataclasses import dataclass
 from typing import Any, List, Optional, Tuple
 
+from .. import _paths
 from ..obs import locks as _locks
 
 __all__ = ["ExecutableStore", "StoreEntry", "default_store",
@@ -94,7 +95,7 @@ class ExecutableStore:
         if root is None:
             root = os.environ.get(
                 "PADDLE_TPU_EXEC_STORE_DIR",
-                os.path.expanduser("~/.cache/paddle_tpu_exec_store"))
+                _paths.cache_path("exec_store"))
         self.root = root
         if enabled is None:
             from ..framework.env import bool_env
@@ -310,6 +311,23 @@ def _computation_hash(lowered) -> str:
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
+def _virtual_cpu_mesh() -> bool:
+    """True in a process on several XLA:CPU devices (the virtual mesh
+    the tests and CPU tools run on). XLA:CPU compiles such a process's
+    programs — single-device ones included — with pseudo-features the
+    loader does not accept back (the cpu_aot_loader hazard
+    paddle_tpu/__init__.py documents), and on the installed jax 0.9.0
+    loading one does not crash, it HANGS: every test of
+    tests/test_tp_engine.py whose engine matched a stored entry sat out
+    its time limit, which alone kept the tier-1 run from reaching its
+    end. There the process-wide DEFAULT store is bypassed — compile
+    every time (a store passed explicitly is still honoured). A
+    one-device CPU process (replica children) and every TPU process
+    store and load as ever."""
+    import jax
+    return _backend_platform() == "cpu" and jax.device_count() > 1
+
+
 def aot_compile(name: str, fn, args: tuple,
                 store: Optional[ExecutableStore] = None,
                 log_record: Optional[dict] = None,
@@ -326,7 +344,12 @@ def aot_compile(name: str, fn, args: tuple,
     from . import counters
     from .registry import donation_spec, signature_hash
     counters.install()
+    # the process-wide default store is what the virtual CPU mesh
+    # bypasses; a store the caller passed explicitly is honoured
+    use_store = (store.enabled if store is not None
+                 else not _virtual_cpu_mesh())
     store = store if store is not None else default_store()
+    use_store = use_store and store.enabled
     rec = log_record if log_record is not None else {}
     sig = signature_hash(args, static_key)
     rec.setdefault("name", name)
@@ -345,7 +368,7 @@ def aot_compile(name: str, fn, args: tuple,
 
     lowered = None
     donation: Tuple[int, ...] = ()
-    if store.enabled:
+    if use_store:
         # donation is part of the key but needs args_info — one cheap
         # trace+lower (no XLA compile) recovers it; the big cost this
         # store kills is the COMPILE, not the trace
@@ -372,7 +395,7 @@ def aot_compile(name: str, fn, args: tuple,
     rec["compile_s"] = round(time.perf_counter() - t1, 4)
     rec["xla_compiles"] = trk.xla_compiles
     rec["persistent_cache_hits"] = trk.persistent_cache_hits
-    saved = store.save(name, sig, donation, compiled)
+    saved = use_store and store.save(name, sig, donation, compiled)
     rec["source"] = "compiled" if saved else "compiled-unstored"
     rec["total_s"] = round(time.perf_counter() - t0, 4)
     return AotProgram(compiled, fn)

@@ -49,9 +49,6 @@ class CostModel:
         raw = [a.value if hasattr(a, "value") else a for a in example_args]
         compiled = jax.jit(lambda *xs: fn(*xs)).lower(*raw).compile()
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):
-            # jax 0.4.x returns [per-partition dict]; newer returns dict
-            cost = cost[0] if cost else {}
         t0 = time.perf_counter()
         out = compiled(*raw)
         jax.block_until_ready(out)

@@ -5,9 +5,11 @@ from __future__ import annotations
 import hashlib
 import os
 
+from .. import _paths
+
 __all__ = ["DATA_HOME", "md5file", "download"]
 
-DATA_HOME = os.path.expanduser("~/.cache/paddle_tpu/dataset")
+DATA_HOME = _paths.cache_path("dataset")
 
 
 def md5file(fname: str) -> str:

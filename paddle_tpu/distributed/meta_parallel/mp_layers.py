@@ -210,7 +210,7 @@ class RowParallelLinear(Layer):
             in_specs = (P(*([None] * (nd - 1) + ["mp"])), P("mp", None))
             y = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                               out_specs=P(*([None] * nd)),
-                              check_rep=False)(xr, wr)
+                              check_vma=False)(xr, wr)
             if maybe_b:
                 y = y + maybe_b[0]
             return y

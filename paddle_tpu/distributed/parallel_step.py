@@ -633,7 +633,7 @@ class ParallelTrainStep:
                 body, mesh=mesh,
                 in_specs=(param_specs, P(), P()) + batch_specs,
                 out_specs=(P(), P(), grad_specs),
-                check_rep=False)
+                check_vma=False)
             return mapped(params, buffers, rng_key, *batch)
 
         return fwd_bwd

@@ -215,7 +215,7 @@ def _rs_program(axis: str, mesh, n: int, dim: int, block: int):
                                    block)[None]
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis),),
-                       out_specs=P(axis), check_rep=False)
+                       out_specs=P(axis), check_vma=False)
     return jax.jit(fn)
 
 
@@ -225,7 +225,7 @@ def _ag_program(axis: str, mesh, n: int, dim: int, block: int):
         return body_all_gather(x[0], axis, n, dim, "int8", block)[None]
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis),),
-                       out_specs=P(axis), check_rep=False)
+                       out_specs=P(axis), check_vma=False)
     return jax.jit(fn)
 
 

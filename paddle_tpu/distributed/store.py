@@ -19,13 +19,14 @@ import subprocess
 import threading
 from typing import Optional
 
+from .. import _paths
 from . import resilience as _resil
 
 __all__ = ["TCPStore", "build_native_store"]
 
 _NATIVE_SRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native", "tcp_store.cc")
-_CACHE_DIR = os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu")
+_CACHE_DIR = _paths.cache_path("native")
 _SO_PATH = os.path.join(_CACHE_DIR, "libtcp_store.so")
 
 _lib = None

@@ -11,6 +11,8 @@ import os
 import threading
 from typing import Any, Callable, Dict, Optional
 
+from .. import _paths
+
 _lock = threading.Lock()
 
 
@@ -88,6 +90,7 @@ define_flag("benchmark", False, "Synchronize after each op for timing.")
 define_flag("cudnn_deterministic", False, "Deterministic kernels (XLA flag passthrough).")
 define_flag("use_persistent_compilation_cache", True,
             "Enable jax persistent compilation cache.")
-define_flag("compilation_cache_dir", os.path.expanduser("~/.cache/paddle_tpu_xla"),
-            "Persistent XLA compilation cache directory.")
+define_flag("compilation_cache_dir", _paths.cache_path("jax"),
+            "Persistent XLA compilation cache directory when "
+            "JAX_COMPILATION_CACHE_DIR is unset (paddle_tpu/_paths.py).")
 define_flag("eager_log_level", 0, "Verbosity of eager runtime logging.")

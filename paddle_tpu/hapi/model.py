@@ -169,7 +169,7 @@ class Model:
             # make one full storm)
             return [LazyLoss(LossWindow(float("nan")))]
         # fault site: the step wedges AFTER dispatch — the loss fetch
-        # hangs (wedged device/tunnel); under a StepWatchdog deadline
+        # hangs (wedged device); under a StepWatchdog deadline
         # this surfaces as StepTimeout, state already advanced
         _resil.maybe_inject("step_hang")
         return [LazyLoss(LossWindow(loss.value))]

@@ -12,11 +12,13 @@ import subprocess
 import sysconfig
 from typing import Optional
 
+from .. import _paths
+
 __all__ = ["build_c_api", "c_api_path"]
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native", "c_api.cc")
-_CACHE_DIR = os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu")
+_CACHE_DIR = _paths.cache_path("native")
 _SO = os.path.join(_CACHE_DIR, "libpaddle_capi.so")
 
 

@@ -195,8 +195,28 @@ def single_device_child_env(platform: str = "cpu",
     flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
              if not f.startswith("--xla_force_host_platform_device_count")]
     if tp > 1:
+        _require_virtual_platform(
+            platform, f"a tp={tp} replica child")
         flags.append(f"--xla_force_host_platform_device_count={tp}")
     return {"JAX_PLATFORMS": platform, "XLA_FLAGS": " ".join(flags)}
+
+
+def _require_virtual_platform(platform: str, what: str) -> None:
+    """Chip placement for replica children exists only for the CPU
+    platform's virtual devices (--xla_force_host_platform_device_count,
+    which means nothing to a TPU). On a TPU host every child would open
+    ALL local chips — the second child fails or hangs on the first
+    one's lock, and a tp>1 child would get whatever it finds — so any
+    layout but ONE single-chip replica on a one-chip host raises
+    instead of running wrong (ROADMAP.md queue 2 item 6: per-child
+    chip assignment)."""
+    if "cpu" not in platform.lower():
+        raise NotImplementedError(
+            f"{what} on platform {platform!r}: assigning TPU chips to "
+            "replica children is not implemented (ROADMAP.md queue 2 "
+            "item 6) — run one tp=1 replica per one-chip host, or "
+            "drive all local chips from one process "
+            "(ContinuousBatchingEngine(model, tp=N))")
 
 
 # ---------------------------------------------------------------------------
@@ -1275,9 +1295,22 @@ class Router:
         # Tier-private by default (only this tier's own single-device
         # entries can ever land in it — the multi-device reload hazard
         # tests/conftest.py documents cannot arise); "" disables.
-        self.jax_cache_dir = (jax_cache_dir if jax_cache_dir is not None
-                              else os.path.join(self.workdir,
-                                                "xla_cache"))
+        # On a TPU tier the children follow the package's one placement
+        # rule instead (paddle_tpu/_paths.jax_cache_dir:
+        # JAX_COMPILATION_CACHE_DIR if set, else the checkout's fixed
+        # .cache/jax) — a directory under a temporary workdir would
+        # never hit on the next run.
+        child_plat = self.spec.env.get(
+            "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", ""))
+        if jax_cache_dir is None:
+            jax_cache_dir = (os.path.join(self.workdir, "xla_cache")
+                             if "cpu" in child_plat.lower() else "")
+        self.jax_cache_dir = jax_cache_dir
+        if self.max_replicas > 1 or self.spec.tp > 1:
+            _require_virtual_platform(
+                child_plat or "(jax default)",
+                f"up to {self.max_replicas} replica(s) at tp="
+                f"{self.spec.tp}")
 
         self._lock = _obs.make_rlock("router.lock")
         self._replicas: List[Replica] = []

@@ -257,6 +257,15 @@ class PredictorServer:
         self._warm_error = None
         self._warmup_thread = None
         self._started = time.monotonic()
+        # the device this process serves from, as jax reports it — on
+        # /healthz so a deployment can see a replica that came up on the
+        # wrong platform (a server always holds a built model, so the
+        # backend is already initialised here)
+        import jax
+        dev = jax.devices()[0]
+        self._device = {"platform": dev.platform,
+                        "kind": dev.device_kind,
+                        "count": len(jax.devices())}
         self.httpd = ThreadingHTTPServer((host, port),
                                          self._make_handler())
         self.host, self.port = self.httpd.server_address[:2]
@@ -330,7 +339,8 @@ class PredictorServer:
                 "inflight": self.inflight(),
                 "draining": draining,
                 "max_queue": self.max_queue,
-                "failure_streak": self._failure_streak}
+                "failure_streak": self._failure_streak,
+                "device": self._device}
         try:
             from ..compilation import log as _clog
             body["compilation"] = _clog.summary()

@@ -14,11 +14,13 @@ import subprocess
 import threading
 from typing import Optional
 
+from .. import _paths
+
 __all__ = ["ShmRing", "build_native_ring", "ring_available"]
 
 _NATIVE_SRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native", "shm_ring.cc")
-_CACHE_DIR = os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu")
+_CACHE_DIR = _paths.cache_path("native")
 _SO_PATH = os.path.join(_CACHE_DIR, "libshm_ring.so")
 
 _lib = None

@@ -45,64 +45,76 @@ __all__ = ["fused_slot_write", "fused_paged_write"]
 
 # ------------------------------------------------------------ slot form
 
+# rows of L one gridded program holds in VMEM (cache block
+# [1, _L_BLOCK, nkv, hd]: 1 MiB at nkv=16, hd=128 bf16; in + out, double
+# buffered = 4 MiB, inside every TPU generation's scoped-VMEM default)
+_L_BLOCK = 256
+
+
+def _blend_rows(cache, rows, pos, l0):
+    """The one slot-write body: blend ``rows`` ([b, 1, ...]) into the
+    ``cache`` block ([b, l, ...], holding global positions l0..l0+l) at
+    position ``pos`` (scalar, or [b, 1, ...] broadcastable). The hit
+    mask is a FULL-RANK iota compare — Mosaic cannot lay out a
+    rank-changing ``hit[..., None, None]`` broadcast, and int8 blocks
+    select in int32 (no packed-int8 select on the VPU)."""
+    hit = l0 + lax.broadcasted_iota(jnp.int32, cache.shape, 1) == pos
+    if cache.dtype == jnp.int8:
+        return jnp.where(hit, rows.astype(jnp.int32),
+                         cache.astype(jnp.int32)).astype(jnp.int8)
+    return jnp.where(hit, rows.astype(cache.dtype), cache)
+
+
 def _slot_kernel_whole(pos_ref, cache_ref, rows_ref, out_ref):
-    """Grid-free body (interpret / CPU): blend every row's write in one
-    whole-array select — the mask is computed in-kernel, never
-    materialized to HBM."""
-    B, L = cache_ref.shape[0], cache_ref.shape[1]
-    l_ids = lax.broadcasted_iota(jnp.int32, (B, L), 1)
-    hit = l_ids == pos_ref[:][:, None]                  # [B, L]
-    extra = (None,) * (len(cache_ref.shape) - 2)
-    out_ref[...] = jnp.where(hit[(...,) + extra],
-                             rows_ref[...].astype(out_ref.dtype),
-                             cache_ref[...])
+    """Grid-free wrapper (interpret / CPU): the whole array is one
+    block, every row's position broadcast down the batch axis."""
+    nd = len(cache_ref.shape)
+    pos = pos_ref[:].reshape((cache_ref.shape[0],) + (1,) * (nd - 1))
+    out_ref[...] = _blend_rows(cache_ref[...], rows_ref[...], pos, 0)
 
 
-def _slot_kernel_row(pos_ref, cache_ref, rows_ref, out_ref):
-    """Gridded body (TPU): one program per batch row; the row's cache
-    block [1, L, ...] sits in VMEM, the single new row blends at
-    pos[b]."""
-    b = pl.program_id(0)
-    L = cache_ref.shape[1]
-    l_ids = lax.broadcasted_iota(jnp.int32, (1, L), 1)
-    hit = l_ids == pos_ref[b]                           # [1, L]
-    extra = (None,) * (len(cache_ref.shape) - 2)
-    out_ref[...] = jnp.where(hit[(...,) + extra],
-                             rows_ref[...].astype(out_ref.dtype),
-                             cache_ref[...])
+def _slot_kernel_block(pos_ref, cache_ref, rows_ref, out_ref):
+    """Gridded wrapper (TPU): one program per (batch row, L-block); the
+    block [1, lb, ...] sits in VMEM, pos[b] is an SMEM scalar."""
+    b, j = pl.program_id(0), pl.program_id(1)
+    out_ref[...] = _blend_rows(cache_ref[...], rows_ref[...], pos_ref[b],
+                               j * cache_ref.shape[1])
 
 
-def fused_slot_write(cache, rows, pos, *, interpret: bool = False):
+def fused_slot_write(cache, rows, pos, *, interpret: bool = False,
+                     gridded: bool | None = None):
     """One-kernel S=1 slot-cache write: ``cache[b, pos[b]] = rows[b, 0]``.
 
     cache: [B, L, ...] (the [B, L, nkv, hd] data array, or the
     [B, L, nkv] int8-cache scale plane); rows: [B, 1, ...] matching;
     pos: [B] int32. The cache operand is aliased to the output
-    (in-place blend — donation flows through).
+    (in-place blend — donation flows through). ``gridded`` defaults to
+    ``not interpret``; tests pass ``interpret=True, gridded=True`` to
+    run the chip's blocked form through the interpreter.
     """
     B, L = cache.shape[0], cache.shape[1]
     pos = jnp.asarray(pos, jnp.int32)
-    if interpret:
+    if gridded is None:
+        gridded = not interpret
+    if not gridded:
         grid = ()
-        in_specs = [pl.BlockSpec(memory_space=pltpu.ANY),
-                    pl.BlockSpec(memory_space=pltpu.ANY)]
-        out_specs = pl.BlockSpec(memory_space=pltpu.ANY)
+        in_specs = [pl.BlockSpec(memory_space=pl.ANY),
+                    pl.BlockSpec(memory_space=pl.ANY)]
+        out_specs = pl.BlockSpec(memory_space=pl.ANY)
         kernel = _slot_kernel_whole
-        compiler_params = None
+        kw = {}
     else:
-        blk = (1, L) + cache.shape[2:]
+        lb = min(L, _L_BLOCK)
+        tail = (0,) * (cache.ndim - 2)
+        blk = (1, lb) + cache.shape[2:]
         rblk = (1, 1) + rows.shape[2:]
-        grid = (B,)
-        nd = cache.ndim
-        idx = lambda b, *_: (b,) + (0,) * (nd - 1)  # noqa: E731
-        in_specs = [pl.BlockSpec(blk, idx), pl.BlockSpec(rblk, idx)]
-        out_specs = pl.BlockSpec(blk, idx)
-        kernel = _slot_kernel_row
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel",))
-    kw = {}
-    if compiler_params is not None:
-        kw["compiler_params"] = compiler_params
+        grid = (B, pl.cdiv(L, lb))
+        in_specs = [pl.BlockSpec(blk, lambda b, j, *_: (b, j) + tail),
+                    pl.BlockSpec(rblk, lambda b, j, *_: (b, 0) + tail)]
+        out_specs = pl.BlockSpec(blk, lambda b, j, *_: (b, j) + tail)
+        kernel = _slot_kernel_block
+        kw = {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"))}
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -149,44 +161,50 @@ def _paged_kernel_page(phys_ref, off_ref, valid_ref, pages_ref,
     blends its row at its offset. Exclusivity (at most one writer per
     (page, offset)) is the caller's copy-on-write invariant."""
     p = pl.program_id(0)
-    PS = pages_ref.shape[1]
-    n = rows_ref.shape[0]
+    o_ids = lax.broadcasted_iota(jnp.int32, pages_ref.shape, 1)
+    int8 = pages_ref.dtype == jnp.int8
 
     def body(i, acc):
-        row = pl.load(rows_ref, (pl.dslice(i, 1),))      # [1, ...]
-        hit = ((phys_ref[i] == p) & (valid_ref[i] != 0))
-        o_ids = lax.broadcasted_iota(jnp.int32, (1, PS), 1)
-        sel = (o_ids == off_ref[i]) & hit                # [1, PS]
-        extra = (None,) * (acc.ndim - 2)
-        return jnp.where(sel[(0, slice(None)) + extra][None],
-                         row.astype(acc.dtype), acc)
+        row = rows_ref[pl.ds(i, 1)][None]                # [1, 1, ...]
+        hit = (phys_ref[i] == p) & (valid_ref[i] != 0)
+        # a miss compares against offset -1, which no iota lane holds
+        # (scalar select in SMEM; the vector mask stays full-rank)
+        sel = o_ids == jnp.where(hit, off_ref[i], -1)
+        return jnp.where(sel, row.astype(acc.dtype), acc)
 
     # rolled loop: unroll=True would replicate the body n times in
     # EVERY one of the NP grid programs (n * NP code blow-up, Mosaic
     # compile time + VMEM) even though each page matches at most a few
-    # of the candidates
-    out_ref[...] = lax.fori_loop(0, n, body, pages_ref[...])
+    # of the candidates. int8 pages blend in int32 (no packed-int8
+    # select on the VPU).
+    page = pages_ref[...]
+    acc = lax.fori_loop(0, rows_ref.shape[0], body,
+                        page.astype(jnp.int32) if int8 else page)
+    out_ref[...] = acc.astype(out_ref.dtype)
 
 
 def fused_paged_write(pages, rows_flat, phys, off, valid, *,
-                      interpret: bool = False):
+                      interpret: bool = False,
+                      gridded: bool | None = None):
     """One-kernel paged-pool write.
 
     pages: [NP, PS, ...] pool half; rows_flat: [n, ...] incoming
     payloads (n = B*S, pre-quantized for int8 pools); phys/off/valid:
     [n] int32 physical page, in-page offset, and write-validity (live,
     wlen and table-bounds gating folded in by the caller). The pool is
-    aliased to the output.
+    aliased to the output. ``gridded`` as in fused_slot_write.
     """
     NP, PS = pages.shape[0], pages.shape[1]
     phys = jnp.asarray(phys, jnp.int32)
     off = jnp.asarray(off, jnp.int32)
     valid = jnp.asarray(valid, jnp.int32)
-    if interpret:
+    if gridded is None:
+        gridded = not interpret
+    if not gridded:
         grid = ()
-        in_specs = [pl.BlockSpec(memory_space=pltpu.ANY),
-                    pl.BlockSpec(memory_space=pltpu.ANY)]
-        out_specs = pl.BlockSpec(memory_space=pltpu.ANY)
+        in_specs = [pl.BlockSpec(memory_space=pl.ANY),
+                    pl.BlockSpec(memory_space=pl.ANY)]
+        out_specs = pl.BlockSpec(memory_space=pl.ANY)
         kernel = _paged_kernel_whole
         kw = {}
     else:

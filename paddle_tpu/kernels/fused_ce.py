@@ -16,12 +16,11 @@ compare folded into the elementwise epilogue (never materialized).
 
 Three entry points:
 
-- ``ce_fwd`` / ``ce_bwd``: the Pallas kernels. TPU grid is one program
-  per row-block with a fori over vocab blocks running the monoid in
-  VMEM scratch; ``interpret=True`` runs the same bodies grid-free on
-  CPU (flash_block precedent; a gridded interpret kernel would lower
-  to a while loop the hlo_cost model charges at full-operand scale per
-  trip).
+- ``ce_fwd`` / ``ce_bwd``: the Pallas kernels. The grid is (row-blocks,
+  vocab-blocks) with the monoid carried in VMEM scratch across the
+  vocab axis; ``interpret=True`` runs the same gridded bodies through
+  the interpreter (one body: what the tests run is what the chip
+  compiles).
 - ``online_lse``: the monoid as ONE variadic ``lax.reduce`` — the
   kernel's dataflow expressed for XLA. This is what the CPU dispatch
   path (nn/functional/loss.py, ``PADDLE_TPU_FUSED_CE``) uses: on this
@@ -83,44 +82,31 @@ def online_lse(lg, valid_vocab=None):
 
 
 # ------------------------------------------------------- Pallas kernels
+#
+# Per-row vectors (labels, per-row loss, lse, upstream grad) travel as
+# [N, 1] columns with (bn, 1) blocks: the chip's compiler refuses rank-1
+# (bn,) blocks (XLA tiles a rank-1 s32[N] operand T(1024), Mosaic
+# T(128)), and every row reduction below keeps its dim so no value ever
+# changes rank in-kernel.
 
-def _fwd_kernel_whole(labels_ref, lg_ref, per_ref, lse_ref, *,
-                      valid_vocab):
-    lg = lg_ref[...].astype(jnp.float32)                 # [N, V]
-    N, V = lg.shape
-    ids = lax.broadcasted_iota(jnp.int32, (N, V), 1)
-    if valid_vocab != V:
-        lg = jnp.where(ids < valid_vocab, lg, _NEG_INF)
-    m = jnp.max(lg, axis=-1)
-    s = jnp.sum(jnp.exp(lg - m[:, None]), axis=-1)
-    lse = jnp.log(s) + m
-    onehot = ids == labels_ref[:][:, None]
-    gold = jnp.sum(jnp.where(onehot, lg, 0.0), axis=-1)
-    per_ref[...] = lse - gold
-    lse_ref[...] = lse
-
-
-def _fwd_kernel_grid(labels_ref, lg_ref, per_ref, lse_ref, m_scr, s_scr,
-                     g_scr, *, valid_vocab, block_v):
+def _fwd_kernel(labels_ref, lg_ref, per_ref, lse_ref, m_scr, s_scr,
+                g_scr, *, valid_vocab, block_v):
     """One program per (row-block, vocab-block): the monoid carried in
-    VMEM scratch across the vocab grid axis. labels_ref is the [bn]
-    row-block of labels (a blocked input, NOT the full [N] array — the
-    whole-array compare would broadcast [N, 1] against [bn, bv] and
-    fail at trace time for any N > block_n)."""
+    VMEM scratch across the vocab grid axis. labels_ref is the [bn, 1]
+    row-block of labels."""
     iv, nv = pl.program_id(1), pl.num_programs(1)
 
     @pl.when(iv == 0)
     def _():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        s_scr[:] = jnp.zeros_like(s_scr)
-        g_scr[:] = jnp.zeros_like(g_scr)
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        s_scr[...] = jnp.zeros_like(s_scr)
+        g_scr[...] = jnp.zeros_like(g_scr)
 
     lg = lg_ref[...].astype(jnp.float32)                 # [bn, bv]
-    bn, bv = lg.shape
-    col = iv * block_v + lax.broadcasted_iota(jnp.int32, (bn, bv), 1)
+    col = iv * block_v + lax.broadcasted_iota(jnp.int32, lg.shape, 1)
     lg = jnp.where(col < valid_vocab, lg, _NEG_INF)
-    m_blk = jnp.max(lg, axis=-1)
-    m_old = m_scr[:]
+    m_blk = jnp.max(lg, axis=-1, keepdims=True)          # [bn, 1]
+    m_old = m_scr[...]
     m_new = jnp.maximum(m_old, m_blk)
     # -inf - -inf guards (same as online_lse's comb): a row whose
     # running max is still -inf (all columns masked so far) must carry
@@ -128,118 +114,77 @@ def _fwd_kernel_grid(labels_ref, lg_ref, per_ref, lse_ref, m_scr, s_scr,
     scale = jnp.where(m_old == _NEG_INF, 0.0, jnp.exp(m_old - m_new))
     s_blk = jnp.where(
         m_new == _NEG_INF, 0.0,
-        jnp.sum(jnp.exp(lg - m_new[:, None]), axis=-1))
-    m_scr[:] = m_new
-    s_scr[:] = s_scr[:] * scale + s_blk
-    hit = col == labels_ref[...][:, None]                # [bn, bv]
-    g_scr[:] = g_scr[:] + jnp.sum(jnp.where(hit, lg, 0.0), axis=-1)
+        jnp.sum(jnp.exp(lg - m_new), axis=-1, keepdims=True))
+    m_scr[...] = m_new
+    s_scr[...] = s_scr[...] * scale + s_blk
+    hit = col == labels_ref[...]                         # [bn, bv]
+    g_scr[...] = g_scr[...] + jnp.sum(jnp.where(hit, lg, 0.0), axis=-1,
+                                      keepdims=True)
 
     @pl.when(iv == nv - 1)
     def _():
-        lse = jnp.log(s_scr[:]) + m_scr[:]
-        per_ref[...] = lse - g_scr[:]
+        lse = jnp.log(s_scr[...]) + m_scr[...]
+        per_ref[...] = lse - g_scr[...]
         lse_ref[...] = lse
 
 
-def _bwd_kernel_whole(labels_ref, lg_ref, lse_ref, g_ref, dlg_ref, *,
-                      valid_vocab):
-    lg = lg_ref[...].astype(jnp.float32)
-    N, V = lg.shape
-    ids = lax.broadcasted_iota(jnp.int32, (N, V), 1)
-    p = jnp.exp(lg - lse_ref[:][:, None])
-    if valid_vocab != V:
-        p = jnp.where(ids < valid_vocab, p, 0.0)
-    onehot = (ids == labels_ref[:][:, None]).astype(jnp.float32)
-    dlg_ref[...] = ((p - onehot)
-                    * g_ref[:][:, None]).astype(dlg_ref.dtype)
-
-
-def _bwd_kernel_grid(labels_ref, lg_ref, lse_ref, g_ref, dlg_ref, *,
-                     valid_vocab, block_v):
-    """labels_ref / lse_ref / g_ref are [bn] row-blocks (blocked
-    inputs; see _fwd_kernel_grid on why labels must be blocked)."""
+def _bwd_kernel(labels_ref, lg_ref, lse_ref, g_ref, dlg_ref, *,
+                valid_vocab, block_v):
+    """labels_ref / lse_ref / g_ref are [bn, 1] row-blocks."""
     iv = pl.program_id(1)
     lg = lg_ref[...].astype(jnp.float32)                 # [bn, bv]
-    bn, bv = lg.shape
-    col = iv * block_v + lax.broadcasted_iota(jnp.int32, (bn, bv), 1)
-    p = jnp.exp(lg - lse_ref[...][:, None])
+    col = iv * block_v + lax.broadcasted_iota(jnp.int32, lg.shape, 1)
+    p = jnp.exp(lg - lse_ref[...])
     p = jnp.where(col < valid_vocab, p, 0.0)
-    onehot = (col == labels_ref[...][:, None]).astype(jnp.float32)
-    dlg_ref[...] = ((p - onehot)
-                    * g_ref[...][:, None]).astype(dlg_ref.dtype)
+    onehot = (col == labels_ref[...]).astype(jnp.float32)
+    dlg_ref[...] = ((p - onehot) * g_ref[...]).astype(dlg_ref.dtype)
 
 
 def ce_fwd(lg, labels, valid_vocab=None, *, block_n: int = 128,
-           block_v: int = 512, interpret: bool = False,
-           force_grid: bool = False):
+           block_v: int = 512, interpret: bool = False):
     """Fused CE forward: per-row loss + LSE residual, one streaming
     pass. lg: [N, V]; labels: [N] int; returns (per [N] f32, lse [N]
-    f32). ``force_grid`` runs the gridded (TPU) kernel body even under
-    ``interpret=True`` so tests cover the blocked path at N > block_n
-    (the dispatch path never sets it — grid-free interpret keeps the
-    hlo_cost model honest, see module docstring)."""
+    f32). ``interpret=True`` runs the SAME gridded body through the
+    interpreter (tests; the CPU dispatch uses ``online_lse`` instead)."""
     N, V = lg.shape
     vv = V if valid_vocab is None else int(valid_vocab)
-    labels = jnp.asarray(labels, jnp.int32)
-    if interpret and not force_grid:
-        return pl.pallas_call(
-            functools.partial(_fwd_kernel_whole, valid_vocab=vv),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1, grid=(),
-                in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-                out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 2),
-            out_shape=[jax.ShapeDtypeStruct((N,), jnp.float32)] * 2,
-            interpret=True,
-        )(labels, lg)
+    labels = jnp.asarray(labels, jnp.int32).reshape(N, 1)
     bn, bv = min(block_n, N), min(block_v, V)
-    grid = (pl.cdiv(N, bn), pl.cdiv(V, bv))
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel_grid, valid_vocab=vv, block_v=bv),
-        grid=grid,
-        in_specs=[pl.BlockSpec((bn,), lambda i, j: (i,)),
-                  pl.BlockSpec((bn, bv), lambda i, j: (i, j))],
-        out_specs=[pl.BlockSpec((bn,), lambda i, j: (i,))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((N,), jnp.float32)] * 2,
-        scratch_shapes=[pltpu.VMEM((bn,), jnp.float32)] * 3,
+    row = pl.BlockSpec((bn, 1), lambda i, j: (i, 0))
+    per, lse = pl.pallas_call(
+        functools.partial(_fwd_kernel, valid_vocab=vv, block_v=bv),
+        grid=(pl.cdiv(N, bn), pl.cdiv(V, bv)),
+        in_specs=[row, pl.BlockSpec((bn, bv), lambda i, j: (i, j))],
+        out_specs=[row, row],
+        out_shape=[jax.ShapeDtypeStruct((N, 1), jnp.float32)] * 2,
+        scratch_shapes=[pltpu.VMEM((bn, 1), jnp.float32)] * 3,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(labels, lg)
+    return per[:, 0], lse[:, 0]
 
 
 def ce_bwd(lg, labels, lse, g, valid_vocab=None, *, block_n: int = 128,
-           block_v: int = 512, interpret: bool = False,
-           force_grid: bool = False):
+           block_v: int = 512, interpret: bool = False):
     """Fused CE backward: dlogits = (softmax - onehot) * g in one
     streaming pass (one-hot folded into the epilogue). Returns dlogits
-    at lg's dtype. ``force_grid`` as in ce_fwd."""
+    at lg's dtype. ``interpret`` as in ce_fwd."""
     N, V = lg.shape
     vv = V if valid_vocab is None else int(valid_vocab)
-    labels = jnp.asarray(labels, jnp.int32)
-    lse = jnp.asarray(lse, jnp.float32)
-    g = jnp.asarray(g, jnp.float32)
-    if interpret and not force_grid:
-        return pl.pallas_call(
-            functools.partial(_bwd_kernel_whole, valid_vocab=vv),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1, grid=(),
-                in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 3,
-                out_specs=pl.BlockSpec(memory_space=pltpu.ANY)),
-            out_shape=jax.ShapeDtypeStruct((N, V), lg.dtype),
-            interpret=True,
-        )(labels, lg, lse, g)
+    labels = jnp.asarray(labels, jnp.int32).reshape(N, 1)
+    lse = jnp.asarray(lse, jnp.float32).reshape(N, 1)
+    g = jnp.asarray(g, jnp.float32).reshape(N, 1)
     bn, bv = min(block_n, N), min(block_v, V)
-    grid = (pl.cdiv(N, bn), pl.cdiv(V, bv))
+    row = pl.BlockSpec((bn, 1), lambda i, j: (i, 0))
+    tile = pl.BlockSpec((bn, bv), lambda i, j: (i, j))
     return pl.pallas_call(
-        functools.partial(_bwd_kernel_grid, valid_vocab=vv, block_v=bv),
-        grid=grid,
-        in_specs=[pl.BlockSpec((bn,), lambda i, j: (i,)),
-                  pl.BlockSpec((bn, bv), lambda i, j: (i, j)),
-                  pl.BlockSpec((bn,), lambda i, j: (i,)),
-                  pl.BlockSpec((bn,), lambda i, j: (i,))],
-        out_specs=pl.BlockSpec((bn, bv), lambda i, j: (i, j)),
+        functools.partial(_bwd_kernel, valid_vocab=vv, block_v=bv),
+        grid=(pl.cdiv(N, bn), pl.cdiv(V, bv)),
+        in_specs=[row, tile, row, row],
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((N, V), lg.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(labels, lg, lse, g)

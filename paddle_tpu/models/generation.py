@@ -5,8 +5,8 @@ Serving-path role parity: the reference's inference transformer stack
 decode helpers. TPU-native design: ONE jitted prefill program + ONE jitted
 whole-decode program — the entire token loop is a `lax.scan` inside the
 compiled program (eos masking included), so generating N tokens costs a
-single host->device dispatch instead of N round-trips. Over a tunneled
-or remote chip the per-step host sync would otherwise dominate decode.
+single host->device dispatch instead of N round-trips (a per-step host
+sync would otherwise dominate decode).
 Caches are donated so XLA updates them in place in HBM.
 
 Works with any model exposing:

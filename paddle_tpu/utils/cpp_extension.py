@@ -27,7 +27,6 @@ import ctypes
 import hashlib
 import os
 import subprocess
-import tempfile
 from typing import Callable, Optional, Sequence
 
 import numpy as np
@@ -35,6 +34,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import _paths
 from ..autograd.tape import apply
 
 __all__ = ["load", "CppExtension", "get_build_directory"]
@@ -48,8 +48,8 @@ _ARGTYPES = [
 
 
 def get_build_directory(override: Optional[str] = None) -> str:
-    d = override or os.environ.get("PADDLE_EXTENSION_DIR") or os.path.join(
-        tempfile.gettempdir(), "paddle_tpu_extensions")
+    d = (override or os.environ.get("PADDLE_EXTENSION_DIR")
+         or _paths.cache_path("extensions"))
     os.makedirs(d, exist_ok=True)
     return d
 

@@ -328,8 +328,8 @@ def test_real_engine_decode_program_is_clean():
 
 
 def test_tpulint_cli_codebase_only_gate_passes(capsys, monkeypatch):
-    """The CLI contract tpu_suite2.sh relies on: last stdout line is a
-    good JSON record (tools/_have_result.py), gate passes on HEAD.
+    """The CLI contract: the last stdout line is one terminal JSON
+    record, and the gate passes on HEAD.
     Run in-process (runpy) — a subprocess would pay a cold paddle_tpu
     import (~10 s) for nothing on the 1-core tier-1 budget."""
     import runpy

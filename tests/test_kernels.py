@@ -1,8 +1,9 @@
 """Fused Pallas kernel library (ISSUE 19): interpret-mode unit tests.
 
-kernels/fused_ce.py, kernels/cache_write.py, kernels/mega_decode.py run
-grid-free in interpret mode on CPU — the same bodies compile gridded on
-TPU. Identity targets are the UNFUSED chains they replace: jax.nn
+kernels/fused_ce.py runs its gridded bodies through the interpreter;
+kernels/cache_write.py and kernels/mega_decode.py share ONE math body
+between a grid-free wrapper (the CPU dispatch) and the blocked wrapper
+the chip compiles (tests/test_tpu_lowering.py), both covered here. Identity targets are the UNFUSED chains they replace: jax.nn
 softmax/logsumexp for cross-entropy, flash_attention.py's one-hot write
 + read + masked-softmax chain for the decode paths. The dispatch knobs
 (PADDLE_TPU_FUSED_CE / _FUSED_CACHE_WRITE / _MEGA_DECODE) are exercised
@@ -128,7 +129,7 @@ class TestFusedCE:
         lg = jnp.asarray(rs.randn(N, V).astype("float32") * 3)
         labels = jnp.asarray(rs.randint(0, V, N), jnp.int32)
         per, lse = ce_fwd(lg, labels, block_n=bn, block_v=bv,
-                          interpret=True, force_grid=True)
+                          interpret=True)
         ref_lse = jax.scipy.special.logsumexp(lg, axis=-1)
         ref_per = ref_lse - jnp.take_along_axis(
             lg, labels[:, None], 1)[:, 0]
@@ -136,7 +137,7 @@ class TestFusedCE:
         np.testing.assert_allclose(lse, ref_lse, atol=1e-5)
         g = _rand(N, seed=6)
         dlg = ce_bwd(lg, labels, lse, g, block_n=bn, block_v=bv,
-                     interpret=True, force_grid=True)
+                     interpret=True)
         ref = ((jax.nn.softmax(lg, axis=-1)
                 - jax.nn.one_hot(labels, V)) * g[:, None])
         np.testing.assert_allclose(dlg, ref, atol=1e-5)
@@ -150,14 +151,14 @@ class TestFusedCE:
         junk = lg.at[:, vv:].set(1e4)
         labels = jnp.asarray(rs.randint(0, vv, N), jnp.int32)
         per, lse = ce_fwd(junk, labels, valid_vocab=vv, block_n=bn,
-                          block_v=bv, interpret=True, force_grid=True)
+                          block_v=bv, interpret=True)
         ref_lse = jax.scipy.special.logsumexp(lg[:, :vv], axis=-1)
         ref_per = ref_lse - jnp.take_along_axis(
             lg, labels[:, None], 1)[:, 0]
         np.testing.assert_allclose(per, ref_per, atol=1e-5)
         dlg = ce_bwd(junk, labels, lse, _rand(N, seed=8),
                      valid_vocab=vv, block_n=bn, block_v=bv,
-                     interpret=True, force_grid=True)
+                     interpret=True)
         assert bool(jnp.all(dlg[:, vv:] == 0))
         assert bool(jnp.all(jnp.isfinite(dlg)))
 
@@ -273,49 +274,46 @@ class TestFusedPagedWrite:
 
 
 class TestGriddedKernelPaths:
-    """The interpret dispatch runs grid-free bodies, so the gridded
-    (TPU) bodies were invisible to tests — the fused-CE labels
-    broadcast bug hid exactly there. These force the gridded kernels
-    through the interpreter so their blocked index/broadcast logic is
-    trace-covered on CPU. (fused-CE's gridded path has its own
-    ``force_grid`` tests above.)"""
+    """The CPU dispatch runs the grid-free wrappers; these run the
+    BLOCKED wrappers the chip compiles (``gridded=True``) through the
+    interpreter, so their grid index maps, L-block offsets and scratch
+    carry are covered on CPU. (fused-CE has one gridded form only.)"""
 
-    @pytest.fixture
-    def force_interpret(self, monkeypatch):
-        from jax.experimental import pallas as pl
-        orig = pl.pallas_call
-        monkeypatch.setattr(
-            pl, "pallas_call",
-            lambda *a, **kw: orig(*a, **{**kw, "interpret": True}))
-
-    def test_slot_write_gridded(self, force_interpret):
+    def test_slot_write_gridded(self, monkeypatch):
+        from paddle_tpu.kernels import cache_write
+        monkeypatch.setattr(cache_write, "_L_BLOCK", 8)   # 2 L-blocks
         cache = _rand(3, 16, 2, 8, seed=20)
         rows = _rand(3, 1, 2, 8, seed=21)
         pos = jnp.asarray([0, 7, 15], jnp.int32)
-        out = fused_slot_write(cache, rows, pos, interpret=False)
+        out = fused_slot_write(cache, rows, pos, interpret=True,
+                               gridded=True)
         ref = cache
         for b in range(3):
             ref = ref.at[b, int(pos[b])].set(rows[b, 0])
         assert bool(jnp.array_equal(out, ref))
 
-    def test_paged_write_gridded(self, force_interpret):
+    def test_paged_write_gridded(self):
         pages = _rand(5, 3, 1, 2, seed=22)
         rows = _rand(4, 1, 2, seed=23)
         phys = jnp.asarray([4, 0, 2, 1], jnp.int32)
         off = jnp.asarray([0, 2, 1, 2], jnp.int32)
         valid = jnp.asarray([1, 0, 1, 1], jnp.int32)
         out = fused_paged_write(pages, rows, phys, off, valid,
-                                interpret=False)
+                                interpret=True, gridded=True)
         ref = pages
         for i in range(4):
             if int(valid[i]):
                 ref = ref.at[int(phys[i]), int(off[i])].set(rows[i])
         assert bool(jnp.array_equal(out, ref))
 
-    def test_mega_decode_gridded(self, force_interpret):
-        q, k, v, kc, vc, pos = _decode_fixture(nh=4, nkv=2, L=8)
+    @pytest.mark.parametrize("nh,nkv", [(4, 4), (4, 2)])
+    def test_mega_decode_gridded(self, monkeypatch, nh, nkv):
+        from paddle_tpu.kernels import mega_decode
+        monkeypatch.setattr(mega_decode, "_L_BLOCK", 4)   # 4 L-blocks
+        q, k, v, kc, vc, pos = _decode_fixture(nh=nh, nkv=nkv, L=16)
         ctx_g, kc_g, vc_g = mega_decode_step(q, k, v, kc, vc, pos,
-                                             interpret=False)
+                                             interpret=True,
+                                             gridded=True)
         ctx_w, kc_w, vc_w = mega_decode_step(q, k, v, kc, vc, pos,
                                              interpret=True)
         np.testing.assert_allclose(np.asarray(ctx_g), np.asarray(ctx_w),

@@ -28,7 +28,7 @@ The child sets JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS=0 so even
 sub-second eager compiles are cache hits on the warm pass; the
 store-loaded big programs never enter jax's compile path at all.
 
-Last stdout line is one JSON record (tools/_have_result.py contract).
+Last stdout line is one JSON record (one terminal JSON record).
 Exit 1 if the warm pass compiled anything (the zero-compile claim is
 ASSERTED, not just reported). Record lands in PERF.md.
 """
@@ -144,6 +144,10 @@ def _child_fit(t0: float) -> dict:
 
 def _run_child(mode: str, workdir: str) -> dict:
     env = dict(os.environ)
+    # this tool measures a COLD start on purpose: each run gets an empty
+    # executable store and an empty jax cache of its own under a fresh
+    # workdir, overriding the package's placement rule
+    # (paddle_tpu/_paths.jax_cache_dir) — never copy this elsewhere
     env.update({
         "JAX_PLATFORMS": "cpu",
         "PADDLE_TPU_EXEC_STORE_DIR": os.path.join(workdir, "exec"),

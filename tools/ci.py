@@ -46,8 +46,8 @@ QUICK_FILES = [
     "tests/test_zero_accumulation.py", "tests/test_api_surface.py",
     "tests/test_op_numerics.py", "tests/test_functional_numerics.py",
     "tests/test_incubate_geometric.py", "tests/test_gpt_scan_layers.py",
-    "tests/test_tpu_lowering.py", "tests/test_single_flight.py",
-    "tests/test_suite_mechanics.py", "tests/test_checkpoint_resume_zero3.py",
+    "tests/test_tpu_lowering.py", "tests/test_chip_smoke.py",
+    "tests/test_checkpoint_resume_zero3.py",
     "tests/test_quickstart_parity.py",
     # serving engine: continuous batching is a core-correctness surface
     # (greedy token-identity + the no-recompile guarantee)
@@ -301,8 +301,8 @@ def _run_tpuprof(env, update_baseline=False) -> int:
     after review with `python tools/ci.py --tpuprof
     --update-baseline`. Not appended to --quick/--full automatically:
     it EXECUTES every program under the profiler, and wall-time gates
-    belong where wall time is quiet (tpu_suite2.sh runs it; run it by
-    hand when touching a hot program)."""
+    belong where wall time is quiet (run it by hand when touching a
+    hot program)."""
     print("\n=== tpuprof measured-runtime gate ===")
     cmd = [sys.executable, os.path.join("tools", "tpuprof.py")]
     if update_baseline:
@@ -470,7 +470,7 @@ def main():
     # a crashed suite costs more than the recompiles it saves.
     cache_env = dict(env)
     cache_env.setdefault("JAX_COMPILATION_CACHE_DIR",
-                         os.path.expanduser("~/.cache/paddle_tpu_ci_xla"))
+                         os.path.join(ROOT, ".cache", "jax_ci_cpu"))
     cache_env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
                          "1")
 

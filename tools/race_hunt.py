@@ -46,7 +46,7 @@ that never import jax — the test-suite smoke):
 
 Exit codes: 0 = all hammers clean, 1 = invariant violation or
 sanitizer artifact, 2 = harness error. The last stdout line is one
-JSON record (tools/_have_result.py contract); ``--json`` also writes
+JSON record (one terminal JSON record); ``--json`` also writes
 the full record. tools/tpurace.py is the static half of the race
 gate; this is the dynamic half ci.py --quick runs after the tests.
 """
@@ -340,7 +340,7 @@ def main() -> int:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         os.environ.setdefault(
             "JAX_COMPILATION_CACHE_DIR",
-            os.path.expanduser("~/.cache/paddle_tpu_ci_xla"))
+            os.path.join(ROOT, ".cache", "jax_ci_cpu"))
         os.environ.setdefault(
             "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
 

@@ -15,7 +15,7 @@ Serve mode (default):
         --model '{"kind": "gpt", "vocab_size": 50304, ...}'
     ... SIGINT/SIGTERM drains the tier and exits; the LAST stdout line
     is one JSON record of the tier's lifetime stats
-    (tools/_have_result.py contract).
+    (one terminal JSON record).
 
 Smoke mode (--smoke): tiny model, 2 replicas, a short closed-loop
 workload including one replica kill and one rolling restart; exits

@@ -30,8 +30,7 @@ loop, the request journal, the compilation store, the metrics
 registry: any finding whose key matches an anchor prefix fails the
 gate even with a count bump, so a fixed race cannot silently return.
 
-The last stdout line is one JSON record (tools/_have_result.py
-terminal-record contract) so tpu_suite2.sh's self-skip predicate works
+The last stdout line is one terminal JSON record, so a caller can gate
 on the artifact.
 """
 from __future__ import annotations

@@ -19,9 +19,9 @@ Modes:
       Spin a tiny 2-replica tier, run a few traced requests through
       the router, and write ONE merged Chrome trace (router spans +
       the serving replica's engine spans, correlated by request id) to
-      OUT — the artifact tpu_suite2.sh uploads.
+      OUT.
 
-Prints ONE terminal JSON record (tools/_have_result.py contract);
+Prints ONE terminal JSON record;
 exit 2 on usage errors with an {"error": ...} record (warmup.py
 parity, so the suite watcher never spins on an empty artifact).
 """
