@@ -1,0 +1,8 @@
+"""1 minus the union of device-operation intervals over the traced
+window."""
+
+
+def read(trace, obs, cell, chip, say):
+    if trace is None:
+        return None
+    return 100.0 * (1.0 - trace.busy_s() / trace.window_s())
