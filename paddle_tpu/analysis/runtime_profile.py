@@ -48,6 +48,7 @@ import glob
 import gzip
 import json
 import os
+import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -60,6 +61,7 @@ __all__ = [
     "category_of", "normalize_kernel_name",
     "join_measured_modeled", "time_weighted_histogram",
     "time_weighted_chains", "runtime_report",
+    "hlo_op_scopes", "read_scope", "by_scope",
     "host_example_args", "measure_dispatch", "trace_dispatches",
     "profile_program",
     "load_profile_baseline", "updated_profile_baseline",
@@ -82,11 +84,14 @@ class DeviceProfile:
     """Aggregated device-lane view of one chrome trace.
 
     ``per_op`` maps kernel (HLO instruction) name -> total device us
-    across the traced window; ``op_category`` keeps the profiler's own
-    ``hlo_category`` label where present. ``had_device`` False means
+    across the traced window and ``per_op_self`` the same less the time
+    of operations nested inside it (a ``while`` holds its body's);
+    ``op_category`` keeps the profiler's own ``hlo_category`` label
+    where present. ``had_device`` False means
     the trace came from a backend with no device plane (CPU) and the
     caller must degrade to wall-time-only reporting."""
     per_op: Dict[str, float] = field(default_factory=dict)
+    per_op_self: Dict[str, float] = field(default_factory=dict)
     op_category: Dict[str, str] = field(default_factory=dict)
     had_device: bool = False
     host_dispatch_events: int = 0
@@ -138,6 +143,7 @@ def device_op_times(events: Sequence[dict]) -> DeviceProfile:
                 e.get("pid") in device_pids and \
                 "XLA Ops" in str(e.get("args", {}).get("name", "")):
             op_tids.add((e.get("pid"), e.get("tid")))
+    lanes: Dict[tuple, list] = {}
     for e in events:
         if e.get("ph") != "X":
             continue
@@ -148,13 +154,36 @@ def device_op_times(events: Sequence[dict]) -> DeviceProfile:
                 continue
             prof.per_op[name] = prof.per_op.get(name, 0.0) + \
                 float(e.get("dur", 0.0))
+            t0 = float(e.get("ts", 0.0))
+            lanes.setdefault((e.get("pid"), e.get("tid")), []).append(
+                (name, t0, t0 + float(e.get("dur", 0.0))))
             args = e.get("args") or {}
             cat = args.get("hlo_category") or args.get("category")
             if cat:
                 prof.op_category[name] = str(cat)
         elif any(m in name for m in _HOST_DISPATCH_MARKERS):
             prof.host_dispatch_events += 1
+    for lane in lanes.values():
+        for name, us in self_times(lane).items():
+            prof.per_op_self[name] = prof.per_op_self.get(name, 0.0) + us
     return prof
+
+
+def self_times(events: Sequence[Tuple[str, float, float]]
+               ) -> Dict[str, float]:
+    """name -> time not covered by an event nested inside it, for one
+    lane's (name, start, end) events; nesting is by containment."""
+    out: Dict[str, float] = {}
+    stack: List[Tuple[str, float, float]] = []
+    for name, s, e in sorted(events, key=lambda ev: (ev[1], -ev[2])):
+        while stack and stack[-1][2] <= s:
+            stack.pop()
+        if stack:                       # take my time out of my parent's
+            parent = stack[-1]
+            out[parent[0]] = out.get(parent[0], 0.0) - (min(e, parent[2]) - s)
+        out[name] = out.get(name, 0.0) + (e - s)
+        stack.append((name, s, e))
+    return out
 
 
 def category_of(name: str, op_cat: Optional[Dict[str, str]] = None) -> str:
@@ -315,6 +344,190 @@ def time_weighted_chains(join: dict, chains: Sequence[dict],
 
 
 # ---------------------------------------------------------------------------
+# whose time it is: instruction -> scope of the program -> (region, pass)
+# ---------------------------------------------------------------------------
+#
+# The program names itself (`nn.Layer.__call__` runs every forward under
+# `jax.named_scope(<the key its parent registered it under>)`; the
+# trainers add `head_loss`, `optimizer`, `grad_accumulate`), the names
+# survive XLA's optimisation in each instruction's
+# ``metadata={op_name="..."}``, and JAX marks the pass itself:
+# ``jvp(..)`` forward, ``transpose(jvp(..))`` backward,
+# ``checkpoint/rematted_computation`` the recomputed forward.
+
+# JAX's own path tokens that are no scope of the program
+_JAX_TOKENS = frozenset((
+    "while", "body", "cond", "body_fun", "cond_fun", "scan", "switch",
+    "closed_call", "core_call", "checkpoint", "rematted_computation",
+    "remat", "remat2", "pjit", "shard_map", "custom_jvp_call",
+    "custom_vjp_call", "custom_vjp_call_jaxpr", "custom_lin"))
+_BRANCH_RE = re.compile(r"^branch_\d+_fun$")
+_WRAPPED_RE = re.compile(r"^([\w.\-]*)\((.*)\)$")
+_INDEX_RE = re.compile(r"(?:^|_)\d+$")
+_NAME_RE = re.compile(r"^[\w.\-]+$")     # a scope the program could give
+_UPDATE_SCOPES = ("optimizer", "grad_accumulate")
+# the scopes the trainers give where no layer stands: one of these on a
+# path owns it whatever layer runs inside (a loss layer, say)
+_TRAINER_SCOPES = ("head_loss",) + _UPDATE_SCOPES
+
+
+def _split_path(path: str) -> List[str]:
+    """``a/jvp(b/c)/d`` -> [a, jvp(b/c), d]: ``/`` outside brackets."""
+    out, depth, cur = [], 0, []
+    for ch in path:
+        if ch == "(":
+            depth += 1
+        elif ch == ")":
+            depth = max(0, depth - 1)
+        if ch == "/" and depth == 0:
+            out.append("".join(cur))
+            cur = []
+        else:
+            cur.append(ch)
+    out.append("".join(cur))
+    return out
+
+
+def read_scope(path: str) -> dict:
+    """THE rule that reads one ``op_name`` path. Pure.
+
+    Pass: ``update`` if ``optimizer`` or ``grad_accumulate`` is on the
+    path, else ``recompute`` if ``rematted_computation``, else
+    ``backward`` if ``transpose(``, else ``forward``.
+
+    Scope: the path less JAX's own tokens (``jit(..)`` whole; the
+    wrappers ``jvp(..)``/``transpose(..)``/``vmap(..)`` opened, what
+    they wrap kept; ``while``, ``body``, ``cond``, ``closed_call``,
+    ``checkpoint``, ``rematted_computation``; the primitive at the
+    tail), a layer's index stripped (``block_7`` -> ``block``); of
+    several paths joined by ``;`` the first.
+
+    Region, for the summary: the innermost of the trainers' own scopes
+    on the path (``head_loss``, ``optimizer``, ``grad_accumulate``);
+    else the innermost scope, which is the name a layer was registered
+    under, whatever the model calls its layers (``attn`` or
+    ``self_attn``, ``ln_1`` -> ``ln`` or ``input_layernorm``; a
+    block's residual adds read ``block``) -- except that where that
+    scope is one that holds a loop (a ``ScannedStack``: its own
+    operations stack and slice the saved activations and gradients)
+    the region is ``scan_carry``; under no scope of the program at all,
+    ``unscoped``. A caller that wants coarser rows (all of attention,
+    every norm) groups by ``scope``, which keeps the whole path."""
+    raw = _split_path(path.split(";", 1)[0])
+    tail, raw = raw[-1], raw[:-1]                   # the primitive
+    scopes: List[str] = []      # the program's scopes, outermost first
+    loops = set()               # indices into `scopes` that hold a loop
+    backward = recompute = False
+    for tok in raw:
+        while True:             # open jvp(..), transpose(..), vmap(..)
+            m = _WRAPPED_RE.match(tok)
+            if m is None:
+                break
+            if m.group(1) == "transpose":
+                backward = True
+            tok = "" if m.group(1) in ("jit", "pjit") else m.group(2)
+        if tok == "rematted_computation":
+            recompute = True
+        if tok == "while" and scopes:
+            loops.add(len(scopes) - 1)
+        if not tok or tok in _JAX_TOKENS or _BRANCH_RE.match(tok) \
+                or not _NAME_RE.match(tok):
+            continue            # JAX's own, or a library's (einsum spec)
+        tok = _INDEX_RE.sub("", tok)
+        if tok:
+            scopes.append(tok)
+    if tail == "while" and scopes:      # the loop's own instruction
+        loops.add(len(scopes) - 1)
+    if any(s in _UPDATE_SCOPES for s in scopes):
+        pass_ = "update"
+    elif recompute:
+        pass_ = "recompute"
+    else:
+        pass_ = "backward" if backward else "forward"
+    if not scopes:
+        region = "unscoped"
+    else:
+        region = next((s for s in reversed(scopes)
+                       if s in _TRAINER_SCOPES), None)
+        if region is None:
+            region = "scan_carry" if len(scopes) - 1 in loops \
+                else scopes[-1]
+    return {"pass": pass_, "scope": "/".join(scopes), "region": region}
+
+
+def hlo_op_scopes(hlo_text: str) -> Dict[str, str]:
+    """{HLO instruction name: ``op_name`` path} for EVERY instruction of
+    a compiled program's text (``""`` where none is found). An
+    instruction the compiler made late has no path of its own: one that
+    calls a computation (a fusion) takes that computation's root's, or
+    its first instruction's that has one; failing that (a copy, a
+    reduction split in two) it takes its first operand's, which made
+    the value it moves."""
+    from .hlo_cost import parse_hlo_module
+    module = parse_hlo_module(hlo_text)
+    out: Dict[str, str] = {}
+    for comp in module.computations.values():
+        for ins in comp.instrs:     # operands come before their users
+            path = ins.attrs.get("op_name", "")
+            called = module.computations.get(ins.attrs.get("calls", ""))
+            if not path and called is not None:
+                root = called.root
+                path = (root.attrs.get("op_name", "") if root else "") \
+                    or next((i.attrs["op_name"] for i in called.instrs
+                             if i.attrs.get("op_name")), "")
+            if not path and ins.operands and ins.opcode != "parameter":
+                made_by = comp.by_name.get(ins.operands[0])
+                if made_by is not None and made_by.opcode != "parameter":
+                    path = out.get(made_by.name, "")    # not an argument's
+            out[ins.name] = path
+    return out
+
+
+def by_scope(per_op_self_s: Dict[str, float],
+             op_scopes: Dict[str, str]) -> dict:
+    """Seconds and share of device busy time per (region, pass): the
+    self time of each instruction (``per_op_self_s``: name -> seconds
+    not covered by an operation nested inside it, summed over the
+    traced window) joined with `hlo_op_scopes`' table by name. Pure
+    function of two dicts. An instruction the table does not know, or
+    knows no path for, is ``unscoped``: that share is printed, not
+    hidden."""
+    rows: Dict[Tuple[str, str], float] = {}
+    unknown = 0.0
+    for name, secs in per_op_self_s.items():
+        path = op_scopes.get(normalize_kernel_name(name))
+        if path is None:
+            unknown += secs
+        got = read_scope(path) if path else \
+            {"region": "unscoped", "pass": "forward"}
+        key = (got["region"], got["pass"])
+        rows[key] = rows.get(key, 0.0) + secs
+    total = sum(rows.values())
+
+    def share(s):
+        return round(s / total, 6) if total else 0.0
+    table = [{"region": r, "pass": p, "seconds": s, "share": share(s)}
+             for (r, p), s in sorted(rows.items(), key=lambda kv: -kv[1])]
+    passes: Dict[str, float] = {}
+    regions: Dict[str, float] = {}
+    for (r, p), s in rows.items():
+        passes[p] = passes.get(p, 0.0) + s
+        regions[r] = regions.get(r, 0.0) + s
+    return {
+        "busy_s": total,
+        "rows": table,
+        "by_pass": {p: {"seconds": s, "share": share(s)}
+                    for p, s in sorted(passes.items(),
+                                       key=lambda kv: -kv[1])},
+        "by_region": {r: {"seconds": s, "share": share(s)}
+                      for r, s in sorted(regions.items(),
+                                         key=lambda kv: -kv[1])},
+        "unscoped_share": share(regions.get("unscoped", 0.0)),
+        "not_in_table_share": share(unknown),
+    }
+
+
+# ---------------------------------------------------------------------------
 # per-program report
 # ---------------------------------------------------------------------------
 
@@ -340,13 +553,17 @@ def runtime_report(name: str, *, hlo_text: Optional[str] = None,
                    dispatches_profiled: int = 1,
                    chip: "str | ChipSpec" = DEFAULT_CHIP,
                    geometry: Optional[dict] = None,
+                   op_scopes: Optional[Dict[str, str]] = None,
                    top: int = 15) -> dict:
     """Compose ONE program's measured-runtime record: wall dispatch
     stats + (when a device plane exists) the measured<->modeled join,
     time-weighted fusion histogram, per-kernel roofline ratios, and
     the time-ranked unfused chains. Pass either ``hlo_text`` (parsed
     here) or a pre-collected ``kernels`` list, and either raw trace
-    ``events`` or a pre-parsed ``profile``."""
+    ``events`` or a pre-parsed ``profile``. With ``op_scopes``
+    (`hlo_op_scopes` of the program's text, or a trainer's
+    ``op_scopes()``) the record gains ``by_scope``: device seconds per
+    dispatch by (region, pass) of the program's own names."""
     from .fusion import unfused_chains
     from .hlo_cost import collect_kernels, parse_hlo_module
     if isinstance(chip, str):
@@ -396,6 +613,11 @@ def runtime_report(name: str, *, hlo_text: Optional[str] = None,
             if modeled_roofline_us else None
         rec["top_unfused_by_time"] = time_weighted_chains(
             join, unfused_chains(list(kernels), limit=max(20, top)))
+        if op_scopes is not None:
+            n = max(1, int(dispatches_profiled))
+            rec["by_scope"] = by_scope(
+                {k: us / 1e6 / n for k, us in profile.per_op_self.items()},
+                op_scopes)
     else:
         rec["join"] = {
             "available": False,
@@ -499,7 +721,8 @@ def profile_program(build_result, *, rounds: int = 3, inner: int = 3,
     return runtime_report(name, hlo_text=hlo, events=events,
                           dispatch_s=dispatch_s,
                           dispatches_profiled=profile_dispatches,
-                          chip=chip, geometry=r.geometry)
+                          chip=chip, geometry=r.geometry,
+                          op_scopes=hlo_op_scopes(hlo))
 
 
 # ---------------------------------------------------------------------------

@@ -19,6 +19,22 @@ eliminating them. One process-global set of counters fed by
   cold-start claim tools/bench_cold_start.py asserts.
 - ``compile_secs``: wall time spent inside the backend compile path.
 
+A trace (function -> jaxpr), a lowering (jaxpr -> MLIR module) and a
+backend compile each also land in the obs flight recorder, where
+ambient instrumentation is on, as a span ``compile.trace`` /
+``compile.lower`` / ``compile.backend`` (cat ``compile``; end = the
+moment the event fired, start = end - duration; ``fun_name`` where the
+event carries one), so that a reader can tell one phase's compiles from
+another's by WHEN they happened (an inner function's trace lies inside
+its caller's: a reader takes overlaps once). The ring is where the
+seconds of traces and lowerings live; no counter sums them. Events
+shorter than ``RING_FLOOR_S`` (a millisecond) are not recorded (traces
+are still counted, backend compiles counted and summed): a training
+set-up fires two thousand traces of ``multiply``/``add``/``sqrt``
+inside its programs' own traces, 1.4% of the compile seconds and nine
+tenths of the events (CPU rehearsal of the benchmark's driver, PR 28),
+which would push everything else out of the ring.
+
 Writers (the listeners) fire on whatever thread is compiling —
 parallel warmup means concurrent increments, so they serialize on a
 lock (compiles are rare; the cost is nil). Readers stay the syncs.py
@@ -27,13 +43,21 @@ idiom: plain delta reads on one consumer thread between phases.
 from __future__ import annotations
 
 import threading
+import time
 
 __all__ = ["backend_compiles", "persistent_cache_hits", "xla_compiles",
-           "compile_secs", "traces", "CompileTracker", "install"]
+           "compile_secs", "traces", "CompileTracker", "install",
+           "RING_FLOOR_S"]
 
 _BACKEND_COMPILE_EVT = "/jax/core/compile/backend_compile_duration"
 _TRACE_EVT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _CACHE_HIT_EVT = "/jax/compilation_cache/cache_hits"
+#: shortest event that lands in the ring
+RING_FLOOR_S = 1e-3
+# event -> its span in the obs ring
+_SPAN_OF = {_TRACE_EVT: "compile.trace", _LOWER_EVT: "compile.lower",
+            _BACKEND_COMPILE_EVT: "compile.backend"}
 
 _backend_compiles = 0
 _cache_hits = 0
@@ -73,6 +97,10 @@ def _obs() -> tuple:
 
 def _on_duration(event: str, duration_secs: float, **kw) -> None:
     global _backend_compiles, _traces, _compile_secs
+    span = _SPAN_OF.get(event)
+    if span is None:
+        return
+    now = time.perf_counter()
     if event == _BACKEND_COMPILE_EVT:
         with _count_lock:
             _backend_compiles += 1
@@ -84,6 +112,13 @@ def _on_duration(event: str, duration_secs: float, **kw) -> None:
     elif event == _TRACE_EVT:
         with _count_lock:
             _traces += 1
+    if duration_secs < RING_FLOOR_S:
+        return
+    from .. import obs
+    if obs.enabled():
+        args = {"fun_name": str(kw["fun_name"])} if "fun_name" in kw else {}
+        obs.record_span(span, now - duration_secs, now, cat="compile",
+                        **args)
 
 
 def _on_event(event: str, **kw) -> None:

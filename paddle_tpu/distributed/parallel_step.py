@@ -36,7 +36,9 @@ from ..autograd.tape import no_grad
 from ..core.tensor import Tensor
 from ..framework import random as _rng
 from ..jit.functional import functional_call, load_state, raw_state, _wrap
-from ..jit.training import TrainStep, _raw_tuple
+from ..jit.training import (TrainStep, _raw_tuple, op_scopes_of,
+                            remember_trace)
+from ..obs.trace import span as _span
 from . import mesh as mesh_mod
 
 __all__ = ["ParallelTrainStep", "param_sharding", "shard_params"]
@@ -165,6 +167,10 @@ class ParallelTrainStep:
         self._scan_progs = {}
         # trace-time program counter (same contract as jit.TrainStep)
         self._trace_count = 0
+        # what `op_scopes` lowers, and its last table
+        # (jit.training.remember_trace / op_scopes_of)
+        self._last_traced = None
+        self._op_scopes = None
         # LR-scheduler ownership knob, honored by BOTH __call__ and
         # scan_steps (same contract as jit.TrainStep.auto_lr_step):
         # False = an external owner steps the schedule between calls
@@ -404,7 +410,7 @@ class ParallelTrainStep:
                 with _rng.rng_guard(rng_key), aux_loss_scope() as auxes:
                     out, new_bufs = functional_call(model, p, buffers,
                                                     *inputs, training=True)
-                    with no_grad():
+                    with no_grad(), jax.named_scope("head_loss"):
                         loss_t = loss_fn(_wrap(out),
                                          *[_wrap(l) for l in labels])
                 loss_v = loss_t.value if isinstance(loss_t, Tensor) else loss_t
@@ -601,7 +607,7 @@ class ParallelTrainStep:
                         out, new_bufs = functional_call(
                             model, p, buffers_l, *inputs,
                             training=True)
-                        with no_grad():
+                        with no_grad(), jax.named_scope("head_loss"):
                             loss_t = loss_fn(_wrap(out),
                                              *[_wrap(l) for l in labels])
                     loss_v = (loss_t.value
@@ -674,11 +680,13 @@ class ParallelTrainStep:
         if k == 1:
             def full_step(params, buffers, opt_state, lr, step_no, rng_key,
                           *batch):
-                step_self._trace_count += 1   # fires at trace time only
+                remember_trace(step_self, "_jitted", params, buffers,
+                               opt_state, lr, step_no, rng_key, *batch)
                 loss, new_bufs, grads = fwd_bwd(params, buffers, lr, step_no,
                                                 rng_key, *batch)
-                new_params, new_opt = optimizer.apply_gradients(
-                    params, grads, opt_state, lr=lr, step=step_no)
+                with jax.named_scope("optimizer"):
+                    new_params, new_opt = optimizer.apply_gradients(
+                        params, grads, opt_state, lr=lr, step=step_no)
                 if post_update is not None:
                     new_params = post_update(new_params)
                 return loss, new_params, new_bufs, new_opt
@@ -701,20 +709,25 @@ class ParallelTrainStep:
 
         def acc_step(params, buffers, opt_state, acc, lr, step_no, rng_key,
                      *batch):
-            step_self._trace_count += 1       # fires at trace time only
+            remember_trace(step_self, "_jitted_acc", params, buffers,
+                           opt_state, acc, lr, step_no, rng_key, *batch)
             loss, new_bufs, grads = fwd_bwd(params, buffers, lr, step_no,
                                             rng_key, *batch)
-            new_acc = {n: acc[n] + grads[n] for n in acc}
+            with jax.named_scope("grad_accumulate"):
+                new_acc = {n: acc[n] + grads[n] for n in acc}
             return loss, new_bufs, new_acc
 
         def apply_step(params, buffers, opt_state, acc, lr, step_no, rng_key,
                        *batch):
-            step_self._trace_count += 1       # fires at trace time only
+            remember_trace(step_self, "_jitted", params, buffers,
+                           opt_state, acc, lr, step_no, rng_key, *batch)
             loss, new_bufs, grads = fwd_bwd(params, buffers, lr, step_no,
                                             rng_key, *batch)
-            mean = {n: (acc[n] + grads[n]) / k for n in acc}
-            new_params, new_opt = optimizer.apply_gradients(
-                params, mean, opt_state, lr=lr, step=step_no)
+            with jax.named_scope("grad_accumulate"):
+                mean = {n: (acc[n] + grads[n]) / k for n in acc}
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = optimizer.apply_gradients(
+                    params, mean, opt_state, lr=lr, step=step_no)
             if post_update is not None:
                 new_params = post_update(new_params)
             zeros = {n: jnp.zeros_like(v) for n, v in acc.items()}
@@ -794,29 +807,41 @@ class ParallelTrainStep:
         if self._jitted is None:
             self._build(raw_batch)
         self.step_count += 1
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        rng_key = _rng.default_generator().fold_in(self.step_count)
+        n = self.step_count
         k = self.accumulate_steps
-        if k > 1 and self.step_count % k != 0:
-            step_no = jnp.asarray(self.update_count + 1, jnp.float32)
-            loss, self.buffers, self.acc_grads = self._jitted_acc(
-                self.params, self.buffers, self.opt_state, self.acc_grads,
-                lr, step_no, rng_key, *raw_batch)
+        micro = k > 1 and n % k != 0    # accumulate grads, no update
+        # the spans of jit.TrainStep.__call__, name for name
+        with _span("train.step", cat="train", step=n,
+                   program="accumulate" if micro else "step"):
+            with _span("train.step.prep", cat="train", step=n):
+                lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
+                rng_key = _rng.default_generator().fold_in(n)
+                if not micro:
+                    self.update_count += 1
+                step_no = jnp.asarray(
+                    self.update_count + (1 if micro else 0), jnp.float32)
+            with _span("train.step.enqueue", cat="train", step=n):
+                if micro:
+                    loss, self.buffers, self.acc_grads = self._jitted_acc(
+                        self.params, self.buffers, self.opt_state,
+                        self.acc_grads, lr, step_no, rng_key, *raw_batch)
+                elif k > 1:
+                    (loss, self.params, self.buffers, self.opt_state,
+                     self.acc_grads) = self._jitted(
+                        self.params, self.buffers, self.opt_state,
+                        self.acc_grads, lr, step_no, rng_key, *raw_batch)
+                else:
+                    (loss, self.params, self.buffers,
+                     self.opt_state) = self._jitted(
+                        self.params, self.buffers, self.opt_state, lr,
+                        step_no, rng_key, *raw_batch)
+            with _span("train.step.post", cat="train", step=n):
+                lr_sched = getattr(self.optimizer, "_learning_rate", None)
+                if not micro and self.auto_lr_step \
+                        and hasattr(lr_sched, "step"):
+                    lr_sched.step()
+        if micro:
             return Tensor(loss)
-        self.update_count += 1
-        step_no = jnp.asarray(self.update_count, jnp.float32)
-        if k > 1:
-            (loss, self.params, self.buffers, self.opt_state,
-             self.acc_grads) = self._jitted(
-                self.params, self.buffers, self.opt_state, self.acc_grads,
-                lr, step_no, rng_key, *raw_batch)
-        else:
-            loss, self.params, self.buffers, self.opt_state = self._jitted(
-                self.params, self.buffers, self.opt_state, lr, step_no,
-                rng_key, *raw_batch)
-        lr_sched = getattr(self.optimizer, "_learning_rate", None)
-        if self.auto_lr_step and hasattr(lr_sched, "step"):
-            lr_sched.step()
         # FLAGS_check_nan_inf wiring (framework/nan_inf.py): scan the
         # step loss — the one concrete value the fused program yields —
         # so a divergence aborts (level 0) or warns (level>=1) at the
@@ -895,6 +920,11 @@ class ParallelTrainStep:
     def _count_trace(self):
         self._trace_count += 1    # fires at trace time only
 
+    def op_scopes(self) -> Dict[str, str]:
+        return op_scopes_of(self)
+
+    op_scopes.__doc__ = op_scopes_of.__doc__
+
     def scan_steps(self, k_steps: int, *batch) -> Tensor:
         """K fused (micro-)steps in ONE compiled program over the mesh —
         see jit.TrainStep.scan_steps for the full contract (stacked
@@ -913,13 +943,17 @@ class ParallelTrainStep:
                 raise ValueError(
                     f"scan_steps batch leaves must be stacked "
                     f"[{k_steps}, ...]; got shape {b.shape}")
-        prog = self._get_scan_prog(k_steps, raw_batch)
-        base_key = _rng.get_rng_state()
         from ..jit.training import (_quiet_unused_donation,
                                     window_rollback, window_schedule)
-        with window_rollback(self):
-            lrs, step_nos, counts, upd = window_schedule(self, k_steps)
-            with _quiet_unused_donation():
+        n = self.step_count + 1         # the window's first step
+        with _span("train.window", cat="train", step=n, k=k_steps), \
+                window_rollback(self):
+            with _span("train.step.prep", cat="train", step=n):
+                prog = self._get_scan_prog(k_steps, raw_batch)
+                base_key = _rng.get_rng_state()
+                lrs, step_nos, counts, upd = window_schedule(self, k_steps)
+            with _span("train.step.enqueue", cat="train", step=n), \
+                    _quiet_unused_donation():
                 if self.accumulate_steps > 1:
                     (losses, self.params, self.buffers, self.opt_state,
                      self.acc_grads) = prog(

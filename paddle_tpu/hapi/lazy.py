@@ -47,8 +47,8 @@ class LossWindow:
             from ..obs.trace import span as _span
             syncs.record_sync()
             # the window's ONE blocking device read — the "fetch" leg
-            # of the per-window span triplet (prefetch-wait / dispatch
-            # live in hapi.Model's fused loop)
+            # of the per-window spans (`train.prefetch_wait` is
+            # hapi.Model's fused loop's, `train.window` the step's own)
             with _span("train.fetch", cat="train"):
                 self._np = np.asarray(self._dev,
                                       dtype=np.float64).reshape(-1)

@@ -465,10 +465,8 @@ class Model:
                 eff_x0 = x[0] if x else None
 
                 def run_window(x=x, y=y):
-                    with _obs.span("train.dispatch", cat="train",
-                                   k=k):
-                        return LossWindow(
-                            step.scan_steps(k, *x, *y).value)
+                    # scan_steps times itself (`train.window`)
+                    return LossWindow(step.scan_steps(k, *x, *y).value)
 
                 if watchdog is not None:
                     # the K-step window is ONE dispatch: its deadline is

@@ -25,6 +25,7 @@ from jax import lax
 from ..autograd.tape import no_grad
 from ..core.tensor import Tensor
 from ..framework import random as _rng
+from ..obs.trace import span as _span
 from .functional import functional_call, load_state, raw_state, _wrap
 
 __all__ = ["TrainStep"]
@@ -153,8 +154,9 @@ def make_scan_window(fwd, optimizer, k, on_trace, post_update=None):
                 loss, new_bufs, grads = fwd(
                     params, buffers, opt_state, lr, step_no, rng_key,
                     *batch)
-                new_params, new_opt = optimizer.apply_gradients(
-                    params, grads, opt_state, lr=lr, step=step_no)
+                with jax.named_scope("optimizer"):
+                    new_params, new_opt = optimizer.apply_gradients(
+                        params, grads, opt_state, lr=lr, step=step_no)
                 if post_update is not None:
                     new_params = post_update(new_params)
                 return (new_params, new_bufs, new_opt), loss
@@ -180,17 +182,20 @@ def make_scan_window(fwd, optimizer, k, on_trace, post_update=None):
                 *batch)
 
             def apply_br(_):
-                mean = jax.tree_util.tree_map(
-                    lambda a, g: (a + g) / k, acc, grads)
-                new_p, new_o = optimizer.apply_gradients(
-                    params, mean, opt_state, lr=lr, step=step_no)
+                with jax.named_scope("grad_accumulate"):
+                    mean = jax.tree_util.tree_map(
+                        lambda a, g: (a + g) / k, acc, grads)
+                with jax.named_scope("optimizer"):
+                    new_p, new_o = optimizer.apply_gradients(
+                        params, mean, opt_state, lr=lr, step=step_no)
                 if post_update is not None:
                     new_p = post_update(new_p)
                 zeros = jax.tree_util.tree_map(jnp.zeros_like, acc)
                 return new_p, new_o, zeros
 
             def acc_br(_):
-                new_acc = jax.tree_util.tree_map(jnp.add, acc, grads)
+                with jax.named_scope("grad_accumulate"):
+                    new_acc = jax.tree_util.tree_map(jnp.add, acc, grads)
                 return params, opt_state, new_acc
 
             new_p, new_o, new_acc = lax.cond(
@@ -203,6 +208,47 @@ def make_scan_window(fwd, optimizer, k, on_trace, post_update=None):
         return losses, params, buffers, opt_state, acc
 
     return scan_window
+
+
+def remember_trace(step, attr: str, *args) -> None:
+    """Called inside a per-step program's traced body (so once per
+    actual trace): tick the step's trace count and remember which
+    program traced (the attribute that holds it) and at which shapes,
+    as ``ShapeDtypeStruct``s. `op_scopes_of` lowers from them; nothing
+    else reads them. ``step`` is a :class:`TrainStep` or a
+    ``distributed.ParallelTrainStep``."""
+    step._trace_count += 1
+    step._last_traced = (attr, jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args))
+
+
+def op_scopes_of(step) -> Dict[str, str]:
+    """{HLO instruction name: ``op_name`` path} of the per-step program
+    that traced last, at the shapes it traced at: the table that maps a
+    device operation in a profiler's trace to the scope of the program
+    it came from (``analysis.runtime_profile``: `read_scope` reads a
+    path, `by_scope` sums a trace by it).
+
+    Costs nothing until called. Called, it costs one trace of the step
+    and one compile, which the persistent compilation cache turns into
+    a load where it is on; the table is kept for the next call at the
+    same shapes. No step, update or trace counter, learning rate or RNG
+    state moves, and nothing runs on the device."""
+    last = step._last_traced
+    if last is None:
+        raise RuntimeError(
+            "op_scopes(): no per-step program has traced yet; run one "
+            "step (or warm()) first")
+    if step._op_scopes is None or step._op_scopes[0] is not last:
+        from ..analysis.runtime_profile import hlo_op_scopes
+        attr, avals = last
+        count = step._trace_count
+        try:
+            text = getattr(step, attr).lower(*avals).compile().as_text()
+        finally:            # lowering may re-run the traced body's hook
+            step._trace_count, step._last_traced = count, last
+        step._op_scopes = (last, hlo_op_scopes(text))
+    return dict(step._op_scopes[1])
 
 
 class TrainStep:
@@ -259,6 +305,11 @@ class TrainStep:
         # tests assert a drifting-length fused epoch compiles exactly 2
         # programs (scanned window + trailing per-step)
         self._trace_count = 0
+        # (attribute of the per-step program that traced last, its
+        # arguments' ShapeDtypeStructs): what `op_scopes` lowers, and
+        # (that pair, the table made from it) of its last call
+        self._last_traced = None
+        self._op_scopes = None
 
     # ------------------------------------------------------------------
     def _make_step_fn(self):
@@ -282,7 +333,7 @@ class TrainStep:
                 with _rng.rng_guard(rng_key), aux_loss_scope() as auxes:
                     out, new_bufs = functional_call(model, p, buffers,
                                                     *inputs, training=True)
-                    with no_grad():
+                    with no_grad(), jax.named_scope("head_loss"):
                         loss_t = loss_fn(_wrap(out),
                                          *[_wrap(l) for l in labels])
                 loss_v = loss_t.value if isinstance(loss_t, Tensor) else loss_t
@@ -306,11 +357,13 @@ class TrainStep:
         if k == 1:
             def full_step(params, buffers, opt_state, lr, step_no, rng_key,
                           *batch):
-                step_self._trace_count += 1   # fires at trace time only
+                remember_trace(step_self, "_jitted", params, buffers,
+                               opt_state, lr, step_no, rng_key, *batch)
                 loss, new_bufs, grads = step_fn(params, buffers, opt_state,
                                                 lr, step_no, rng_key, *batch)
-                new_params, new_opt = optimizer.apply_gradients(
-                    params, grads, opt_state, lr=lr, step=step_no)
+                with jax.named_scope("optimizer"):
+                    new_params, new_opt = optimizer.apply_gradients(
+                        params, grads, opt_state, lr=lr, step=step_no)
                 return loss, new_params, new_bufs, new_opt
 
             # donate params/buffers/opt-state: they update in place in HBM
@@ -321,21 +374,26 @@ class TrainStep:
         # (call_count % k), so no in-program branch is needed
         def acc_step(params, buffers, opt_state, acc, lr, step_no, rng_key,
                      *batch):
-            step_self._trace_count += 1       # fires at trace time only
+            remember_trace(step_self, "_jitted_acc", params, buffers,
+                           opt_state, acc, lr, step_no, rng_key, *batch)
             loss, new_bufs, grads = step_fn(params, buffers, opt_state,
                                             lr, step_no, rng_key, *batch)
-            new_acc = jax.tree_util.tree_map(jnp.add, acc, grads)
+            with jax.named_scope("grad_accumulate"):
+                new_acc = jax.tree_util.tree_map(jnp.add, acc, grads)
             return loss, new_bufs, new_acc
 
         def apply_step(params, buffers, opt_state, acc, lr, step_no, rng_key,
                        *batch):
-            step_self._trace_count += 1       # fires at trace time only
+            remember_trace(step_self, "_jitted", params, buffers,
+                           opt_state, acc, lr, step_no, rng_key, *batch)
             loss, new_bufs, grads = step_fn(params, buffers, opt_state,
                                             lr, step_no, rng_key, *batch)
-            mean = jax.tree_util.tree_map(
-                lambda a, g: (a + g) / k, acc, grads)
-            new_params, new_opt = optimizer.apply_gradients(
-                params, mean, opt_state, lr=lr, step=step_no)
+            with jax.named_scope("grad_accumulate"):
+                mean = jax.tree_util.tree_map(
+                    lambda a, g: (a + g) / k, acc, grads)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = optimizer.apply_gradients(
+                    params, mean, opt_state, lr=lr, step=step_no)
             zeros = jax.tree_util.tree_map(jnp.zeros_like, acc)
             return loss, new_params, new_bufs, new_opt, zeros
 
@@ -344,36 +402,55 @@ class TrainStep:
 
     # ------------------------------------------------------------------
     def __call__(self, *batch) -> Tensor:
+        """One (micro-)step. Spans, in the ring and in a running
+        profiler session: ``train.step`` with children by containment
+        ``.prep`` (learning rate, step number, key fold-in, batch),
+        ``.enqueue`` (the call of the jitted program: the runtime takes
+        it, or makes the host wait) and ``.post`` (scheduler, wrap)."""
         if self._jitted is None:
             self._build()
         self.step_count += 1
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        rng_key = _rng.default_generator().fold_in(self.step_count)
-        raw_batch = _raw_tuple(batch)
+        n = self.step_count
         k = self.accumulate_steps
-        if k > 1 and self.step_count % k != 0:
-            # micro-step: accumulate grads, no parameter update
-            step_no = jnp.asarray(self.update_count + 1, jnp.float32)
-            loss, self.buffers, self.acc_grads = self._jitted_acc(
-                self.params, self.buffers, self.opt_state, self.acc_grads,
-                lr, step_no, rng_key, *raw_batch)
-            return Tensor(loss)
-        self.update_count += 1
-        step_no = jnp.asarray(self.update_count, jnp.float32)
-        if k > 1:
-            (loss, self.params, self.buffers, self.opt_state,
-             self.acc_grads) = self._jitted(
-                self.params, self.buffers, self.opt_state, self.acc_grads,
-                lr, step_no, rng_key, *raw_batch)
-        else:
-            loss, self.params, self.buffers, self.opt_state = self._jitted(
-                self.params, self.buffers, self.opt_state, lr, step_no,
-                rng_key, *raw_batch)
-        if self.auto_lr_step:
-            lr_sched = getattr(self.optimizer, "_learning_rate", None)
-            if hasattr(lr_sched, "step"):
-                lr_sched.step()
-        return Tensor(loss)
+        micro = k > 1 and n % k != 0    # accumulate grads, no update
+        with _span("train.step", cat="train", step=n,
+                   program="accumulate" if micro else "step"):
+            with _span("train.step.prep", cat="train", step=n):
+                lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
+                rng_key = _rng.default_generator().fold_in(n)
+                raw_batch = _raw_tuple(batch)
+                if not micro:
+                    self.update_count += 1
+                step_no = jnp.asarray(
+                    self.update_count + (1 if micro else 0), jnp.float32)
+            with _span("train.step.enqueue", cat="train", step=n):
+                if micro:
+                    loss, self.buffers, self.acc_grads = self._jitted_acc(
+                        self.params, self.buffers, self.opt_state,
+                        self.acc_grads, lr, step_no, rng_key, *raw_batch)
+                elif k > 1:
+                    (loss, self.params, self.buffers, self.opt_state,
+                     self.acc_grads) = self._jitted(
+                        self.params, self.buffers, self.opt_state,
+                        self.acc_grads, lr, step_no, rng_key, *raw_batch)
+                else:
+                    (loss, self.params, self.buffers,
+                     self.opt_state) = self._jitted(
+                        self.params, self.buffers, self.opt_state, lr,
+                        step_no, rng_key, *raw_batch)
+            with _span("train.step.post", cat="train", step=n):
+                if not micro and self.auto_lr_step:
+                    lr_sched = getattr(self.optimizer, "_learning_rate",
+                                       None)
+                    if hasattr(lr_sched, "step"):
+                        lr_sched.step()
+                out = Tensor(loss)
+        return out
+
+    def op_scopes(self) -> Dict[str, str]:
+        return op_scopes_of(self)
+
+    op_scopes.__doc__ = op_scopes_of.__doc__
 
     # ------------------------------------------------------------------
     # fused K-step window (lax.scan over a stacked super-batch)
@@ -452,11 +529,15 @@ class TrainStep:
                 raise ValueError(
                     f"scan_steps batch leaves must be stacked "
                     f"[{k_steps}, ...]; got shape {b.shape}")
-        prog = self._get_scan_prog(k_steps, len(raw_batch))
-        base_key = _rng.get_rng_state()
-        with window_rollback(self):
-            lrs, step_nos, counts, upd = window_schedule(self, k_steps)
-            with _quiet_unused_donation():
+        n = self.step_count + 1         # the window's first step
+        with _span("train.window", cat="train", step=n, k=k_steps), \
+                window_rollback(self):
+            with _span("train.step.prep", cat="train", step=n):
+                prog = self._get_scan_prog(k_steps, len(raw_batch))
+                base_key = _rng.get_rng_state()
+                lrs, step_nos, counts, upd = window_schedule(self, k_steps)
+            with _span("train.step.enqueue", cat="train", step=n), \
+                    _quiet_unused_donation():
                 if self.accumulate_steps > 1:
                     (losses, self.params, self.buffers, self.opt_state,
                      self.acc_grads) = prog(
@@ -468,7 +549,9 @@ class TrainStep:
                      self.opt_state) = prog(
                         self.params, self.buffers, self.opt_state,
                         base_key, lrs, step_nos, counts, *raw_batch)
-        return Tensor(losses)
+            with _span("train.step.post", cat="train", step=n):
+                out = Tensor(losses)
+        return out
 
     # ------------------------------------------------------------------
     # AOT warmup (paddle_tpu.compilation)

@@ -391,10 +391,12 @@ class GPTForCausalLM(Layer):
             return GPTForCausalLM.loss_fn(outputs, labels)
         hidden, w = outputs
         S = hidden.shape[1]
-        h_s = T.slice(hidden, [1], [0], [S - 1])
-        l_s = T.slice(labels, [1], [1], [S])
-        return F.fused_linear_cross_entropy(h_s, w, l_s,
-                                            chunk_size=chunk_size)
+        # no Layer stands here: named as the trainers name a loss
+        with jax.named_scope("head_loss"):
+            h_s = T.slice(hidden, [1], [0], [S - 1])
+            l_s = T.slice(labels, [1], [1], [S])
+            return F.fused_linear_cross_entropy(h_s, w, l_s,
+                                                chunk_size=chunk_size)
 
 
 class _EmbedStage(Layer):

@@ -79,6 +79,9 @@ class ScannedStack(Layer):
         with LazyGuard():
             self._template = [block_factory()]
         tmpl = self._template[0]
+        # the template stands for every layer: its scope in a traced
+        # program is what an unrolled stack names one, less the index
+        tmpl._scope_name = "block"
         if list(tmpl.named_buffers()):
             raise NotImplementedError(
                 "scan_layers with buffered blocks: buffers are not "

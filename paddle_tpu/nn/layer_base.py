@@ -14,6 +14,7 @@ import collections
 from typing import Callable, Dict, Iterator, Optional, Tuple
 
 import numpy as np
+from jax import named_scope as _named_scope
 
 from ..core.tensor import Parameter, Tensor
 from ..framework.dtype import convert_dtype
@@ -56,6 +57,11 @@ class Layer:
         self.training = True
         self._dtype = dtype
         self._name_scope = name_scope or self.__class__.__name__.lower()
+        # what `__call__` names this layer's operations in a traced
+        # program: the key its parent registers it under, until then
+        # (a root) the class name. No id or counter: the name is part of
+        # a compiled program's cache key
+        self._scope_name = self.__class__.__name__.lower()
         self._forward_pre_hooks = collections.OrderedDict()
         self._forward_post_hooks = collections.OrderedDict()
         self._hook_id = 0
@@ -77,6 +83,7 @@ class Layer:
                 if d is not None and name in d:
                     del d[name]
             subs[name] = value
+            value._scope_name = name
         elif bufs is not None and name in bufs:
             # re-assigning an existing buffer keeps it registered
             if isinstance(value, Tensor):
@@ -140,6 +147,8 @@ class Layer:
 
     def add_sublayer(self, name, sublayer):
         self._sub_layers[str(name)] = sublayer
+        if sublayer is not None:
+            sublayer._scope_name = str(name)
         return sublayer
 
     def register_buffer(self, name, tensor, persistable=True):
@@ -314,7 +323,8 @@ class Layer:
             out = hook(self, inputs)
             if out is not None:
                 inputs = out if isinstance(out, tuple) else (out,)
-        outputs = self.forward(*inputs, **kwargs)
+        with _named_scope(self._scope_name):
+            outputs = self.forward(*inputs, **kwargs)
         for hook in self._forward_post_hooks.values():
             out = hook(self, inputs, outputs)
             if out is not None:

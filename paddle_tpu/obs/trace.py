@@ -1,10 +1,11 @@
 """Span tracer + ring-buffer flight recorder (the obs timeline half).
 
 Every instrumented phase — a request's queue-wait/prefill/decode in the
-engine, a router forward attempt, a training window's
-prefetch-wait/dispatch/fetch, a profiler RecordEvent scope — lands as
-ONE event format: a Chrome-trace complete event (``ph: "X"``, ts/dur in
-microseconds on the ``time.perf_counter`` clock) carrying its
+engine, a router forward attempt, a training step's prep/enqueue/post
+(``jit.TrainStep`` times itself), a compile's trace/lower/backend, a
+profiler RecordEvent scope — lands as ONE event format: a Chrome-trace
+complete event (``ph: "X"``, ts/dur in microseconds on the
+``time.perf_counter`` clock) carrying its
 ``request_id`` and category in ``args``. They all buffer in one
 fixed-size ring (`FlightRecorder`) — always on, bounded memory, no
 per-event I/O — so the answer to "what was this process doing in the
@@ -18,11 +19,14 @@ per-event I/O — so the answer to "what was this process doing in the
   router's replica-death path, and exposed as ``POST /admin/trace`` on
   live servers (`capture`).
 
-Layering: the primitives here (``record_span``/``begin``/``end``)
-ALWAYS record — an explicit call is its own opt-in (profiler
-RecordEvent must work with ambient telemetry off). The ``span()``
-helper is the gated face for ambient instrumentation: with
-``PADDLE_TPU_OBS=0`` it returns one shared no-op singleton — zero
+Layering: the primitives here (``record_span``/``begin``/``end``, the
+``Span`` class) ALWAYS record — an explicit call is its own opt-in
+(``profiler.RecordEvent`` is ``Span`` under its reference name and must
+work with ambient telemetry off). A ``Span`` also enters the
+profiler's own host scope of the same name (`_annotation`), so every
+span of the tree lands in a profiler session's trace on the device's
+clock. The ``span()`` helper is the gated face for ambient
+instrumentation: with ``PADDLE_TPU_OBS=0`` it returns one shared no-op singleton — zero
 allocations on the disabled hot path (counter-asserted in
 tests/test_obs.py). Heavier sites (the engine tick) gate themselves
 once at init instead of per call.
@@ -37,12 +41,13 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
 
-__all__ = ["FlightRecorder", "recorder", "span", "record_span",
+__all__ = ["FlightRecorder", "recorder", "Span", "span", "record_span",
            "begin_span", "end_span", "export_chrome", "dump_flight",
            "capture", "artifact_dir"]
 
@@ -185,23 +190,53 @@ recorder = FlightRecorder(_ring_size())
 # span API
 # ---------------------------------------------------------------------------
 
-class _Span:
-    __slots__ = ("_name", "_cat", "_args", "_token")
+def _annotation(name: str):
+    """The profiler's own host scope of the same name, where jax is
+    already loaded (this package stays stdlib-only to import): the
+    ONE place a ``jax.profiler.TraceAnnotation`` is made. While a
+    profiler session records, the scope lands on the ``/host:CPU``
+    plane of its trace, on the device's clock; outside one it costs a
+    microsecond."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    return jax.profiler.TraceAnnotation(name)
 
-    def __init__(self, name, cat, args):
-        self._name = name
+
+class Span:
+    """One span, written to the ring AND, through `_annotation`, to a
+    running profiler session. Ungated: ``span()`` is the gated face,
+    ``profiler.RecordEvent`` the explicit one."""
+
+    __slots__ = ("name", "_cat", "_args", "_token", "_ann")
+
+    def __init__(self, name, cat="app", args=None):
+        self.name = name
         self._cat = cat
         self._args = args or None
         self._token = None
+        self._ann = None
 
-    def __enter__(self):
-        self._token = recorder.begin(self._name, self._cat, self._args)
-        return self
+    def begin(self):
+        self._token = recorder.begin(self.name, self._cat, self._args)
+        self._ann = _annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
 
-    def __exit__(self, *exc):
+    def end(self):
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if self._token is not None:
             recorder.end(self._token)
             self._token = None
+
+    def __enter__(self):
+        self.begin()
+        return self
+
+    def __exit__(self, *exc):
+        self.end()
         return False
 
 
@@ -226,7 +261,7 @@ def span(name: str, cat: str = "app", **args):
     the shared no-op singleton (identity-testable)."""
     if not _enabled():
         return _NOOP
-    return _Span(name, cat, args)
+    return Span(name, cat, args)
 
 
 def record_span(name: str, t0_s: float, t1_s: float, cat: str = "app",

@@ -46,43 +46,21 @@ class ProfilerTarget(Enum):
     TPU = 3
 
 
-class RecordEvent:
+class RecordEvent(_obs_trace.Span):
     """Host annotation scope.
 
-    Parity: paddle.profiler.RecordEvent (event_tracing.h:43). Doubles as a
-    jax.profiler.TraceAnnotation so the scope shows up inside the XLA
-    xplane trace too. The host side records straight into the obs
-    flight recorder (cat="profiler") — an explicit annotation is its
-    own opt-in, so it records even with ambient telemetry
-    (PADDLE_TPU_OBS) off.
+    Parity: paddle.profiler.RecordEvent (event_tracing.h:43). The
+    ungated face of ``obs.trace.Span``: it records into the obs flight
+    recorder (cat="profiler") and enters the profiler's own host scope,
+    so it shows up inside the XLA xplane trace too. An explicit
+    annotation is its own opt-in, so it records even with ambient
+    telemetry (PADDLE_TPU_OBS) off.
     """
 
+    __slots__ = ()
+
     def __init__(self, name: str, event_type=None):
-        self.name = name
-        self._ann = None
-        self._start = None
-
-    def begin(self):
-        self._start = time.perf_counter()
-        self._ann = jax.profiler.TraceAnnotation(self.name)
-        self._ann.__enter__()
-
-    def end(self):
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
-        if self._start is not None:
-            _obs_trace.record_span(self.name, self._start,
-                                   time.perf_counter(), cat="profiler")
-            self._start = None
-
-    def __enter__(self):
-        self.begin()
-        return self
-
-    def __exit__(self, *exc):
-        self.end()
-        return False
+        super().__init__(name, cat="profiler")
 
 
 def make_scheduler(*, closed: int, ready: int, record: int, repeat: int = 0,

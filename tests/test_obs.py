@@ -217,11 +217,16 @@ def test_disabled_engine_ticks_append_nothing():
     finally:
         obs.set_enabled(None)
     try:
+        # the flag is back on by now, so the compiles a first request
+        # pays land in the ring as `compile.*` spans (the compile
+        # counters listen process-wide): pay them before counting
+        engine.generate([1, 2, 3], max_new_tokens=6, timeout=120)
         before = obs.recorder.appended
         ticks_h = obs.metrics.registry.get("ptpu_engine_ticks_total")
         t0 = ticks_h.value() if ticks_h is not None else 0
+        ticks0 = engine.ticks
         engine.generate([1, 2, 3], max_new_tokens=6, timeout=120)
-        assert engine.ticks > 0
+        assert engine.ticks > ticks0
         assert obs.recorder.appended == before
         if ticks_h is not None:
             assert ticks_h.value() == t0

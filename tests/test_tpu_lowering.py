@@ -19,6 +19,7 @@ a second file could land on another xdist worker and skip in silence):
   chip_smoke.py run. A compile that passes is not a chip run.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -400,6 +401,43 @@ def test_gpt1p3b_train_step_compiles_for_v5e(one_chip, chip_like_config,
         4, 2048, False, one_chip, monkeypatch)
     assert compiled.as_text().count("tpu_custom_call") >= 4
     print("GPT-1.3B one-chip step:", mem)
+
+
+def test_gpt1p3b_width_step_carries_its_scopes_for_v5e(one_chip,
+                                                       chip_like_config,
+                                                       monkeypatch):
+    """Two layers at GPT-1.3B widths, batch 4 x seq 2048, scanned and
+    recomputed as the benchmark's cell runs them: compiled for the
+    described chip, the program's scopes are on its fusions and on its
+    three flash kernels (the forward twice: once recomputed), so a
+    device trace's operations can be summed by them."""
+    from paddle_tpu.analysis import runtime_profile as rp
+    compiled, _ = _compile_train_step_for_v5e(
+        dict(vocab_size=50304, hidden_size=2048, num_layers=2,
+             num_heads=16, max_seq_len=2048, recompute=True,
+             scan_layers=True, fused_loss_chunk=2048),
+        4, 2048, False, one_chip, monkeypatch)
+    text = compiled.as_text()
+    table = rp.hlo_op_scopes(text)
+    kernels = [rp.read_scope(table[n]) for n in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)]
+    assert sorted((k["region"], k["pass"]) for k in kernels) == [
+        ("attn", "backward"), ("attn", "backward"), ("attn", "forward"),
+        ("attn", "recompute")]
+    assert {k["scope"] for k in kernels} == {
+        "gptforcausallm/gpt/blocks/block/attn"}
+    fusions = [rp.read_scope(table[n]) for n in re.findall(
+        r"%([\w.\-]+) = [^\n]* fusion\(", text) if table[n]]
+    assert len(fusions) > 100
+    assert sum(f["region"] == "unscoped" for f in fusions) \
+        < 0.05 * len(fusions)
+    found = {(f["region"], f["pass"]) for f in fusions}
+    assert {("fc_in", "forward"), ("fc_in", "recompute"),
+            ("fc_in", "backward"), ("qkv", "recompute"),
+            ("ln", "backward"), ("head_loss", "forward"),
+            ("head_loss", "backward"), ("optimizer", "update"),
+            ("scan_carry", "backward"),
+            ("word_embeddings", "forward")} <= found
 
 
 # -- the library kernel under a multi-device mesh ---------------------------

@@ -130,6 +130,7 @@ def collect_profiles(programs=None, chip="v5lite", rounds=3, inner=3,
                 raise
             built.append({"name": name, "fn": r.fn, "args": args,
                           "kernels": kernels, "cleanup": r.cleanup,
+                          "op_scopes": rp.hlo_op_scopes(hlo),
                           "geometry": dict(r.geometry),
                           "dispatch_s": []})
 
@@ -155,7 +156,8 @@ def collect_profiles(programs=None, chip="v5lite", rounds=3, inner=3,
                 b["name"], kernels=b["kernels"], events=events,
                 dispatch_s=b["dispatch_s"],
                 dispatches_profiled=profile_dispatches,
-                chip=chip, geometry=b["geometry"], top=top)
+                chip=chip, geometry=b["geometry"],
+                op_scopes=b["op_scopes"], top=top)
     finally:
         for b in built:
             if b["cleanup"] is not None:
