@@ -218,14 +218,16 @@ def phase_kernels(sizes=KERNEL_SIZES) -> None:
             loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
         jax.block_until_ready(g)
         secs = time.perf_counter() - t0
-        backend = fa.last_attention_dispatch().get("backend")
+        dispatch = fa.last_attention_dispatch()
+        backend = dispatch.get("backend")
         (_, rout), rg = jax.jit(jax.value_and_grad(
             ref_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
         e_f = _nerr(out, rout)
         e_b = max(_nerr(a, r) for a, r in zip(g, rg))
         say("kernels", kernel="F.flash_attention", shape=(b, s, h, d),
-            backend=backend, err_fwd=e_f, err_bwd=e_b, tol=(TOL_FWD,
-            TOL_BWD), compile_and_run_s=round(secs, 1))
+            backend=backend, library_kernel=dispatch.get("kernel"),
+            blocks=dispatch.get("blocks"), err_fwd=e_f, err_bwd=e_b,
+            tol=(TOL_FWD, TOL_BWD), compile_and_run_s=round(secs, 1))
         check(backend == ("pallas" if PLATFORM == "tpu" else "xla"),
               f"flash_attention dispatched to {backend!r}")
         check(e_f <= TOL_FWD and e_b <= TOL_BWD,

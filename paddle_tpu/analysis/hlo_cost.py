@@ -222,6 +222,11 @@ def parse_hlo_module(text: str) -> HloModule:
     comps: Dict[str, Computation] = {}
     entry = ""
     cur: Optional[Computation] = None
+    # an instruction's text may run over several lines (the splash
+    # kernel's ``kernel_metadata`` attribute holds its JSON on a line of
+    # its own, and ``metadata={op_name=...}`` follows it): a line that
+    # opens no instruction continues the one before
+    body: List[str] = []
     for line in text.splitlines():
         if cur is None:
             cm = _COMP_RE.match(line)
@@ -230,15 +235,18 @@ def parse_hlo_module(text: str) -> HloModule:
                                   bool(cm.group("entry")))
             continue
         if line.strip() == "}":
+            for ins in filter(None, map(_parse_instr, body)):
+                cur.instrs.append(ins)
+                cur.by_name[ins.name] = ins
+            body = []
             comps[cur.name] = cur
             if cur.entry:
                 entry = cur.name
             cur = None
-            continue
-        ins = _parse_instr(line)
-        if ins is not None:
-            cur.instrs.append(ins)
-            cur.by_name[ins.name] = ins
+        elif body and not _INSTR_RE.match(line):
+            body[-1] += line
+        else:
+            body.append(line)
     if not entry and comps:       # single-computation fixture w/o ENTRY
         entry = next(iter(comps))
     return HloModule(comps, entry)

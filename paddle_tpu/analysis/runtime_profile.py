@@ -388,8 +388,13 @@ def _split_path(path: str) -> List[str]:
     return out
 
 
-def read_scope(path: str) -> dict:
-    """THE rule that reads one ``op_name`` path. Pure.
+def read_scope(path: str, instruction: str = "") -> dict:
+    """THE rule that reads one ``op_name`` path. Pure. ``instruction``
+    is the HLO instruction's own name where the caller has it: a Pallas
+    kernel that was given a name is entered through a scope of that name
+    (the library's wrapper may open it once more) and its instruction
+    carries the name too, so a scope equal to it (less the ``.N``) names
+    the kernel and no layer.
 
     Pass: ``update`` if ``optimizer`` or ``grad_accumulate`` is on the
     path, else ``recompute`` if ``rematted_computation``, else
@@ -415,6 +420,7 @@ def read_scope(path: str) -> dict:
     every norm) groups by ``scope``, which keeps the whole path."""
     raw = _split_path(path.split(";", 1)[0])
     tail, raw = raw[-1], raw[:-1]                   # the primitive
+    own = re.sub(r"(\.\d+)+$", "", instruction.lstrip("%")) or None
     scopes: List[str] = []      # the program's scopes, outermost first
     loops = set()               # indices into `scopes` that hold a loop
     backward = recompute = False
@@ -433,6 +439,8 @@ def read_scope(path: str) -> dict:
         if not tok or tok in _JAX_TOKENS or _BRANCH_RE.match(tok) \
                 or not _NAME_RE.match(tok):
             continue            # JAX's own, or a library's (einsum spec)
+        if tok == own:
+            continue            # the kernel's name, not a layer's
         tok = _INDEX_RE.sub("", tok)
         if tok:
             scopes.append(tok)
@@ -498,7 +506,7 @@ def by_scope(per_op_self_s: Dict[str, float],
         path = op_scopes.get(normalize_kernel_name(name))
         if path is None:
             unknown += secs
-        got = read_scope(path) if path else \
+        got = read_scope(path, name) if path else \
             {"region": "unscoped", "pass": "forward"}
         key = (got["region"], got["pass"])
         rows[key] = rows.get(key, 0.0) + secs

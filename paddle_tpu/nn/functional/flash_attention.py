@@ -2,15 +2,32 @@
 
 Parity: python/paddle/nn/functional/flash_attention.py:20,121 (FlashAttention2
 integration) + scaled_dot_product_attention. TPU-first: on TPU the fused path
-is the Pallas flash-attention kernel (jax.experimental.pallas.ops.tpu) —
-the TPU analog of the reference's dlopened flashattn library
+is the library's splash-attention Pallas kernel
+(jax.experimental.pallas.ops.tpu.splash_attention) -- the TPU analog of the
+reference's dlopened flashattn library
 (paddle/phi/backends/dynload/flashattn.h); elsewhere it falls back to XLA's
 fused attention (jax.nn.dot_product_attention).
+
+The kernel call (``_pallas_flash_local``): one forward kernel and ONE fused
+backward kernel (dq, dk and dv from one look at the scores), a causal or full
+mask whose skipped blocks are a table built at trace time, residuals one
+logsumexp 8 sublanes wide; f32 scores, statistics and accumulation. It takes
+[heads, s, d] and no scale: q is scaled before the call in q's dtype and the
+call is vmapped over the batch, so the kernels' operands are [b, h, s, d].
+Blocks come from ``_splash_blocks``, one rule of the two sequence lengths
+(largest divisor up to 1024, compute blocks up to 512); the kernel object is
+built once a geometry (``_splash_kernel``). Under a multi-device mesh the call
+runs per shard inside a shard_map (``_mesh_wrap``).
+
+``last_attention_dispatch()`` says what the last traced call did:
+``backend`` ("pallas" | "xla"), ``reason``, and on the Pallas path ``kernel``
+("splash_fused") and ``blocks`` ({"q", "kv", "kv_compute"}).
 
 Layout note: paddle flash_attention uses (batch, seqlen, nheads, head_dim).
 """
 from __future__ import annotations
 
+import functools
 import os
 import warnings
 
@@ -29,7 +46,8 @@ __all__ = ["flash_attention", "scaled_dot_product_attention",
            "paged_kv_cache"]
 
 # most recent kernel-dispatch decision — observable, never silent
-# (VERDICT r2 weak #3). {"backend": "pallas"|"xla", "reason": str}
+# (VERDICT r2 weak #3). {"backend": "pallas"|"xla", "reason": str} and,
+# on the Pallas path, {"kernel": str, "blocks": {"q", "kv", "kv_compute"}}
 _last_dispatch = {}
 
 
@@ -102,9 +120,9 @@ def _mega_decode_on() -> bool:
 
 
 def _pallas_geometry_ok(seq: int, d: int, drop: float) -> bool:
-    """Pure geometry gate for the Pallas TPU kernel: seq long enough to
-    tile, head_dim either under one lane tile (kernel broadcasts l/m over
-    min(head_dim, 128)) or a multiple of 128, no attention dropout."""
+    """Pure geometry gate for the Pallas TPU kernel: seq a multiple of
+    the 128-lane tile, head_dim either within one lane tile or a multiple
+    of 128, no attention dropout."""
     return (seq >= 128 and seq % 128 == 0 and (d <= 128 or d % 128 == 0)
             and drop == 0.0)
 
@@ -152,6 +170,7 @@ def _mesh_wrap(shape):
 
 
 def _pallas_ok(q, d, drop):
+    _last_dispatch.clear()      # a record of this call, none of an earlier
     if not _on_tpu():
         _last_dispatch.update(backend="xla", reason="not on TPU")
         if _require_pallas():
@@ -186,7 +205,8 @@ def _pallas_ok(q, d, drop):
 
 def _pallas_flash(q, k, v, causal, scale):
     """The library kernel on [b, s, h, d] operands; under a multi-device
-    mesh, per shard inside a shard_map (``_mesh_wrap``)."""
+    mesh, per shard inside a shard_map (``_mesh_wrap``), where the kernel
+    is built from the shard's own head count."""
     mesh, spec, _ = _mesh_wrap(q.shape)
     if mesh is None:
         return _pallas_flash_local(q, k, v, causal, scale)
@@ -196,33 +216,65 @@ def _pallas_flash(q, k, v, causal, scale):
         check_vma=False)(q, k, v)
 
 
+def _blk(n: int, cap: int) -> int:
+    """Largest multiple of 128 up to ``cap`` that divides ``n``."""
+    b = min(cap, n)
+    while n % b:
+        b -= 128
+    return b
+
+
+def _splash_blocks(s_q: int, s_k: int) -> dict:
+    """THE block rule, from what the call can see: memory blocks the
+    largest divisor of the sequence up to 1024, compute blocks up to 512,
+    one fused dq+dkv backward kernel. Read on the chip at heads of 64 on
+    a sequence of 1024 and heads of 128 on 2048 (PERF.md section 6,
+    PR 29): both want the same, so the rule reads no head size. Smaller
+    memory blocks skip more of the causal half but make the fused
+    backward write one partial dq a kv block, and summing them costs more
+    than the skipping saves; dq and dkv apart look at the scores twice; a
+    whole 2048 is refused for VMEM."""
+    bq, bkv = _blk(s_q, 1024), _blk(s_k, 1024)
+    bkv_c = _blk(bkv, 512)
+    return dict(block_q=bq, block_kv=bkv, block_kv_compute=bkv_c,
+                block_q_dkv=bq, block_kv_dkv=bkv,
+                block_kv_dkv_compute=bkv_c, use_fused_bwd_kernel=True)
+
+
+@functools.lru_cache(maxsize=64)
+def _splash_kernel(heads, s_q, s_k, causal, interpret):
+    """The library's splash kernel for one [heads, s, d] attention. Its
+    mask tables are numpy work at trace time, so one object serves every
+    layer and every later trace of the same geometry."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+    mask = (sm.CausalMask if causal else sm.FullMask)((s_q, s_k))
+    # the tables become device constants here, not values of whatever
+    # trace (jit, shard_map, remat) first asked for this geometry
+    with jax.ensure_compile_time_eval():
+        return sk.make_splash_mha_single_device(
+            sm.MultiHeadMask([mask] * heads),
+            block_sizes=sk.BlockSizes(**_splash_blocks(s_q, s_k)),
+            interpret=interpret)
+
+
 def _pallas_flash_local(q, k, v, causal, scale):
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        BlockSizes, flash_attention as pallas_flash)
-    # pallas kernel expects (b, h, s, d)
+    # the kernel works on [h, s, d], one batch row a call; vmap puts the
+    # batch back in front, so its operands are [b, h, s, d]
     qh = jnp.swapaxes(q, 1, 2)
     kh = jnp.swapaxes(k, 1, 2)
     vh = jnp.swapaxes(v, 1, 2)
     s_q, s_k = qh.shape[2], kh.shape[2]
-
-    # The kernel's default backward block sizes are 128, which leaves the
-    # MXU starved (profiled: dkv/dq passes dominate the step). Use the
-    # largest block that divides the sequence, capped at 512 (VMEM stays
-    # modest at head_dim<=128); ~3x faster on the GPT-125M bench.
-    def blk(n, cap=512):
-        b = min(cap, n)
-        while n % b:
-            b -= 128
-        return b
-    block_sizes = BlockSizes(
-        block_q=blk(s_q, 512), block_k_major=blk(s_k, 512),
-        block_k=blk(s_k, 512), block_b=1,
-        block_q_major_dkv=blk(s_q, 512), block_k_major_dkv=blk(s_k, 512),
-        block_k_dkv=blk(s_k, 512), block_q_dkv=blk(s_q, 512),
-        block_k_major_dq=blk(s_k, 512), block_k_dq=blk(s_k, 512),
-        block_q_dq=blk(s_q, 512))
-    out = pallas_flash(qh, kh, vh, causal=causal, sm_scale=scale,
-                       block_sizes=block_sizes)
+    kernel = _splash_kernel(qh.shape[1], s_q, s_k, bool(causal),
+                            not _on_tpu())
+    blocks = _splash_blocks(s_q, s_k)
+    _last_dispatch.update(
+        kernel="splash_fused" if blocks["use_fused_bwd_kernel"]
+        else "splash",
+        blocks={"q": blocks["block_q"], "kv": blocks["block_kv"],
+                "kv_compute": blocks["block_kv_compute"]})
+    # the kernel takes no scale: q carries it, in q's dtype
+    out = jax.vmap(kernel)(qh * jnp.asarray(scale, qh.dtype), kh, vh)
     return jnp.swapaxes(out, 1, 2)
 
 

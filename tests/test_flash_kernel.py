@@ -1,4 +1,5 @@
-"""Pallas blockwise flash kernel (kernels/flash_block.py) + fused ring path.
+"""Pallas blockwise flash kernel (kernels/flash_block.py) + fused ring path,
+and the library's splash kernel as nn/functional/flash_attention.py calls it.
 
 Runs in interpret mode on the CPU mesh; the same code compiles on TPU.
 Reference semantics: paddle/phi/kernels/gpu/flash_attn_kernel.cu (fused
@@ -112,6 +113,84 @@ def test_attention_dispatch_gate_at_bench_geometry():
     F.flash_attention(q, q, q)[0]
     d = last_attention_dispatch()
     assert d["backend"] == "xla" and "TPU" in d["reason"]
+
+
+def _plain_attention(q, k, v, causal, scale):
+    """Plain f32 softmax attention on [b, s, h, d]; the causal mask is
+    aligned to the first row and column, as the kernel's is."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+
+
+@pytest.mark.parametrize("s_q,s_k", [(256, 256), (128, 384)])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("d", [64, 128])
+def test_splash_call_matches_plain_attention(d, causal, s_q, s_k):
+    """_pallas_flash_local itself (interpret mode off the chip): the
+    scaling of q, the layout changes, the vmap over the batch and the
+    fused backward, against plain attention in all three gradients."""
+    from paddle_tpu.nn.functional.flash_attention import _pallas_flash_local
+    B, H = 2, 3
+    q = _rand(B, s_q, H, d, seed=1)
+    k, v = _rand(B, s_k, H, d, seed=2), _rand(B, s_k, H, d, seed=3)
+    co = _rand(B, s_q, H, d, seed=4)
+    scale = 1.0 / np.sqrt(d)
+
+    def run(attn):
+        return jax.value_and_grad(
+            lambda q, k, v: (attn(q, k, v, causal, scale) * co).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    (out, grads), (ro, rg) = run(_pallas_flash_local), run(_plain_attention)
+    np.testing.assert_allclose(float(out), float(ro), rtol=2e-5)
+    for g, r in zip(grads, rg):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-5)
+
+
+def test_splash_kernel_is_built_once_a_geometry():
+    """Mask tables are numpy work at trace time: twelve unrolled layers,
+    and every later trace, share one kernel object."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.nn.functional.flash_attention")
+    q = jax.ShapeDtypeStruct((2, 256, 4, 64), jnp.float32)
+
+    def two_layers(q, k, v):
+        o = fa._pallas_flash_local(q, k, v, True, 0.125)
+        return fa._pallas_flash_local(o, k, v, True, 0.125)
+
+    fa._splash_kernel.cache_clear()
+    jax.eval_shape(two_layers, q, q, q)
+    jax.eval_shape(jax.grad(lambda *a: two_layers(*a).sum()), q, q, q)
+    info = fa._splash_kernel.cache_info()
+    assert (info.misses, info.currsize) == (1, 1) and info.hits == 3
+    jax.eval_shape(lambda q, k, v: fa._pallas_flash_local(
+        q, k, v, False, 0.125), q, q, q)            # another mask: another
+    assert fa._splash_kernel.cache_info().currsize == 2
+
+
+def test_dispatch_record_names_kernel_and_blocks(monkeypatch):
+    """What the driver prints and chip_smoke.py records: backend, the
+    library kernel that engaged and the blocks the rule gave it."""
+    import importlib
+
+    import paddle_tpu.nn.functional as F
+    from paddle_tpu.core.tensor import Tensor
+    fa_mod = importlib.import_module(
+        "paddle_tpu.nn.functional.flash_attention")
+    monkeypatch.setattr(fa_mod, "_on_tpu", lambda: True)
+    for (s, d), blocks in {
+            (1024, 64): {"q": 1024, "kv": 1024, "kv_compute": 512},
+            (2048, 128): {"q": 1024, "kv": 1024, "kv_compute": 512},
+            (384, 64): {"q": 384, "kv": 384, "kv_compute": 384}}.items():
+        q = jax.ShapeDtypeStruct((2, s, 4, d), jnp.bfloat16)
+        # traced only: off the chip nothing can run the compiled kernel
+        jax.eval_shape(lambda q, k, v: F.flash_attention(
+            Tensor(q), Tensor(k), Tensor(v), causal=True)[0].value, q, q, q)
+        rec = fa_mod.last_attention_dispatch()
+        assert rec["backend"] == "pallas" and rec["reason"] == "ok"
+        assert rec["kernel"] == "splash_fused" and rec["blocks"] == blocks
 
 
 def test_require_pallas_flag_raises(monkeypatch):

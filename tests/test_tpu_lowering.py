@@ -13,13 +13,14 @@ a second file could land on another xdist worker and skip in silence):
   this depth and were all refused by the compiler).
 - ``.lower(...).compile()`` against a DESCRIBED v5e (``topo`` fixture):
   what the chip's compiler would say, at the real widths — every kernel
-  in paddle_tpu/kernels/, the library flash call at both bench
+  in paddle_tpu/kernels/, the library splash call at both bench
   geometries, the engine's decode ticks at 1.3B widths, and (marked
   slow) the whole GPT-125M and GPT-1.3B train steps bench.py and
   chip_smoke.py run. A compile that passes is not a chip run.
 """
 import functools
 import re
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +67,7 @@ def chip_like_config():
     read back without a chip (the next one warns and recompiles) — keep
     these compiles out of it. (2) tests/conftest.py asks for "highest"
     matmul precision for the CPU numerics; a program on the chip runs
-    jax's default, and the library flash kernel's bf16 dots are REFUSED
+    jax's default, and the library attention kernel's bf16 dots are REFUSED
     at fp32 contract precision ("Bad lhs type") — a fault of the test
     environment, not of the kernel."""
     from jax.experimental.compilation_cache import compilation_cache
@@ -140,8 +141,9 @@ def _export_train_step_for_tpu(step, batch=(2, 256)):
 
 def test_gpt_train_step_with_pallas_attention_lowers_for_tpu(monkeypatch):
     """The exact bench path: full donated GPT train step with the library
-    pallas flash attention (dispatch forced as on a real TPU backend),
-    cross-lowered for the TPU target — fwd + dq + dkv Mosaic payloads."""
+    splash attention (dispatch forced as on a real TPU backend),
+    cross-lowered for the TPU target — the forward and the fused backward
+    Mosaic payloads."""
     import importlib
     import paddle_tpu as paddle
     from paddle_tpu.jit import TrainStep
@@ -158,7 +160,7 @@ def test_gpt_train_step_with_pallas_attention_lowers_for_tpu(monkeypatch):
                                  parameters=model.parameters())
     step = TrainStep(model, GPTForCausalLM.loss_fn, opt)
     exp = _export_train_step_for_tpu(step)
-    assert exp.mlir_module().count("tpu_custom_call") == 3
+    assert exp.mlir_module().count("tpu_custom_call") == 2
     assert fa.last_attention_dispatch()["backend"] == "pallas"
 
 
@@ -189,9 +191,9 @@ def test_gpt_1p3b_shaped_step_lowers_for_tpu(monkeypatch, policy):
                                  parameters=model.parameters())
     step = TrainStep(model, model.make_loss_fn(), opt)
     exp = _export_train_step_for_tpu(step)
-    # scan body compiles ONCE (depth-independent): fwd + dq + dkv, plus
-    # the remat'd bwd replaying the fwd kernel = 4 Mosaic payloads
-    assert exp.mlir_module().count("tpu_custom_call") == 4
+    # scan body compiles ONCE (depth-independent): fwd + fused bwd, plus
+    # the remat'd bwd replaying the fwd kernel = 3 Mosaic payloads
+    assert exp.mlir_module().count("tpu_custom_call") == 3
     assert fa.last_attention_dispatch()["backend"] == "pallas"
 
 
@@ -221,16 +223,18 @@ def _flash_block(shape, bwd):
 
 
 def _library_flash(shape):
-    """flash_attention._pallas_flash: the library kernel with this
-    repo's block sizes, forward and both backward kernels."""
+    """flash_attention._pallas_flash: the library's splash kernel with
+    this repo's block rule, the forward and the one fused backward."""
     import importlib
     fa = importlib.import_module("paddle_tpu.nn.functional.flash_attention")
     scale = 1.0 / shape[-1] ** 0.5
 
     def loss(q, k, v):
-        return fa._pallas_flash(q, k, v, True, scale).astype(
-            jnp.float32).sum()
-    return jax.grad(loss, argnums=(0, 1, 2)), [_sd(shape, BF16)] * 3, 3
+        # traced as on the chip: off it the kernel is built to interpret
+        with mock.patch.object(fa, "_on_tpu", lambda: True):
+            return fa._pallas_flash(q, k, v, True, scale).astype(
+                jnp.float32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2)), [_sd(shape, BF16)] * 3, 2
 
 
 def _slot_write(dtype, tail):
@@ -374,7 +378,7 @@ def test_gpt125m_train_step_compiles_for_v5e(scan, one_chip,
         dict(vocab_size=50304, hidden_size=768, num_layers=12,
              num_heads=12, max_seq_len=1024, scan_layers=scan),
         8, 1024, True, one_chip, monkeypatch)
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert compiled.as_text().count("tpu_custom_call") >= 2
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
@@ -399,7 +403,7 @@ def test_gpt1p3b_train_step_compiles_for_v5e(one_chip, chip_like_config,
              max_seq_len=2048, recompute=True, scan_layers=True,
              fused_loss_chunk=2048),
         4, 2048, False, one_chip, monkeypatch)
-    assert compiled.as_text().count("tpu_custom_call") >= 4
+    assert compiled.as_text().count("tpu_custom_call") >= 3
     print("GPT-1.3B one-chip step:", mem)
 
 
@@ -409,8 +413,8 @@ def test_gpt1p3b_width_step_carries_its_scopes_for_v5e(one_chip,
     """Two layers at GPT-1.3B widths, batch 4 x seq 2048, scanned and
     recomputed as the benchmark's cell runs them: compiled for the
     described chip, the program's scopes are on its fusions and on its
-    three flash kernels (the forward twice: once recomputed), so a
-    device trace's operations can be summed by them."""
+    two attention kernels (the forward twice: once recomputed; one fused
+    backward), so a device trace's operations can be summed by them."""
     from paddle_tpu.analysis import runtime_profile as rp
     compiled, _ = _compile_train_step_for_v5e(
         dict(vocab_size=50304, hidden_size=2048, num_layers=2,
@@ -419,11 +423,10 @@ def test_gpt1p3b_width_step_carries_its_scopes_for_v5e(one_chip,
         4, 2048, False, one_chip, monkeypatch)
     text = compiled.as_text()
     table = rp.hlo_op_scopes(text)
-    kernels = [rp.read_scope(table[n]) for n in re.findall(
+    kernels = [rp.read_scope(table[n], n) for n in re.findall(
         r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)]
     assert sorted((k["region"], k["pass"]) for k in kernels) == [
-        ("attn", "backward"), ("attn", "backward"), ("attn", "forward"),
-        ("attn", "recompute")]
+        ("attn", "backward"), ("attn", "forward"), ("attn", "recompute")]
     assert {k["scope"] for k in kernels} == {
         "gptforcausallm/gpt/blocks/block/attn"}
     fusions = [rp.read_scope(table[n]) for n in re.findall(
@@ -466,6 +469,32 @@ def test_mesh_wrap_decides_from_the_trace_time_mesh():
     assert "sp" in fa._mesh_wrap(shape)[2]
 
 
+def test_kernel_inside_the_shard_map_is_built_for_the_shard(topo,
+                                                            chip_like_config,
+                                                            monkeypatch):
+    """tp=4 on the described chips: _pallas_flash wraps the call in a
+    shard_map over batch and heads, and the kernel (its mask tables are a
+    row a head) is built inside it from the shard's own head count."""
+    import importlib
+    from jax.sharding import NamedSharding
+    fa = importlib.import_module("paddle_tpu.nn.functional.flash_attention")
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    dist.init_mesh({"dp": 2, "mp": 2}, devices=list(topo.devices))
+    mesh, spec, _ = fa._mesh_wrap((4, 1024, 8, 64))
+    built = []
+    real = fa._splash_kernel.__wrapped__
+    monkeypatch.setattr(fa, "_splash_kernel", lambda heads, *a: (
+        built.append(heads), real(heads, *a))[1])
+    x = _sd((4, 1024, 8, 64), BF16, sharding=NamedSharding(mesh, spec))
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v: fa._pallas_flash(q, k, v, True, 0.125).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))).lower(x, x, x).compile()
+    assert set(built) == {4}                       # 8 heads over mp=2
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "bf16[2,4,1024,64]" in text             # the shard's operands
+
+
 def test_zero3_train_step_compiles_for_four_v5e_chips(topo,
                                                       chip_like_config,
                                                       monkeypatch):
@@ -493,5 +522,5 @@ def test_zero3_train_step_compiles_for_four_v5e_chips(topo,
     compiled = step.aot_compile(ids, ids)
     assert "shard_map" in fa.last_attention_dispatch()["reason"]
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 3
+    assert text.count("tpu_custom_call") >= 2
     assert "all-gather" in text            # the stage-3 weight gathers

@@ -235,6 +235,26 @@ RULE_CASES = {
 }
 
 
+def test_a_named_kernels_scope_is_no_layer():
+    """The splash kernel is entered through scopes of its own name, which
+    its instruction carries too: with the instruction's name the rule
+    reads the layer; without it, or for another instruction, the path."""
+    path = (J + "transpose(jvp(gptforcausallm))/gpt/blocks/while/body/"
+            "closed_call/checkpoint/block/attn/vmap(jit(_splash_attention))/"
+            "splash_mha_dkv_no_residuals/splash_mha_dkv_no_residuals/"
+            "pallas_call")
+    want = {"pass": "backward", "region": "attn",
+            "scope": "gptforcausallm/gpt/blocks/block/attn"}
+    assert rp.read_scope(path, "splash_mha_dkv_no_residuals.9") == want
+    assert rp.read_scope(path, "%splash_mha_dkv_no_residuals") == want
+    assert rp.read_scope(path)["region"] == "splash_mha_dkv_no_residuals"
+    assert rp.read_scope(path, "fusion.9")["region"] \
+        == "splash_mha_dkv_no_residuals"
+    got = rp.by_scope({"%splash_mha_dkv_no_residuals.9": 2.0},
+                      {"splash_mha_dkv_no_residuals.9": path})
+    assert got["rows"][0]["region"] == "attn"
+
+
 @pytest.mark.parametrize("case", sorted(RULE_CASES))
 def test_the_rule_reads_a_path(case):
     path, pass_, scope, region = RULE_CASES[case]
@@ -276,6 +296,26 @@ def test_the_table_from_text_and_its_fallbacks():
     assert table["fusion.3"].endswith("mlp/neg")    # callee's first named
     assert table["copy.4"] == table["fusion.3"]     # its operand's
     assert table["a"] == "a" and table["p0"] == ""
+
+
+def test_an_instruction_broken_over_lines_keeps_its_path():
+    """The splash kernel's custom call prints its ``kernel_metadata`` JSON
+    on lines of its own, with ``metadata={op_name=...}`` after them: the
+    kernel takes its own path, not its first operand's (a mask table)."""
+    text = """HloModule m
+ENTRY %main (p0: s8[2]) -> f32[8] {
+  %p0 = s8[2]{0} parameter(0)
+  %tab = s8[2]{0} copy(%p0), metadata={op_name="jit(step)/blocks/while/body"}
+  %kern.1 = f32[8]{0} custom-call(%tab), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={
+"xprof_metadata":"{\\"block_q\\": 1024}"
+}}, metadata={op_name="jit(step)/jvp(net)/blocks/block/attn/pallas_call"}
+  ROOT %after = f32[8]{0} negate(%kern.1), metadata={op_name="jit(step)/jvp(net)/blocks/block/mlp/neg"}
+}
+"""
+    table = rp.hlo_op_scopes(text)
+    assert list(table) == ["p0", "tab", "kern.1", "after"]
+    assert table["kern.1"].endswith("block/attn/pallas_call")
+    assert table["after"].endswith("mlp/neg")
 
 
 def test_by_scope_on_a_made_up_trace():
