@@ -88,6 +88,8 @@ SERVE = dict(
     prompt_lens=(32, 200, 512, 1024), new_tokens=64)
 KERNEL_SIZES = dict(
     flash=((8, 1024, 12, 64), (4, 2048, 16, 128)),     # (b, s, h, d)
+    # (b, s, h, kv heads, d, window): Trinity-Mini's window and full layers
+    flash_gqa=((2, 8192, 32, 4, 128, 2048), (2, 8192, 32, 4, 128, None)),
     flash_block=((1, 12, 1024, 64), (1, 16, 2048, 128)),  # (b, h, s, d)
     cache=(8, 2048, 16, 128),                          # (B, L, nkv, hd)
     pool=(1024, 16, 16, 128),                          # (NP, PS, nkv, hd)
@@ -181,6 +183,33 @@ def _ref_attention(q, k, v, causal, scale):
     return out, lse
 
 
+def _ref_attention_grouped(q, k, v, window, scale):
+    """Plain f32 causal attention with the mask written out, [b, s, h, d]
+    q on [b, s, kv, d] keys (query head h reads key/value head
+    h // group), optionally in a window of ``window`` keys: one batch row
+    and key/value head at a time, recomputed in the backward pass, so
+    that the scores of the real sizes fit."""
+    import jax
+    import jax.numpy as jnp
+    b, s, h, d = q.shape
+    kv = k.shape[2]
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    mask = (j <= i) if window is None else (j <= i) & (i - j < window)
+
+    @jax.checkpoint
+    def one(qg, kg, vg):            # [group, s, d], [s, d], [s, d]
+        lg = jnp.einsum("gqd,kd->gqk", qg, kg, precision="highest") * scale
+        p = jax.nn.softmax(jnp.where(mask, lg, -1e30), axis=-1)
+        return jnp.einsum("gqk,kd->gqd", p, vg, precision="highest")
+
+    qf = jnp.moveaxis(q.astype(jnp.float32), 1, 2).reshape(
+        b * kv, h // kv, s, d)
+    kf, vf = (jnp.moveaxis(x.astype(jnp.float32), 1, 2).reshape(b * kv, s, d)
+              for x in (k, v))
+    out = jax.lax.map(lambda a: one(*a), (qf, kf, vf))
+    return jnp.moveaxis(out.reshape(b, h, s, d), 1, 2)
+
+
 def phase_kernels(sizes=KERNEL_SIZES) -> None:
     dev = require_device()
     import jax
@@ -232,6 +261,43 @@ def phase_kernels(sizes=KERNEL_SIZES) -> None:
               f"flash_attention dispatched to {backend!r}")
         check(e_f <= TOL_FWD and e_b <= TOL_BWD,
               f"F.flash_attention {b, s, h, d}: {e_f}, {e_b}")
+
+    # the same functional with a causal window and grouped key/value
+    # heads, against the plain mask
+    for (b, s, h, kv, d, window) in sizes.get("flash_gqa", ()):
+        q, w = rnd((b, s, h, d)), rnd((b, s, h, d))
+        k, v = rnd((b, s, kv, d)), rnd((b, s, kv, d))
+
+        def loss(q, k, v):
+            out = F.flash_attention(q, k, v, causal=True,
+                                    window=window)[0].value
+            return (out.astype(jnp.float32)
+                    * w.astype(jnp.float32)).sum(), out
+
+        def ref_loss(q, k, v):
+            out = _ref_attention_grouped(q, k, v, window, 1.0 / d ** 0.5)
+            return (out * w.astype(jnp.float32)).sum(), out
+
+        (_, out), g = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        dispatch = fa.last_attention_dispatch()
+        (_, rout), rg = jax.jit(jax.value_and_grad(
+            ref_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        e_f = _nerr(out, rout)
+        e_b = max(_nerr(a, r) for a, r in zip(g, rg))
+        say("kernels", kernel="F.flash_attention", shape=(b, s, h, d),
+            kv_heads=dispatch.get("kv_heads"), window=dispatch.get("window"),
+            backend=dispatch.get("backend"),
+            library_kernel=dispatch.get("kernel"),
+            blocks=dispatch.get("blocks"), err_fwd=e_f, err_bwd=e_b,
+            tol=(TOL_FWD, TOL_BWD))
+        check(dispatch.get("backend")
+              == ("pallas" if PLATFORM == "tpu" else "xla")
+              and dispatch.get("kv_heads") == kv
+              and dispatch.get("window") == window,
+              f"windowed grouped flash_attention dispatched as {dispatch}")
+        check(e_f <= TOL_FWD and e_b <= TOL_BWD,
+              f"F.flash_attention {b, s, h, kv, d, window}: {e_f}, {e_b}")
 
     # kernels/flash_block.py: the ring/Ulysses block kernel with LSE
     for (b, h, s, d) in sizes["flash_block"]:

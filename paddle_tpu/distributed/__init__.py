@@ -20,7 +20,8 @@ from .mesh import (Mesh, PartitionSpec, get_mesh, init_mesh, mesh_axis_size,
 from .parallel import DataParallel, init_parallel_env, is_initialized, \
     shard_batch
 from .parallel_step import ParallelTrainStep, param_sharding, shard_params
-from .moe import GShardGate, MoELayer, NaiveGate, SwitchGate
+from .moe import (GShardGate, MoELayer, NaiveGate, SwitchGate,
+                  TokenChoiceMoE, last_moe_dispatch)
 from .recompute import recompute, recompute_sequential
 from .sequence_parallel import (ring_attention, shard_sequence,
                                 ulysses_attention)
@@ -84,7 +85,8 @@ __all__ = [
     "DistributedStrategy", "CommunicateTopology", "HybridCommunicateGroup",
     "get_hybrid_communicate_group", "set_hybrid_communicate_group",
     "ParallelTrainStep", "param_sharding", "shard_params", "fleet",
-    "MoELayer", "SwitchGate", "GShardGate", "NaiveGate",
+    "MoELayer", "SwitchGate", "GShardGate", "NaiveGate", "TokenChoiceMoE",
+    "last_moe_dispatch",
     "recompute", "recompute_sequential",
     "save_state_dict", "load_state_dict", "verify_checkpoint", "TCPStore",
     "list_checkpoints", "latest_checkpoint", "gc_checkpoints",

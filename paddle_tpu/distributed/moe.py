@@ -10,13 +10,25 @@ dispatch mask — when the E dim is sharded, GSPMD lowers exactly the
 all-to-all pair the reference implements as explicit collective ops.
 Capacity-bounded top-1 (Switch) and top-2 (GShard) gates with the standard
 load-balancing auxiliary loss.
+
+Beside it, ``TokenChoiceMoE``: the dropless token-choice layer of today's
+sparse decoders (sigmoid scores, top-k of many, a bias that balances the
+load without an auxiliary loss, a shared expert). It is told which of the
+published experts it holds, routes over all of them, sorts the assignments
+that land here by expert and multiplies each group with its expert in one
+grouped product. Which layer when: ``MoELayer`` is the Paddle parity API
+(softmax gate, top-1/top-2, capacity, GELU experts with biases) and
+materialises [tokens, experts, capacity] tensors, so it is for few experts
+and short batches; ``TokenChoiceMoE`` is for everything else.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..autograd import tape as _tape
 from ..core.tensor import Parameter, Tensor
@@ -25,7 +37,8 @@ from ..nn import initializer as I
 from ..nn.layer_base import Layer
 from . import mesh as mesh_mod
 
-__all__ = ["MoELayer", "SwitchGate", "GShardGate", "NaiveGate"]
+__all__ = ["MoELayer", "SwitchGate", "GShardGate", "NaiveGate",
+           "TokenChoiceMoE", "last_moe_dispatch"]
 
 
 class _BaseGate(Layer):
@@ -182,3 +195,284 @@ class MoELayer(Layer):
             aux.value if hasattr(aux, "value") else aux))
         self._l_aux = aux   # tpulint: disable=traced-attr-mutation
         return out
+
+
+# ---------------------------------------------------------------------------
+# dropless token-choice layer
+# ---------------------------------------------------------------------------
+
+# what the last traced ``TokenChoiceMoE`` call did (as
+# ``F.last_attention_dispatch()`` for attention): {"kernel",
+# "experts_held", "experts_published", "top_k", "rows_bound"}
+_last_moe = {}
+
+
+def last_moe_dispatch() -> dict:
+    """The most recent ``TokenChoiceMoE`` dispatch: ``kernel`` (what
+    multiplies the sorted rows with their experts), ``experts_held`` of
+    ``experts_published``, ``top_k``, and ``rows_bound`` (the sorted rows
+    the grouped product is built for; more assignments than that landing
+    here take the dense path, none is dropped)."""
+    return dict(_last_moe)
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+# rows, contraction and columns a tile of the grouped product: read on
+# the chip at [32768, 2048] x [16, 2048, 1024] (PERF.md section 6, PR 30)
+_GMM_TILING = (512, 1024, 1024)
+
+# sorted rows the grouped product is built for, in even shares of the
+# assignments (what a uniform routing lands on the experts held here).
+# Sized from what landed on the chip (PERF.md section 6, PR 30): a router
+# trained from a random start without a warm-up put up to 2.2 even shares
+# on one layer's held experts within 25 steps, and at 2 shares two seeds
+# of nine took the dense path for some steps. The gather, scatter-add
+# and masks round the products cost by this bound, not by what lands:
+# 3 shares for 2 cost 4.0% of the step there
+_ROWS_OVER_EVEN = 3
+
+
+def _grouped_dot(lhs, rhs, sizes):
+    """lhs [rows, k] sorted by group, rhs [groups, k, n], sizes [groups]
+    -> [rows, n]: rows of group g times rhs[g]. Rows past the groups'
+    sum hold no result. On the chip the library's megablox kernel, which
+    visits the tiles that hold rows and no others; elsewhere XLA's
+    ragged dot."""
+    if _on_tpu():
+        from jax.experimental.pallas.ops.tpu.megablox import ops as mb
+        tm, tk, tn = _GMM_TILING
+        return mb.gmm(lhs, rhs, sizes, lhs.dtype,
+                      (min(tm, lhs.shape[0]), tk, tn))
+    return lax.ragged_dot(lhs, rhs, sizes)
+
+
+@jax.custom_vjp
+def _sorted_weights(wgt, slot, pos):
+    """wgt [T, k] -> the weight of each sorted row, wgt.ravel()[slot].
+    Differentiated as it stands it would scatter scalars one at a time;
+    its transpose is written as the gather it is (``pos``: where each
+    assignment stands among the rows, len(slot) for one that does not)."""
+    return wgt.reshape(-1)[slot]
+
+
+def _sorted_weights_fwd(wgt, slot, pos):
+    return wgt.reshape(-1)[slot], pos
+
+
+def _sorted_weights_bwd(pos, d_rows):
+    return jnp.concatenate([d_rows, jnp.zeros((1,), d_rows.dtype)])[pos], \
+        None, None
+
+
+_sorted_weights.defvjp(_sorted_weights_fwd, _sorted_weights_bwd)
+
+
+def _swiglu(x, w1, w3, w2, dot):
+    return dot(jax.nn.silu(dot(x, w1)) * dot(x, w3), w2)
+
+
+def _routed_sorted(x, w1, w3, w2, wgt, local, here, rows):
+    """The held experts' part for x [T, d] by sorted rows: ``rows`` of
+    them, which must hold every assignment that landed here."""
+    T, k = local.shape
+    held = w1.shape[0]
+    key = jnp.where(here, local, held).reshape(-1)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    bounds = jnp.searchsorted(key[order], jnp.arange(held + 1,
+                                                     dtype=key.dtype))
+    sizes = jnp.diff(bounds).astype(jnp.int32)
+    landed = bounds[held]
+    # where each assignment stands in the sorted order; `rows` (the row
+    # of noughts) for one that is held elsewhere
+    pos = jnp.argsort(order).astype(jnp.int32).reshape(T, k)
+    pos = jnp.where(here & (pos < rows), pos, rows)
+    slot = jnp.pad(order, (0, max(0, rows - T * k)))[:rows]
+    tok = slot // k
+    dot = functools.partial(_grouped_dot, sizes=sizes)
+    # rows past the groups are written by no product, forward or
+    # backward, and hold whatever the buffer held: nought on both sides
+    live = (jnp.arange(rows) < landed)[:, None]
+    y = _swiglu(jnp.where(live, x[tok], 0), w1, w3, w2, dot)
+    y = jnp.where(live, y, 0)
+    y = y * _sorted_weights(wgt, slot, pos)[:, None].astype(y.dtype)
+    # combine: each row back to its token. (The chip adds rows of the
+    # model width one at a time, 3.0 ms for 32,768 of them, PERF.md PR 30;
+    # a gather of [T, k] rows with a sum over k takes 6.1 ms.)
+    return jnp.zeros_like(x).at[tok].add(y)
+
+
+def _routed_dense(x, w1, w3, w2, wgt, local, here):
+    """The same part with no bound on the rows: every held expert over
+    every token, by the token's weight for it (nought where it did not
+    choose it). ``held`` times the work; the path of a routing that lands
+    more here than the sorted rows hold."""
+    held = w1.shape[0]
+    cw = jnp.einsum("tk,tke->te", jnp.where(here, wgt, 0),
+                    jax.nn.one_hot(local, held, dtype=wgt.dtype))
+    one = jax.checkpoint(lambda a, b, c, w: w[:, None].astype(x.dtype)
+                         * _swiglu(x, a, b, c, jnp.dot))
+
+    def add(acc, e):
+        return acc + one(*e), None
+    return lax.scan(add, jnp.zeros_like(x), (w1, w3, w2, cw.T))[0]
+
+
+class _Router(Layer):
+    """Scores of every published expert and the choice among them."""
+
+    def __init__(self, d_model, num_experts, top_k, route_norm, route_scale,
+                 init):
+        super().__init__()
+        self.top_k, self.route_norm = int(top_k), bool(route_norm)
+        self.route_scale = float(route_scale)
+        self.num_experts = int(num_experts)
+        self.weight = self.create_parameter([d_model, num_experts],
+                                            default_initializer=init)
+
+    def forward(self, x, bias):
+        """x [T, d] -> (sel [T, k] int32, weights [T, k] f32, counts
+        [E] f32). The bias enters the choice, never the weight."""
+        def scores(xv, w):
+            return jax.nn.sigmoid(jnp.dot(
+                xv, w.astype(xv.dtype), preferred_element_type=jnp.float32))
+
+        def choose(s, b):
+            sel = lax.top_k(s + b, self.top_k)[1].astype(jnp.int32)
+            counts = jnp.sum(jax.nn.one_hot(sel, self.num_experts,
+                                            dtype=jnp.float32), axis=(0, 1))
+            return sel, counts
+
+        def weigh(s, sel):
+            w = jnp.take_along_axis(s, sel, axis=-1)
+            if self.route_norm:
+                w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+            return w * self.route_scale
+
+        s = _tape.apply(scores, x, self.weight, _op_name="moe_scores")
+        with _tape.no_grad():
+            sel, counts = _tape.apply(choose, s, bias, _op_name="moe_choose")
+        w = _tape.apply(weigh, s, sel, _op_name="moe_weigh")
+        return sel, w, counts
+
+
+class _Experts(Layer):
+    """The SwiGLU experts held here, stacked [held, ...] over "ep"."""
+
+    def __init__(self, d_model, d_expert, held, offset, published, top_k,
+                 init):
+        super().__init__()
+        self.held, self.offset = int(held), int(offset)
+        self.published, self.top_k = int(published), int(top_k)
+
+        def stacked(shape):
+            p = Parameter(init(shape, "float32"))
+            p.sharding_axes = ("ep",) + (None,) * (len(shape) - 1)
+            return p
+        self.w1 = self.add_parameter("w1", stacked([held, d_model, d_expert]))
+        self.w3 = self.add_parameter("w3", stacked([held, d_model, d_expert]))
+        self.w2 = self.add_parameter("w2", stacked([held, d_expert, d_model]))
+
+    def rows_bound(self, tokens: int) -> int:
+        """Sorted rows the grouped product is built for:
+        ``_ROWS_OVER_EVEN`` times what an even routing lands here, at
+        most what any routing can (each token's top-k are distinct
+        experts), in whole tiles."""
+        worst = tokens * min(self.top_k, self.held)
+        even = -(-tokens * self.top_k * self.held // self.published)
+        rows = min(worst, _ROWS_OVER_EVEN * even)
+        unit = _GMM_TILING[0] if rows >= _GMM_TILING[0] else 16
+        return -(-rows // unit) * unit
+
+    def forward(self, x, sel, wgt):
+        """x [T, d], sel and wgt [T, k] -> sum over the experts held
+        here of wgt * expert(x), [T, d]."""
+        rows = self.rows_bound(int(x.shape[0]))
+        _last_moe.clear()
+        _last_moe.update(
+            kernel="megablox_gmm" if _on_tpu() else "xla_ragged_dot",
+            experts_held=self.held, experts_published=self.published,
+            top_k=self.top_k, rows_bound=rows)
+
+        def fn(xv, w1, w3, w2, wv, selv):
+            local = selv - self.offset
+            here = (local >= 0) & (local < self.held)
+            w1, w3, w2 = (w.astype(xv.dtype) for w in (w1, w3, w2))
+            return lax.cond(
+                jnp.sum(here) <= rows,
+                lambda: _routed_sorted(xv, w1, w3, w2, wv, local, here,
+                                       rows),
+                lambda: _routed_dense(xv, w1, w3, w2, wv, local, here))
+        return _tape.apply(fn, x, self.w1, self.w3, self.w2, wgt, sel,
+                           _op_name="moe_experts")
+
+
+class TokenChoiceMoE(Layer):
+    """Dropless token-choice mixture of SwiGLU experts.
+
+    ``num_experts`` is the published count the router scores;
+    ``experts_held`` of them, from ``expert_offset`` on, live here (one
+    chip's share of expert parallelism; all of them by default).
+    Assignments to experts held elsewhere add nothing here: with every
+    share's output and the shared expert counted once, the shares add up
+    to the whole layer. Nothing is dropped under any routing.
+
+    ``forward(x)`` returns ``(y, counts)``: counts [num_experts] of the
+    tokens that chose each expert, a VALUE, so that the layer runs under
+    ``jax.checkpoint``; the caller hands it to ``note_load`` outside the
+    recomputed region, which keeps it in the buffer ``expert_load``, adds
+    it to the buffer ``expert_load_total`` (the counts of every step so
+    far: the difference of two readings is what a span of steps routed)
+    and moves the buffer ``expert_bias`` by ``bias_update_rate * sign(mean
+    - count)`` (auxiliary-loss-free balancing, arXiv:2408.15664). The
+    buffers ride ``TrainStep``'s buffers and stay float32 whatever the
+    weights are cast to.
+    """
+
+    _fixed_dtype_buffers = frozenset({"expert_bias", "expert_load",
+                                      "expert_load_total"})
+
+    def __init__(self, d_model, d_expert, num_experts, top_k,
+                 experts_held=None, expert_offset=0, shared_expert=None,
+                 route_norm=True, route_scale=1.0, bias_update_rate=0.001,
+                 initializer_range=0.02):
+        super().__init__()
+        held = num_experts if experts_held is None else int(experts_held)
+        if not 0 <= expert_offset <= num_experts - held:
+            raise ValueError(f"experts {expert_offset}..{expert_offset + held}"
+                             f" are not among the {num_experts} published")
+        init = I.Normal(0.0, initializer_range)
+        self.router = _Router(d_model, num_experts, top_k, route_norm,
+                              route_scale, init)
+        self.experts = _Experts(d_model, d_expert, held, expert_offset,
+                                num_experts, top_k, init)
+        self.shared_expert = shared_expert
+        self.bias_update_rate = float(bias_update_rate)
+        self.register_buffer("expert_bias", Tensor(
+            jnp.zeros((num_experts,), jnp.float32), stop_gradient=True))
+        for name in ("expert_load", "expert_load_total"):
+            self.register_buffer(name, Tensor(
+                jnp.zeros((num_experts,), jnp.float32), stop_gradient=True))
+
+    def forward(self, x):
+        """x [..., d_model] -> (y, counts)."""
+        from .. import tensor as T
+        flat = T.reshape(x, [-1, x.shape[-1]])
+        sel, wgt, counts = self.router(flat, self.expert_bias)
+        y = self.experts(flat, sel, wgt)
+        if self.shared_expert is not None:
+            y = y + self.shared_expert(flat)
+        return T.reshape(y, list(x.shape)), counts
+
+    def note_load(self, counts):
+        """Keep a step's counts and move the bias by them (no
+        gradient). Call it once a training step, outside any recomputed
+        region."""
+        c = counts.value if isinstance(counts, Tensor) else counts
+        c = lax.stop_gradient(c).astype(jnp.float32)
+        self.expert_load.value = c
+        self.expert_load_total.value = self.expert_load_total.value + c
+        self.expert_bias.value = self.expert_bias.value + \
+            self.bias_update_rate * jnp.sign(jnp.mean(c) - c)

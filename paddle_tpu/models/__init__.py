@@ -6,6 +6,7 @@ from .gpt import (GPTConfig, GPTForCausalLM, GPTModel,
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel,
                     LlamaPipelineForCausalLM, llama_tiny, llama_7b,
                     llama_13b)
+from .afmoe import AfmoeConfig, AfmoeForCausalLM, AfmoeModel
 from .bert import (BertConfig, BertModel, BertForSequenceClassification,
                    BertForMaskedLM, ErnieModel, bert_tiny, bert_base,
                    ernie_3_tiny, ernie_3_base)
@@ -16,6 +17,7 @@ __all__ = ["GPTConfig", "GPTModel", "GPTForCausalLM",
            "LlamaConfig", "LlamaModel", "LlamaForCausalLM",
            "LlamaPipelineForCausalLM", "llama_tiny", "llama_7b",
            "llama_13b",
+           "AfmoeConfig", "AfmoeModel", "AfmoeForCausalLM",
            "BertConfig", "BertModel", "BertForSequenceClassification",
            "BertForMaskedLM", "ErnieModel", "bert_tiny", "bert_base",
            "ernie_3_tiny", "ernie_3_base"]

@@ -276,10 +276,12 @@ class GPTModel(Layer):
         if self.cfg.recompute and self.training:
             if self.cfg.use_moe:
                 raise NotImplementedError(
-                    "cfg.recompute with use_moe: the MoE aux-loss side "
+                    "cfg.recompute with use_moe: MoELayer's aux-loss side "
                     "channel would cross the jax.checkpoint boundary "
-                    "(tracer leak); use GPTPipelineForCausalLM's "
-                    "recompute_interval for MoE models")
+                    "(tracer leak). distributed.moe.TokenChoiceMoE hands "
+                    "its counts out as values and runs under recomputation "
+                    "(models/afmoe.py); for MoELayer use "
+                    "GPTPipelineForCausalLM's recompute_interval")
             from ..distributed.recompute import recompute as _rc
             for blk in self.blocks:
                 x = _rc(blk, x, policy=self.cfg.recompute_policy)

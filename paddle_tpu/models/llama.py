@@ -7,8 +7,9 @@ layer library as GPT:
 
 - mp: q/k/v/gate/up projections are ColumnParallelLinear, o/down are
   RowParallelLinear (Megatron layout, one GSPMD allreduce per block pair);
-- GQA: num_kv_heads < num_heads supported; kv heads are broadcast to query
-  heads right before attention (XLA fuses the expand into the kernel);
+- GQA: num_kv_heads < num_heads supported; the attention functional takes
+  the fewer key/value heads as they are (the kernel's grouped path: k and
+  v are never copied out to the query heads);
 - RoPE is applied to q/k on the full (pre-sp-shard) sequence;
 - sp: ring attention dispatch when the "sp" mesh axis is real;
 - pp: LlamaPipelineForCausalLM stacks blocks over the pp axis.
@@ -170,11 +171,11 @@ class LlamaAttention(Layer):
                                            pos)
             return self.o_proj(
                 T.reshape(ctx, [B, S, nh * hd])), (kc, vc)
-        if nkv != nh:
-            rep = nh // nkv
-            k = T.repeat_interleave(k, rep, axis=2)
-            v = T.repeat_interleave(v, rep, axis=2)
         if _sp_active():
+            if nkv != nh:       # the ring schedule wants a key head a head
+                rep = nh // nkv
+                k = T.repeat_interleave(k, rep, axis=2)
+                v = T.repeat_interleave(v, rep, axis=2)
             ctx = ring_attention(q, k, v, causal=True)
         else:
             ctx, _ = F.flash_attention(q, k, v, causal=True,

@@ -9,25 +9,31 @@ reference's dlopened flashattn library
 fused attention (jax.nn.dot_product_attention).
 
 The kernel call (``_pallas_flash_local``): one forward kernel and ONE fused
-backward kernel (dq, dk and dv from one look at the scores), a causal or full
-mask whose skipped blocks are a table built at trace time, residuals one
-logsumexp 8 sublanes wide; f32 scores, statistics and accumulation. It takes
-[heads, s, d] and no scale: q is scaled before the call in q's dtype and the
-call is vmapped over the batch, so the kernels' operands are [b, h, s, d].
-Blocks come from ``_splash_blocks``, one rule of the two sequence lengths
-(largest divisor up to 1024, compute blocks up to 512); the kernel object is
-built once a geometry (``_splash_kernel``). Under a multi-device mesh the call
-runs per shard inside a shard_map (``_mesh_wrap``).
+backward kernel (dq, dk and dv from one look at the scores), a causal, causal
+window (``LocalMask``) or full mask whose skipped blocks are a table built at
+trace time, residuals one logsumexp 8 sublanes wide; f32 scores, statistics
+and accumulation. It takes [heads, s, d] and no scale: q is scaled before the
+call in q's dtype and the call is vmapped over the batch, so the kernels'
+operands are [b, h, s, d]. Fewer key/value heads than query heads (GQA) go
+through the library's MQA kernel, one call a key/value head over its group of
+query heads (operands [b, kv_heads, group, s, d] and [b, kv_heads, s, d]): k
+and v are never copied out to the query heads. Blocks come from
+``_splash_blocks``, one rule of the two sequence lengths (largest divisor up
+to 1024, compute blocks up to 512); the kernel object is built once a geometry
+(``_splash_kernel``). Under a multi-device mesh the call runs per shard inside
+a shard_map (``_mesh_wrap``).
 
 ``last_attention_dispatch()`` says what the last traced call did:
-``backend`` ("pallas" | "xla"), ``reason``, and on the Pallas path ``kernel``
-("splash_fused") and ``blocks`` ({"q", "kv", "kv_compute"}).
+``backend`` ("pallas" | "xla"), ``reason``, ``window`` (None: no window) and
+``kv_heads``, and on the Pallas path ``kernel`` ("splash_fused") and
+``blocks`` ({"q", "kv", "kv_compute"}).
 
 Layout note: paddle flash_attention uses (batch, seqlen, nheads, head_dim).
 """
 from __future__ import annotations
 
 import functools
+import math
 import os
 import warnings
 
@@ -46,7 +52,8 @@ __all__ = ["flash_attention", "scaled_dot_product_attention",
            "paged_kv_cache"]
 
 # most recent kernel-dispatch decision — observable, never silent
-# (VERDICT r2 weak #3). {"backend": "pallas"|"xla", "reason": str} and,
+# (VERDICT r2 weak #3). {"backend": "pallas"|"xla", "reason": str,
+# "window": int or None, "kv_heads": int} and,
 # on the Pallas path, {"kernel": str, "blocks": {"q", "kv", "kv_compute"}}
 _last_dispatch = {}
 
@@ -122,12 +129,27 @@ def _mega_decode_on() -> bool:
 def _pallas_geometry_ok(seq: int, d: int, drop: float) -> bool:
     """Pure geometry gate for the Pallas TPU kernel: seq a multiple of
     the 128-lane tile, head_dim either within one lane tile or a multiple
-    of 128, no attention dropout."""
+    of 128, no attention dropout. Inside it the kernel admits every
+    causal window (any width of 1 or more; a width past the sequence is
+    plain causal) and every grouping of query heads over key/value heads
+    that divides them (``_check_heads`` refuses the rest on both paths),
+    so neither is read here."""
     return (seq >= 128 and seq % 128 == 0 and (d <= 128 or d % 128 == 0)
             and drop == 0.0)
 
 
-def _mesh_wrap(shape):
+def _check_heads(q_heads: int, kv_heads: int, window, causal) -> None:
+    """What no path computes: key/value heads that do not divide the
+    query heads, and a window that is not a causal one."""
+    if kv_heads < 1 or q_heads % kv_heads:
+        raise ValueError(f"{q_heads} query heads do not divide over "
+                         f"{kv_heads} key/value heads")
+    if window is not None and (not causal or int(window) < 1):
+        raise ValueError("window is a causal window of 1 or more keys "
+                         f"(got window={window}, causal={causal})")
+
+
+def _mesh_wrap(shape, kv_heads=None):
     """How the library kernel must be wrapped under the trace-time mesh.
 
     Mosaic kernels cannot be partitioned by GSPMD ("Mosaic kernels
@@ -137,7 +159,9 @@ def _mesh_wrap(shape):
     there). Attention is independent across batch and heads, so under a
     multi-device mesh the call runs inside a shard_map with batch over
     the data axes (dp, sharding) and heads over "mp" — the layout GSPMD
-    already keeps these activations in.
+    already keeps these activations in. Grouped heads shard by key/value
+    head (``kv_heads``, where fewer than the query heads): a shard keeps
+    whole groups.
 
     Returns ``(mesh, spec, why_not)``: a mesh and spec to wrap with;
     all ``None`` when no wrap is needed (no mesh, one device, or the
@@ -161,16 +185,38 @@ def _mesh_wrap(shape):
     n_data = 1
     for a in data:
         n_data *= mesh.shape[a]
-    if shape[0] % n_data or shape[2] % mp:
+    heads = shape[2] if kv_heads is None else kv_heads
+    if shape[0] % n_data or heads % mp:
         return None, None, (
-            f"batch {shape[0]} / heads {shape[2]} do not divide the "
+            f"batch {shape[0]} / heads {heads} do not divide the "
             f"mesh's data ({n_data}) / mp ({mp}) degrees")
     return mesh, P(data or None, None, "mp" if mp > 1 else None,
                    None), None
 
 
-def _pallas_ok(q, d, drop):
+def _kv_for_mesh(q, k, v):
+    """k and v for the kernel under the trace-time mesh: as they are,
+    unless the "mp" degree divides the query heads but not the fewer
+    key/value heads. Then each key/value head is copied out the least
+    number of times that lets "mp" divide them (a shard still keeps whole
+    groups), where copying them out to every query head, as callers did
+    before the grouped path, would also have divided."""
+    from ...distributed import mesh as mesh_mod
+    mesh = mesh_mod.get_mesh(create_default=False)
+    if not _on_tpu() or mesh is None \
+            or jax.sharding.get_abstract_mesh().manual_axes:
+        return k, v
+    mp = mesh.shape.get("mp", 1)
+    heads, kv_heads = q.shape[2], k.shape[2]
+    if kv_heads % mp == 0 or heads % mp:
+        return k, v
+    copies = mp // math.gcd(kv_heads, mp)
+    return jnp.repeat(k, copies, axis=2), jnp.repeat(v, copies, axis=2)
+
+
+def _pallas_ok(q, d, drop, kv_heads, window):
     _last_dispatch.clear()      # a record of this call, none of an earlier
+    _last_dispatch.update(window=window, kv_heads=kv_heads)
     if not _on_tpu():
         _last_dispatch.update(backend="xla", reason="not on TPU")
         if _require_pallas():
@@ -190,7 +236,7 @@ def _pallas_ok(q, d, drop):
                 f"geometry (seq={q.shape[1]}, head_dim={d}, "
                 f"dropout={drop}) cannot use the Pallas kernel")
         return False
-    mesh, spec, why_not = _mesh_wrap(q.shape)
+    mesh, spec, why_not = _mesh_wrap(q.shape, kv_heads)
     if why_not:
         _last_dispatch.update(backend="xla", reason=why_not)
         if _require_pallas():
@@ -203,15 +249,15 @@ def _pallas_ok(q, d, drop):
     return True
 
 
-def _pallas_flash(q, k, v, causal, scale):
+def _pallas_flash(q, k, v, causal, scale, window=None):
     """The library kernel on [b, s, h, d] operands; under a multi-device
     mesh, per shard inside a shard_map (``_mesh_wrap``), where the kernel
-    is built from the shard's own head count."""
-    mesh, spec, _ = _mesh_wrap(q.shape)
+    is built from the shard's own head counts."""
+    mesh, spec, _ = _mesh_wrap(q.shape, k.shape[2])
     if mesh is None:
-        return _pallas_flash_local(q, k, v, causal, scale)
+        return _pallas_flash_local(q, k, v, causal, scale, window)
     return jax.shard_map(
-        lambda q, k, v: _pallas_flash_local(q, k, v, causal, scale),
+        lambda q, k, v: _pallas_flash_local(q, k, v, causal, scale, window),
         mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
         check_vma=False)(q, k, v)
 
@@ -242,31 +288,48 @@ def _splash_blocks(s_q: int, s_k: int) -> dict:
 
 
 @functools.lru_cache(maxsize=64)
-def _splash_kernel(heads, s_q, s_k, causal, interpret):
+def _splash_kernel(heads, s_q, s_k, causal, interpret, window=None,
+                   grouped=False):
     """The library's splash kernel for one [heads, s, d] attention. Its
     mask tables are numpy work at trace time, so one object serves every
-    layer and every later trace of the same geometry."""
+    layer and every later trace of the same geometry. ``window``: query i
+    sees keys j with 0 <= i - j < window (causal; the caller hands None
+    for one that reaches every key). ``grouped``: the MQA kernel,
+    ``heads`` query heads on ONE key/value head ([s, d])."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
-    mask = (sm.CausalMask if causal else sm.FullMask)((s_q, s_k))
+    if window is not None:
+        mask = sm.LocalMask((s_q, s_k), (window - 1, 0), 0)
+    else:
+        mask = (sm.CausalMask if causal else sm.FullMask)((s_q, s_k))
+    make = (sk.make_splash_mqa_single_device if grouped
+            else sk.make_splash_mha_single_device)
     # the tables become device constants here, not values of whatever
     # trace (jit, shard_map, remat) first asked for this geometry
     with jax.ensure_compile_time_eval():
-        return sk.make_splash_mha_single_device(
+        return make(
             sm.MultiHeadMask([mask] * heads),
             block_sizes=sk.BlockSizes(**_splash_blocks(s_q, s_k)),
             interpret=interpret)
 
 
-def _pallas_flash_local(q, k, v, causal, scale):
+def _pallas_flash_local(q, k, v, causal, scale, window=None):
     # the kernel works on [h, s, d], one batch row a call; vmap puts the
     # batch back in front, so its operands are [b, h, s, d]
     qh = jnp.swapaxes(q, 1, 2)
     kh = jnp.swapaxes(k, 1, 2)
     vh = jnp.swapaxes(v, 1, 2)
-    s_q, s_k = qh.shape[2], kh.shape[2]
-    kernel = _splash_kernel(qh.shape[1], s_q, s_k, bool(causal),
-                            not _on_tpu())
+    b, heads, s_q, d = qh.shape
+    kv_heads, s_k = kh.shape[1], kh.shape[2]
+    grouped = kv_heads != heads
+    # a window that reaches past every key is plain causal: one kernel
+    if window is not None and window >= s_k:
+        window = None
+    # the mask has a row a query head of one call: all of them, or the
+    # group that shares a key/value head
+    kernel = _splash_kernel(heads // kv_heads if grouped else heads, s_q,
+                            s_k, bool(causal), not _on_tpu(), window,
+                            grouped)
     blocks = _splash_blocks(s_q, s_k)
     _last_dispatch.update(
         kernel="splash_fused" if blocks["use_fused_bwd_kernel"]
@@ -274,15 +337,28 @@ def _pallas_flash_local(q, k, v, causal, scale):
         blocks={"q": blocks["block_q"], "kv": blocks["block_kv"],
                 "kv_compute": blocks["block_kv_compute"]})
     # the kernel takes no scale: q carries it, in q's dtype
-    out = jax.vmap(kernel)(qh * jnp.asarray(scale, qh.dtype), kh, vh)
+    qh = qh * jnp.asarray(scale, qh.dtype)
+    if grouped:
+        # one MQA call a key/value head over its group of query heads:
+        # operands [b, kv, group, s, d] and [b, kv, s, d], k and v as
+        # they are
+        qg = qh.reshape(b, kv_heads, heads // kv_heads, s_q, d)
+        out = jax.vmap(jax.vmap(kernel))(qg, kh, vh)
+        out = out.reshape(b, heads, s_q, d)
+    else:
+        out = jax.vmap(kernel)(qh, kh, vh)
     return jnp.swapaxes(out, 1, 2)
 
 
 def _xla_attention(q, k, v, bias, mask, causal, scale, dropout=0.0,
-                   dropout_key=None):
-    # q,k,v: (b, s, h, d) — jax.nn.dot_product_attention's native layout.
+                   dropout_key=None, window=None):
+    # q,k,v: (b, s, h, d) — jax.nn.dot_product_attention's native layout;
+    # it groups query heads over fewer key/value heads itself.
     if dropout > 0.0 and dropout_key is not None:
         # explicit attention (XLA fuses it) so probs can be dropped
+        if k.shape[2] != q.shape[2]:
+            rep = q.shape[2] // k.shape[2]
+            k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
         if bias is not None:
             logits = logits + bias
@@ -291,6 +367,9 @@ def _xla_attention(q, k, v, bias, mask, causal, scale, dropout=0.0,
         if causal:
             s_q, s_k = q.shape[1], k.shape[1]
             cm = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), s_k - s_q)
+            if window is not None:
+                cm = cm & ~jnp.tril(jnp.ones((s_q, s_k), dtype=bool),
+                                    s_k - s_q - window)
             logits = jnp.where(cm, logits, jnp.asarray(-1e30, logits.dtype))
         probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
         keep = jax.random.bernoulli(dropout_key, 1.0 - dropout, probs.shape)
@@ -298,28 +377,35 @@ def _xla_attention(q, k, v, bias, mask, causal, scale, dropout=0.0,
         return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v)
     return jax.nn.dot_product_attention(
         q, k, v, bias=bias,
-        mask=mask, is_causal=causal, scale=scale)
+        mask=mask, is_causal=causal, scale=scale,
+        local_window_size=None if window is None else (window - 1, 0))
 
 
 def flash_attention(query, key, value, dropout=0.0, causal=False,
                     return_softmax=False, fixed_seed_offset=None, rng_name="",
-                    training=True, name=None):
-    """q/k/v: (batch, seq, heads, head_dim). Returns (out, softmax_lse-like
-    placeholder) matching paddle's (result, softmax) tuple shape."""
+                    training=True, name=None, window=None):
+    """q: (batch, seq, heads, head_dim); k/v the same, or with fewer
+    heads that divide q's (GQA: query head h reads key/value head
+    h // group). ``window`` (with ``causal``): query i sees keys j with
+    0 <= i - j < window. Returns (out, softmax_lse-like placeholder)
+    matching paddle's (result, softmax) tuple shape."""
     d = query.shape[-1]
     scale = 1.0 / (d ** 0.5)
     drop = dropout if training else 0.0
+    _check_heads(query.shape[2], key.shape[2], window, causal)
     dkey = None
     if drop > 0.0:
         from ...framework.random import next_key
         dkey = next_key()
 
     def f(q, k, v):
-        if _pallas_ok(q, d, drop):
+        k, v = _kv_for_mesh(q, k, v)
+        if _pallas_ok(q, d, drop, k.shape[2], window):
             # on the chip the kernel compiles or the call raises — an
             # XLA fallback here would make a broken kernel look healthy
-            return _pallas_flash(q, k, v, causal, scale)
-        return _xla_attention(q, k, v, None, None, causal, scale, drop, dkey)
+            return _pallas_flash(q, k, v, causal, scale, window)
+        return _xla_attention(q, k, v, None, None, causal, scale, drop, dkey,
+                              window)
 
     out = apply(f, query, key, value, _op_name="flash_attention")
     if return_softmax:
@@ -353,11 +439,13 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
-                                 training=True, name=None):
-    """Parity: paddle scaled_dot_product_attention ((b, s, h, d) layout)."""
+                                 training=True, name=None, window=None):
+    """Parity: paddle scaled_dot_product_attention ((b, s, h, d) layout).
+    Grouped key/value heads and ``window`` as ``flash_attention``."""
     d = query.shape[-1]
     scale = 1.0 / (d ** 0.5)
     drop = dropout_p if training else 0.0
+    _check_heads(query.shape[2], key.shape[2], window, is_causal)
     dkey = None
     if drop > 0.0:
         from ...framework.random import next_key
@@ -365,17 +453,19 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 
     if attn_mask is None:
         def f(q, k, v):
-            if _pallas_ok(q, d, drop):
-                return _pallas_flash(q, k, v, is_causal, scale)
+            k, v = _kv_for_mesh(q, k, v)
+            if _pallas_ok(q, d, drop, k.shape[2], window):
+                return _pallas_flash(q, k, v, is_causal, scale, window)
             return _xla_attention(q, k, v, None, None, is_causal, scale,
-                                  drop, dkey)
+                                  drop, dkey, window)
         return apply(f, query, key, value, _op_name="sdpa")
 
     def fm(q, k, v, m):
         if m.dtype == jnp.bool_:
             return _xla_attention(q, k, v, None, m, is_causal, scale,
-                                  drop, dkey)
-        return _xla_attention(q, k, v, m, None, is_causal, scale, drop, dkey)
+                                  drop, dkey, window)
+        return _xla_attention(q, k, v, m, None, is_causal, scale, drop, dkey,
+                              window)
     return apply(fm, query, key, value, attn_mask, _op_name="sdpa")
 
 

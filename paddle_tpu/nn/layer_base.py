@@ -49,6 +49,11 @@ class ParamAttr:
 
 
 class Layer:
+    # names of this layer's own buffers that keep their type under
+    # astype() / bfloat16(): state that is no activation (counters, a
+    # routing bias stepped by thousandths), which a cast would coarsen
+    _fixed_dtype_buffers = frozenset()
+
     def __init__(self, name_scope=None, dtype="float32"):
         object.__setattr__(self, "_parameters", collections.OrderedDict())
         object.__setattr__(self, "_sub_layers", collections.OrderedDict())
@@ -256,9 +261,12 @@ class Layer:
         for p in self.parameters():
             if not float_only or is_inexact(p.value.dtype):
                 p.value = cast(p.value)
-        for _, b in self.named_buffers():
-            if not float_only or is_inexact(b.value.dtype):
-                b.value = cast(b.value)
+        for layer in [self] + self.sublayers():
+            for name, b in layer._buffers.items():
+                if b is None or name in layer._fixed_dtype_buffers:
+                    continue
+                if not float_only or is_inexact(b.value.dtype):
+                    b.value = cast(b.value)
 
     def float(self):
         return self.astype("float32")

@@ -1,0 +1,465 @@
+"""The afmoe family (Trinity-Mini) for training: the dropless token-choice
+expert layer, window and grouped-head attention on both paths, and
+``AfmoeForCausalLM`` through ``jit.TrainStep``, each against the plain
+float32 reference that the benchmark keeps (``benchmark/reference/afmoe.py``,
+which imports nothing of paddle_tpu).
+
+Tolerances: everything here runs in float32 at ``highest`` matmul precision
+(tests/conftest.py), so the program and the reference differ by the order
+of float32 sums alone: 1e-5 relative on outputs, losses and gradients, with
+2e-4 absolute beside it for gradients that are sums over a few hundred terms
+of either sign. The splash kernel
+keeps float32 scores and accumulators in interpret mode: 2e-5.
+"""
+import importlib
+import math
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as paddle
+import paddle_tpu.distributed as dist
+import paddle_tpu.nn.functional as F
+from benchmark.reference import afmoe as R
+from paddle_tpu.core.tensor import Tensor
+from paddle_tpu.distributed.moe import TokenChoiceMoE, last_moe_dispatch
+from paddle_tpu.jit import TrainStep
+from paddle_tpu.jit.functional import load_state
+from paddle_tpu.models import AfmoeConfig, AfmoeForCausalLM
+
+fa = importlib.import_module("paddle_tpu.nn.functional.flash_attention")
+
+
+@pytest.fixture(autouse=True)
+def fresh_mesh():
+    dist.set_mesh(None)
+    yield
+    dist.set_mesh(None)
+
+
+def _rand(*shape, seed=0, scale=1.0):
+    return jnp.asarray(scale * np.random.RandomState(seed).randn(*shape)
+                       .astype("float32"))
+
+
+# ------------------------------------------------------------- attention
+
+def _masked_attention(q, k, v, causal, scale, window=None):
+    """Plain f32 attention on [b, s, h, d] with an explicit mask; k and v
+    may hold fewer heads: query head h reads key/value head h // group."""
+    group = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    i = jnp.arange(q.shape[1])[:, None]
+    j = jnp.arange(k.shape[1])[None, :]
+    mask = (j <= i) if causal else jnp.ones((q.shape[1], k.shape[1]), bool)
+    if window is not None:
+        mask = mask & (i - j < window)
+    s = jnp.where(mask, s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+
+
+def _xla(q, k, v, causal, scale, window=None):
+    return fa._xla_attention(q, k, v, None, None, causal, scale,
+                             window=window)
+
+
+@pytest.mark.parametrize("window", [None, 1, 64, 100, 256, 1000])
+@pytest.mark.parametrize("kv_heads", [4, 2, 1])
+@pytest.mark.parametrize("path", ["xla", "splash"])
+def test_window_and_grouped_heads_match_explicit_mask(path, kv_heads,
+                                                      window):
+    """Both paths of the attention functional, forward and all three
+    gradients, against an explicit mask: a causal window narrower than,
+    equal to and wider than the sequence, heads grouped 1, 2 and 4 to a
+    key/value head."""
+    B, S, H, D = 2, 256, 4, 64
+    q = _rand(B, S, H, D, seed=1)
+    k, v = _rand(B, S, kv_heads, D, seed=2), _rand(B, S, kv_heads, D, seed=3)
+    co = _rand(B, S, H, D, seed=4)
+    scale = 1.0 / math.sqrt(D)
+    attn = _xla if path == "xla" else fa._pallas_flash_local
+
+    def run(f):
+        return jax.value_and_grad(
+            lambda q, k, v: (f(q, k, v, True, scale, window) * co).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    (out, grads), (ro, rg) = run(attn), run(_masked_attention)
+    np.testing.assert_allclose(float(out), float(ro), rtol=2e-5)
+    for g, r in zip(grads, rg):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=5e-5)
+
+
+@pytest.mark.parametrize("path", ["xla", "splash"])
+def test_window_past_the_sequence_is_plain_causal(path):
+    """Bitwise: a window that reaches every key builds the causal call."""
+    q, k, v = (_rand(1, 128, 4, 64, seed=i) for i in range(3))
+    attn = _xla if path == "xla" else fa._pallas_flash_local
+    wide = attn(q, k[:, :, :2], v[:, :, :2], True, 0.125, 128)
+    none = attn(q, k[:, :, :2], v[:, :, :2], True, 0.125, None)
+    if path == "splash":
+        assert np.array_equal(np.asarray(wide), np.asarray(none))
+    else:
+        np.testing.assert_allclose(np.asarray(wide), np.asarray(none),
+                                   atol=1e-6)
+
+
+def test_functional_takes_window_and_grouped_heads_and_says_so():
+    q = paddle.to_tensor(np.asarray(_rand(2, 128, 4, 32, seed=1)))
+    k = paddle.to_tensor(np.asarray(_rand(2, 128, 2, 32, seed=2)))
+    v = paddle.to_tensor(np.asarray(_rand(2, 128, 2, 32, seed=3)))
+    want = _masked_attention(q.value, k.value, v.value, True,
+                             1 / math.sqrt(32), 48)
+    out, _ = F.flash_attention(q, k, v, causal=True, window=48)
+    rec = F.last_attention_dispatch()
+    assert rec["window"] == 48 and rec["kv_heads"] == 2
+    np.testing.assert_allclose(np.asarray(out.value), np.asarray(want),
+                               atol=2e-6)
+    out2 = F.scaled_dot_product_attention(q, k, v, is_causal=True, window=48)
+    np.testing.assert_allclose(np.asarray(out2.value), np.asarray(want),
+                               atol=2e-6)
+    F.flash_attention(q, q, q, causal=True)
+    rec = F.last_attention_dispatch()
+    assert rec["window"] is None and rec["kv_heads"] == 4
+
+
+@pytest.mark.parametrize("bad", ["heads", "not_causal", "zero"])
+def test_functional_refuses_what_no_path_computes(bad):
+    q = paddle.to_tensor(np.zeros((1, 128, 6, 32), "float32"))
+    kv = paddle.to_tensor(np.zeros((1, 128, 4 if bad == "heads" else 3, 32),
+                                   "float32"))
+    kw = {"heads": dict(causal=True), "not_causal": dict(window=8),
+          "zero": dict(causal=True, window=0)}[bad]
+    with pytest.raises(ValueError):
+        F.flash_attention(q, kv, kv, **kw)
+
+
+def test_gpt_call_builds_the_same_kernel_and_blocks(monkeypatch):
+    """The MHA causal call of the GPT cells: the library's MHA maker, a
+    ``CausalMask`` a head, PR 29's blocks; window and grouping key other
+    kernel objects and leave this one alone."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+    made = []
+    for name in ("make_splash_mha_single_device",
+                 "make_splash_mqa_single_device"):
+        real = getattr(sk, name)
+        monkeypatch.setattr(sk, name, lambda mask, _n=name, _r=real, **kw: (
+            made.append((_n, mask, kw)), _r(mask, **kw))[1])
+    fa._splash_kernel.cache_clear()
+    for s, heads in ((1024, 12), (2048, 16)):
+        kernel = fa._splash_kernel(heads, s, s, True, True, None, False)
+        name, mask, kw = made[-1]
+        assert name == "make_splash_mha_single_device"
+        assert len(mask.masks) == heads and all(
+            type(m) is sm.CausalMask for m in mask.masks)
+        assert kw["block_sizes"] == sk.BlockSizes(
+            block_q=1024, block_kv=1024, block_kv_compute=512,
+            block_q_dkv=1024, block_kv_dkv=1024, block_kv_dkv_compute=512,
+            use_fused_bwd_kernel=True)
+        q = jax.ShapeDtypeStruct((2, s, heads, 64), jnp.float32)
+        n = len(made)
+        jax.eval_shape(lambda q: fa._pallas_flash_local(q, q, q, True, 1.0),
+                       q)       # the call asks for that very object
+        assert len(made) == n and fa._splash_kernel(
+            heads, s, s, True, True, None, False) is kernel
+    n = len(made)
+    windowed = fa._splash_kernel(8, 2048, 2048, True, True, 512, True)
+    assert made[-1][0] == "make_splash_mqa_single_device" and len(made) == n + 1
+    assert all(type(m) is sm.LocalMask for m in made[-1][1].masks)
+    assert windowed is not fa._splash_kernel(16, 2048, 2048, True, True,
+                                             None, False)
+    assert fa._splash_blocks(8192, 8192)["block_q"] == 1024
+    fa._splash_kernel.cache_clear()
+
+
+def test_grouped_call_copies_no_keys(monkeypatch):
+    """Traced for the chip: the kernels' key/value operands keep the
+    key/value heads ([b, kv, s, d]); nothing of [b, heads, s, d] is made
+    from them."""
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    q = jax.ShapeDtypeStruct((2, 256, 8, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, 256, 2, 64), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: F.flash_attention(
+        Tensor(q), Tensor(k), Tensor(v), causal=True,
+        window=128)[0].value)(q, kv, kv)
+    rec = fa.last_attention_dispatch()
+    assert rec["backend"] == "pallas" and rec["kernel"] == "splash_fused"
+    assert rec["window"] == 128 and rec["kv_heads"] == 2
+    text = str(jaxpr)
+    assert "bf16[2,2,4,256,64]" in text          # q by group
+    assert "bf16[2,2,256,64]" in text            # k, v by key/value head
+    assert "concatenate" not in text and "gather" not in text
+    fa._splash_kernel.cache_clear()
+
+
+# ---------------------------------------------------------- expert layer
+
+E, K, D_MODEL, D_EXP = 8, 2, 32, 16
+
+
+def _moe_leaves(seed=0):
+    rng = np.random.default_rng(seed)
+    g = lambda *s, sc: jnp.asarray(rng.standard_normal(s).astype("f4") * sc)
+    return {"router_w": g(D_MODEL, E, sc=0.5),
+            "exp_w1": g(E, D_MODEL, D_EXP, sc=0.2),
+            "exp_w3": g(E, D_MODEL, D_EXP, sc=0.2),
+            "exp_w2": g(E, D_EXP, D_MODEL, sc=0.2),
+            "sh_w1": g(D_MODEL, D_EXP, sc=0.2),
+            "sh_w3": g(D_MODEL, D_EXP, sc=0.2),
+            "sh_w2": g(D_EXP, D_MODEL, sc=0.2)}
+
+
+def _ref_cfg(offset=0, top_k=K):
+    return dict(E=E, top_k=top_k, route_norm=True, route_scale=2.826,
+                offset=offset)
+
+
+def _share(p, bias, held, offset, shared=False):
+    """The program's layer holding experts offset .. offset + held."""
+    from paddle_tpu.models.afmoe import _swiglu
+    sh = None
+    if shared:
+        sh = _swiglu(D_MODEL, D_EXP, AfmoeConfig())
+        sh.gate_proj.weight.value = p["sh_w1"]
+        sh.up_proj.weight.value = p["sh_w3"]
+        sh.down_proj.weight.value = p["sh_w2"]
+    m = TokenChoiceMoE(D_MODEL, D_EXP, E, K, experts_held=held,
+                       expert_offset=offset, shared_expert=sh,
+                       route_scale=2.826)
+    m.router.weight.value = p["router_w"]
+    m.expert_bias.value = bias
+    sl = slice(offset, offset + held)
+    m.experts.w1.value, m.experts.w3.value, m.experts.w2.value = (
+        p["exp_w1"][sl], p["exp_w3"][sl], p["exp_w2"][sl])
+    return m
+
+
+def test_shares_add_up_to_the_uncut_layer():
+    """8 experts in 4 shares of 2: the shares' routed parts plus the
+    shared expert counted once equal the uncut reference's layer output,
+    and every share counts the same tokens by expert."""
+    p, x = _moe_leaves(), _rand(3, 20, D_MODEL, seed=5)
+    bias = _rand(E, seed=6, scale=0.1)
+    want, counts = R.moe_forward(p, x, bias, _ref_cfg())
+    total = 0
+    for share in range(4):
+        y, c = _share(p, bias, 2, 2 * share, shared=share == 0)(
+            paddle.to_tensor(np.asarray(x)))
+        total = total + np.asarray(y.value)
+        assert np.array_equal(np.asarray(c.value), np.asarray(counts))
+    np.testing.assert_allclose(total, np.asarray(want), atol=5e-6)
+    assert last_moe_dispatch() == {
+        "kernel": "xla_ragged_dot", "experts_held": 2,
+        "experts_published": 8, "top_k": 2, "rows_bound": 96}
+
+
+@pytest.mark.parametrize("held,offset,bias,dense", [
+    (8, 0, (10., 9.), False), (4, 2, (10., 9.), False),
+    (2, 0, (10., 0.), False), (2, 0, (10., 9.), True)])
+def test_every_token_to_one_expert_drops_nothing(held, offset, bias, dense):
+    """A bias that sends every token to expert 0 (and to expert 1): on a
+    share of 2 that lands twice an even routing's rows, inside the sorted
+    rows, or four times, which the dense path takes; on the uncut layer
+    every row the layer has.
+    Output and every gradient equal the reference's: nothing is
+    dropped."""
+    p, x = _moe_leaves(1), _rand(2, 30, D_MODEL, seed=7)
+    bias = jnp.asarray(bias + (0.,) * 6, jnp.float32)
+    m = _share(p, bias, held, offset)
+    xt = paddle.to_tensor(np.asarray(x))
+    xt.stop_gradient = False
+    y, counts = m(xt)
+    counts = np.asarray(counts.value)
+    assert counts[0] == 60 and counts.sum() == 120
+    landed = counts[offset:offset + held].sum()
+    assert dense == (landed > m.experts.rows_bound(60))
+    sl = slice(offset, offset + held)
+
+    def ref(xv, rw, w1, w3, w2):
+        return R.moe_forward({"router_w": rw, "exp_w1": w1, "exp_w3": w3,
+                              "exp_w2": w2}, xv, bias, _ref_cfg(offset),
+                             shared=False)[0]
+    args = (x, p["router_w"], p["exp_w1"][sl], p["exp_w3"][sl],
+            p["exp_w2"][sl])
+    np.testing.assert_allclose(np.asarray(y.value), np.asarray(ref(*args)),
+                               atol=5e-6)
+    (y * y).sum().backward()
+    want = jax.grad(lambda *a: (ref(*a) ** 2).sum(), range(5))(*args)
+    got = (xt.grad, m.router.weight.grad, m.experts.w1.grad,
+           m.experts.w3.grad, m.experts.w2.grad)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g.value), np.asarray(r),
+                                   rtol=1e-5, atol=2e-4)
+
+
+@pytest.mark.parametrize("held,offset", [(8, 0), (4, 2)])
+def test_expert_layer_under_checkpoint_gives_the_same_gradients(held,
+                                                                offset):
+    """The layer is a function of values (the counts come out, nothing
+    goes through a side channel): under ``jax.checkpoint`` it traces and
+    gives the gradients it gives without."""
+    from paddle_tpu.jit.functional import functional_call, raw_state
+    p, x = _moe_leaves(2), _rand(2, 24, D_MODEL, seed=8)
+    m = _share(p, _rand(E, seed=9, scale=0.1), held, offset, shared=True)
+    params, buffers = raw_state(m)
+
+    def loss(params, x):
+        (y, counts), _ = functional_call(m, params, buffers, x)
+        return (y ** 2).sum() + 0.0 * counts.sum(), counts
+
+    plain = jax.grad(loss, (0, 1), has_aux=True)(params, x)
+    remat = jax.jit(jax.grad(jax.checkpoint(loss), (0, 1), has_aux=True))(
+        params, x)
+    for a, b in zip(jax.tree_util.tree_leaves(plain),
+                    jax.tree_util.tree_leaves(remat)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-5)
+
+
+def test_note_load_keeps_counts_and_moves_the_bias():
+    m = _share(_moe_leaves(), jnp.zeros((E,)), 8, 0)
+    c = jnp.asarray([5., 1., 3., 3., 0., 9., 3., 0.])
+    m.note_load(c)
+    m.note_load(2 * c)
+    assert np.array_equal(np.asarray(m.expert_load.value), 2 * np.asarray(c))
+    assert np.array_equal(np.asarray(m.expert_load_total.value),
+                          3 * np.asarray(c))
+    np.testing.assert_allclose(
+        np.asarray(m.expert_bias.value),
+        2e-3 * np.asarray([-1, 1, 0, 0, 1, -1, 0, 1], "f4"))
+
+
+def test_moe_layer_refuses_experts_not_published():
+    with pytest.raises(ValueError):
+        TokenChoiceMoE(8, 4, 8, 2, experts_held=4, expert_offset=6)
+
+
+# ----------------------------------------------------------------- model
+
+ARCH = dict(hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
+            head_dim=8, intermediate_size=48, moe_intermediate_size=16,
+            num_shared_experts=1, num_experts=4, num_experts_published=8,
+            expert_offset=2, num_experts_per_tok=3, vocab_size=64,
+            num_hidden_layers=3, num_dense_layers=1,
+            layer_types=["sliding_attention", "sliding_attention",
+                         "full_attention"],
+            sliding_window=4, rms_norm_eps=1e-5, rope_theta=10000,
+            route_norm=True, route_scale=2.826, load_balance_coeff=0.001,
+            initializer_range=0.02)
+JOB = dict(compute_dtype="float32", master_weights=True, learning_rate=1e-3,
+           beta1=0.9, beta2=0.999, epsilon=1e-8, weight_decay=0.01)
+
+
+def _program(recompute, fused_loss_chunk=8, seed=5):
+    from benchmark.drivers.train_steps_afmoe import program_layout
+    cfg = AfmoeConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=16, num_hidden_layers=3, num_dense_layers=1,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+        layer_types=ARCH["layer_types"], sliding_window=4, num_experts=8,
+        experts_held=4, expert_offset=2, num_experts_per_tok=3,
+        max_seq_len=64, recompute=recompute,
+        fused_loss_chunk=fused_loss_chunk)
+    model = AfmoeForCausalLM(cfg)
+    leaves = R.init_params(ARCH, seed, jnp.float32)
+    layout = program_layout(ARCH)
+    assert set(layout) == {n for n, _ in model.named_parameters()}
+    load_state(model, {prog: leaves[leaf] if at is None else leaves[leaf][at]
+                       for prog, (leaf, at) in layout.items()})
+    return model, leaves, layout
+
+
+@pytest.mark.parametrize("recompute", [False, True])
+def test_model_two_train_steps_match_the_reference(recompute):
+    """``AfmoeForCausalLM`` + ``make_loss_fn()`` + ``AdamW`` +
+    ``TrainStep``: both losses, every leaf's first gradient (Adam's first
+    moment over 1 - beta1), the counts of tokens by expert of both steps'
+    routing and the expert bias after them, against the reference's two
+    steps; with and without per-block recomputation."""
+    model, leaves, layout = _program(recompute)
+    opt = paddle.optimizer.AdamW(
+        learning_rate=1e-3, beta1=0.9, beta2=0.999, epsilon=1e-8,
+        weight_decay=0.01, parameters=model.parameters())
+    step = TrainStep(model, model.make_loss_fn(), opt)
+    ids = np.random.default_rng(0).integers(0, 64, (2, 16))
+    bias0 = jnp.zeros((2, 8))
+    (_, counts1), grads = jax.value_and_grad(
+        lambda p: R.loss_whole(p, bias0, jnp.asarray(ids), ARCH),
+        has_aux=True)(leaves)
+    ref = R.train_readings(ARCH, JOB, 5, [ids, ids])
+
+    loss1 = float(step(paddle.to_tensor(ids), paddle.to_tensor(ids)))
+    load1 = np.stack([np.asarray(step.buffers[f"model.block_{i}.mlp."
+                                              "expert_load"]) for i in (1, 2)])
+    for prog, (leaf, at) in layout.items():
+        got = np.asarray(step.opt_state[prog]["moment1"]) / (1 - 0.9)
+        want = np.asarray(grads[leaf] if at is None else grads[leaf][at])
+        np.testing.assert_allclose(got, want, atol=2e-5, err_msg=prog)
+    loss2 = float(step(paddle.to_tensor(ids), paddle.to_tensor(ids)))
+    np.testing.assert_allclose([loss1, loss2], ref["losses"], rtol=1e-5)
+    assert np.array_equal(load1, np.asarray(counts1))
+    assert np.array_equal(load1, ref["expert_load"])
+    bias = np.stack([np.asarray(step.buffers[f"model.block_{i}.mlp."
+                                             "expert_bias"]) for i in (1, 2)])
+    np.testing.assert_allclose(bias, ref["expert_bias"], atol=1e-7)
+    assert np.abs(bias).max() > 0          # the step moved it
+    # the running counts hold both steps'
+    for i in (1, 2):
+        total = np.asarray(step.buffers[f"model.block_{i}.mlp."
+                                        "expert_load_total"])
+        last = np.asarray(step.buffers[f"model.block_{i}.mlp.expert_load"])
+        assert np.array_equal(total, load1[i - 1] + last)
+
+
+def test_model_eval_returns_logits_and_leaves_buffers():
+    model, leaves, _ = _program(False)
+    model.eval()
+    ids = np.random.default_rng(1).integers(0, 64, (2, 16))
+    logits = model(paddle.to_tensor(ids))
+    assert tuple(logits.shape) == (2, 16, 64)
+    loss, _ = R.loss_whole(leaves, jnp.zeros((2, 8)), jnp.asarray(ids), ARCH)
+    got = model.make_loss_fn()(logits, paddle.to_tensor(ids))
+    np.testing.assert_allclose(float(got), float(loss), rtol=1e-5)
+    assert all(float(np.abs(np.asarray(b.value)).max()) == 0.0
+               for n, b in model.named_buffers())
+
+
+def test_every_new_layer_registers_its_scope():
+    """``TrainStep.op_scopes()`` maps device operations by these names."""
+    model, _, _ = _program(True)
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                 parameters=model.parameters())
+    step = TrainStep(model, model.make_loss_fn(), opt)
+    ids = np.random.default_rng(0).integers(0, 64, (2, 16))
+    step(paddle.to_tensor(ids), paddle.to_tensor(ids))
+    paths = " ".join(set(step.op_scopes().values()))
+    for scope in ("router", "experts", "shared_expert", "attn", "q_norm",
+                  "k_norm", "gate_proj", "input_layernorm",
+                  "post_attention_layernorm", "pre_mlp_layernorm",
+                  "post_mlp_layernorm", "head_loss", "optimizer"):
+        assert f"/{scope}/" in paths or f"({scope})" in paths, scope
+
+
+def test_default_layer_kinds_follow_the_published_pattern():
+    cfg = AfmoeConfig()
+    kinds = cfg.kinds()
+    assert len(kinds) == 32 and kinds[:4] == (
+        "sliding_attention",) * 3 + ("full_attention",)
+    assert kinds.count("full_attention") == 8
+    with pytest.raises(ValueError):
+        AfmoeConfig(num_hidden_layers=2, layer_types=["full_attention"]
+                    ).kinds()
+
+
+def test_use_moe_recompute_points_at_the_new_layer():
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=32, hidden_size=16, num_layers=1, num_heads=2,
+        max_seq_len=8, use_moe=True, moe_experts=2, recompute=True))
+    with pytest.raises(NotImplementedError, match="TokenChoiceMoE"):
+        model(paddle.to_tensor(np.zeros((1, 8), "int64")))

@@ -59,6 +59,7 @@ def test_rehearse_kernels(smoke, monkeypatch):
     monkeypatch.setattr(mega_decode, "_L_BLOCK", 16)
     smoke.phase_kernels(dict(
         flash=((1, 128, 2, 64),), flash_block=((1, 2, 256, 64),),
+        flash_gqa=((1, 128, 4, 2, 64, 48), (1, 128, 4, 1, 64, None)),
         cache=(3, 32, 2, 128), pool=(8, 4, 2, 128), ce=(48, 640),
         mega=((3, 32, 2, 128),)))
 

@@ -222,19 +222,40 @@ def _flash_block(shape, bwd):
     return fn, [_sd(shape, BF16)] * 3, 3 if bwd else 1
 
 
-def _library_flash(shape):
+def _library_flash(shape, kv_heads=None, window=None):
     """flash_attention._pallas_flash: the library's splash kernel with
-    this repo's block rule, the forward and the one fused backward."""
+    this repo's block rule, the forward and the one fused backward;
+    optionally with fewer key/value heads and a causal window."""
     import importlib
     fa = importlib.import_module("paddle_tpu.nn.functional.flash_attention")
     scale = 1.0 / shape[-1] ** 0.5
+    kv_shape = shape[:2] + (kv_heads or shape[2],) + shape[3:]
 
     def loss(q, k, v):
         # traced as on the chip: off it the kernel is built to interpret
         with mock.patch.object(fa, "_on_tpu", lambda: True):
-            return fa._pallas_flash(q, k, v, True, scale).astype(
+            return fa._pallas_flash(q, k, v, True, scale, window).astype(
                 jnp.float32).sum()
-    return jax.grad(loss, argnums=(0, 1, 2)), [_sd(shape, BF16)] * 3, 2
+    return (jax.grad(loss, argnums=(0, 1, 2)),
+            [_sd(shape, BF16), _sd(kv_shape, BF16), _sd(kv_shape, BF16)], 2)
+
+
+def _grouped_experts():
+    """distributed/moe.py's sorted path at Trinity-Mini's share: 16,384
+    tokens, 8 of 128 experts a token, 16 held, 49,152 sorted rows through
+    the library's megablox kernels, forward and backward."""
+    import importlib
+    moe = importlib.import_module("paddle_tpu.distributed.moe")
+
+    def loss(x, w1, w3, w2, wgt, sel):
+        with mock.patch.object(moe, "_on_tpu", lambda: True):
+            here = sel < 16
+            return moe._routed_sorted(x, w1, w3, w2, wgt, sel, here,
+                                      49152).astype(jnp.float32).sum()
+    return (jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+            [_sd((16384, 2048), BF16), _sd((16, 2048, 1024), BF16),
+             _sd((16, 2048, 1024), BF16), _sd((16, 1024, 2048), BF16),
+             _sd((16384, 8), F32), _sd((16384, 8), I32)], 9)
 
 
 def _slot_write(dtype, tail):
@@ -301,6 +322,11 @@ CHIP_COMPILE_CASES = {
                                                   True),
     "library_flash_gpt125m": lambda: _library_flash((8, 1024, 12, 64)),
     "library_flash_gpt1p3b": lambda: _library_flash((4, 2048, 16, 128)),
+    "library_flash_gqa_window_8k": lambda: _library_flash(
+        (2, 8192, 32, 128), kv_heads=4, window=2048),
+    "library_flash_gqa_full_8k": lambda: _library_flash(
+        (2, 8192, 32, 128), kv_heads=4),
+    "grouped_experts_trinity_share": _grouped_experts,
     "fused_slot_write_bf16": lambda: _slot_write(BF16, (_H, _D)),
     "fused_slot_write_int8": lambda: _slot_write(I8, (_H, _D)),
     "fused_slot_write_scale": lambda: _slot_write(F32, (_H,)),
@@ -493,6 +519,42 @@ def test_kernel_inside_the_shard_map_is_built_for_the_shard(topo,
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 2
     assert "bf16[2,4,1024,64]" in text             # the shard's operands
+
+
+def test_fewer_kv_heads_than_mp_still_take_the_kernel(topo,
+                                                      chip_like_config,
+                                                      monkeypatch):
+    """mp=4 on the described chips, 8 query heads on 2 key/value heads
+    (a Llama block's GQA call): "mp" does not divide the key/value heads,
+    so the functional copies each out twice, the least that divides, and
+    the kernel is built inside the shard_map for the shard's 2 query
+    heads on 1 key/value head. Nothing falls to the XLA path."""
+    import importlib
+    import paddle_tpu as paddle
+    import paddle_tpu.nn.functional as F
+    fa = importlib.import_module("paddle_tpu.nn.functional.flash_attention")
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    monkeypatch.setenv("PADDLE_TPU_REQUIRE_PALLAS", "1")
+    dist.init_mesh({"mp": 4}, devices=list(topo.devices))
+    built = []
+    real = fa._splash_kernel.__wrapped__
+    monkeypatch.setattr(fa, "_splash_kernel", lambda heads, *a: (
+        built.append((heads, a[-1])), real(heads, *a))[1])
+    k4 = fa._kv_for_mesh(jnp.zeros((2, 256, 8, 64)),
+                         *[jnp.arange(2.).reshape(1, 1, 2, 1)] * 2)[0]
+    assert k4.ravel().tolist() == [0., 0., 1., 1.]
+
+    def loss(q, k, v):
+        out, _ = F.flash_attention(paddle.to_tensor(q), paddle.to_tensor(k),
+                                   paddle.to_tensor(v), causal=True)
+        return out.value.astype(jnp.float32).sum()
+    q, kv = _sd((2, 1024, 8, 64), BF16), _sd((2, 1024, 2, 64), BF16)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    assert set(built) == {(2, True)}        # 2 query heads, grouped
+    d = fa.last_attention_dispatch()
+    assert d["backend"] == "pallas" and d["kv_heads"] == 4
+    assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
 def test_zero3_train_step_compiles_for_four_v5e_chips(topo,
