@@ -6,6 +6,8 @@ matching reference c_softmax_with_cross_entropy_op).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -585,6 +587,69 @@ def rnnt_loss(input, label, input_lengths, label_lengths, blank=0,
                  _op_name="rnnt_loss")
 
 
+def _head_logits(xi, w, tw):
+    """One chunk through the head: ``[C, H]`` to f32 logits ``[C, V]``."""
+    return jnp.matmul(xi, w.T if tw else w,
+                      preferred_element_type=jnp.float32)
+
+
+def _head_chunk_loss(lg, ii, ignore_index):
+    """Summed loss of a chunk's tokens that count, from its f32 logits;
+    beside it what the logits' gradient is made of."""
+    m = jnp.max(lg, axis=-1)
+    s = jnp.sum(jnp.exp(lg - m[:, None]), axis=-1)
+    safe = jnp.clip(ii, 0, lg.shape[-1] - 1).astype(jnp.int32)
+    gold = jnp.take_along_axis(lg, safe[:, None], axis=-1)[:, 0]
+    valid = ii != ignore_index
+    loss = jnp.sum(jnp.where(valid, jnp.log(s) + m - gold, 0.0))
+    return loss, (m, s, safe, valid)
+
+
+def _head_count(ic, ignore_index):
+    return jnp.maximum(jnp.sum(ic != ignore_index), 1).astype(jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _chunked_head_ce(xc, w, ic, tw, ignore_index):
+    """Mean cross-entropy of ``xc [n, C, H]`` through the head ``w`` over
+    the labels ``ic [n, C]`` that are not ``ignore_index``, a chunk at a
+    time. Reverse-mode only (a ``custom_vjp``)."""
+    sums = jax.lax.map(
+        lambda a: _head_chunk_loss(_head_logits(a[0], w, tw), a[1],
+                                   ignore_index)[0], (xc, ic))
+    return jnp.sum(sums) / _head_count(ic, ignore_index)
+
+
+def _chunked_head_ce_fwd(xc, w, ic, tw, ignore_index):
+    # The loss is a mean of per-token terms, so a chunk's logit gradient
+    # (softmax - onehot) * valid / count is known with its logits, up to
+    # the scalar from above: the two gradient products (the transposes of
+    # the chunk's own product) run here, on the logits the loss was read
+    # from, and nothing [*, V] outlives a chunk.
+    count = _head_count(ic, ignore_index)
+
+    def body(dw, args):
+        xi, ii = args
+        lg, pull = jax.vjp(lambda x, w_: _head_logits(x, w_, tw), xi, w)
+        loss, (m, s, safe, valid) = _head_chunk_loss(lg, ii, ignore_index)
+        dlg = (jnp.exp(lg - m[:, None]) / s[:, None]
+               - jax.nn.one_hot(safe, lg.shape[-1], dtype=jnp.float32)) \
+            * (valid.astype(jnp.float32) / count)[:, None]
+        dxi, dwi = pull(dlg)
+        return dw + dwi, (loss, dxi)
+
+    dw, (sums, dxc) = jax.lax.scan(body, jnp.zeros_like(w), (xc, ic))
+    return jnp.sum(sums) / count, (dxc, dw)
+
+
+def _chunked_head_ce_bwd(tw, ignore_index, res, g):
+    dxc, dw = res
+    return (g * dxc).astype(dxc.dtype), (g * dw).astype(dw.dtype), None
+
+
+_chunked_head_ce.defvjp(_chunked_head_ce_fwd, _chunked_head_ce_bwd)
+
+
 def fused_linear_cross_entropy(hidden, weight, label, chunk_size=512,
                                ignore_index=-100, transpose_weight=None,
                                name=None):
@@ -595,12 +660,15 @@ def fused_linear_cross_entropy(hidden, weight, label, chunk_size=512,
     (cross_entropy_kernel.cu), so the full logits tensor lives in HBM in
     both passes — at GPT geometry (8k tokens x 50k vocab) that is ~824 MB
     bf16 forward plus the same again for dlogits in backward. Here tokens
-    stream through the projection in chunks under a rematerialized
-    `lax.map`: each chunk's logits exist only transiently, backward
-    recomputes them chunk-wise (jax.checkpoint), and dW accumulates
-    across chunks inside the scan transpose. Peak extra memory is
-    O(chunk_size x vocab) instead of O(N x vocab) — the lever that turns
-    LM-head memory from batch-bound into a constant.
+    stream through the projection in chunks and each chunk's logits are
+    computed ONCE: under differentiation the forward pass of a chunk
+    forms ``softmax - onehot`` from the logits it has in hand and runs
+    both gradient products at once (``dW`` accumulates across chunks in
+    the scan's carry), so the backward pass only scales ``dx`` and ``dW``
+    by the incoming cotangent. Peak extra memory is O(chunk_size x vocab)
+    instead of O(N x vocab) — the lever that turns LM-head memory from
+    batch-bound into a constant. Reverse-mode only (a ``custom_vjp``, as
+    ``_fused_softmax_ce`` is): ``jax.jvp`` through it raises.
 
     hidden: [N, H] or [B, S, H]; label: int [N] or [B, S];
     weight: [V, H] (embedding/tied layout) or [H, V]
@@ -619,7 +687,6 @@ def fused_linear_cross_entropy(hidden, weight, label, chunk_size=512,
                     "fused_linear_cross_entropy: square weight is "
                     "ambiguous — pass transpose_weight explicitly")
             tw = w.shape[-1] == H          # [V, H] -> project with w.T
-        V = w.shape[0] if tw else w.shape[-1]
         xf = x.reshape(-1, H)
         idx = lbl.reshape(-1)
         N = xf.shape[0]
@@ -630,25 +697,7 @@ def fused_linear_cross_entropy(hidden, weight, label, chunk_size=512,
                 [xf, jnp.zeros((pad, H), xf.dtype)], axis=0)
             idx = jnp.concatenate(
                 [idx, jnp.full((pad,), ignore_index, idx.dtype)], axis=0)
-        xc = xf.reshape(-1, C, H)
-        ic = idx.reshape(-1, C)
-
-        def body(args):
-            xi, ii = args
-            wm = w.T if tw else w
-            lg = jnp.matmul(xi, wm,
-                            preferred_element_type=jnp.float32)  # [C, V]
-            m = jnp.max(lg, axis=-1)
-            s = jnp.sum(jnp.exp(lg - m[:, None]), axis=-1)
-            safe = jnp.clip(ii, 0, V - 1).astype(jnp.int32)
-            gold = jnp.take_along_axis(lg, safe[:, None], axis=-1)[:, 0]
-            per = jnp.log(s) + m - gold
-            valid = ii != ignore_index
-            return (jnp.sum(jnp.where(valid, per, 0.0)),
-                    jnp.sum(valid.astype(jnp.int32)))
-
-        sums, counts = jax.lax.map(jax.checkpoint(body), (xc, ic))
-        total = jnp.sum(counts)
-        return jnp.sum(sums) / jnp.maximum(total, 1).astype(jnp.float32)
+        return _chunked_head_ce(xf.reshape(-1, C, H), w, idx.reshape(-1, C),
+                                bool(tw), ignore_index)
 
     return apply(f, hidden, weight, _op_name="fused_linear_cross_entropy")

@@ -164,28 +164,90 @@ class TestFusedLoss:
         np.testing.assert_allclose(traj["plain"], traj["fused"],
                                    rtol=1e-5)
 
-    def test_functional_parity_with_ignore_index(self):
+    @pytest.mark.parametrize("scale", [1.0, 3.0])
+    @pytest.mark.parametrize("labels", ["some_ignored", "chunk_ignored",
+                                        "all_ignored"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("layout", ["vh", "hv"])
+    def test_functional_parity_with_ignore_index(self, layout, dtype,
+                                                 labels, scale):
+        # loss and both gradients (taken in the forward pass of each
+        # chunk, scaled by the incoming cotangent) against the plain
+        # matmul + cross_entropy on float32 copies of the same values
         import paddle_tpu.nn.functional as F
         import paddle_tpu.tensor as T
         rng = np.random.RandomState(0)
-        N, H, V = 70, 16, 37  # non-multiple of chunk -> padding path
-        x = paddle.to_tensor(rng.randn(N, H).astype("float32"))
-        w = paddle.to_tensor(rng.randn(V, H).astype("float32"))
+        N, H, V, C = 70, 16, 37, 16  # non-multiple of chunk -> padding
         lbl = rng.randint(0, V, (N,))
-        lbl[::7] = -100
+        if labels == "some_ignored":
+            lbl[::7] = -100
+        elif labels == "chunk_ignored":
+            lbl[C:2 * C] = -100
+        else:
+            lbl[:] = -100
         lt = paddle.to_tensor(lbl.astype("int64"))
-        x.stop_gradient = False
-        w.stop_gradient = False
-        loss_f = F.fused_linear_cross_entropy(x, w, lt, chunk_size=16)
-        loss_f.backward()
-        gx, gw = np.asarray(x.grad), np.asarray(w.grad)
-        x.clear_grad(), w.clear_grad()
-        logits = paddle.matmul(x, T.transpose(w, [1, 0]))
+        x = paddle.to_tensor(rng.randn(N, H).astype("float32")).astype(dtype)
+        w = paddle.to_tensor(rng.randn(*((V, H) if layout == "vh" else
+                                         (H, V))).astype("float32")
+                             ).astype(dtype)
+        xr, wr = x.astype("float32"), w.astype("float32")
+        for t in (x, w, xr, wr):
+            t.stop_gradient = False
+        loss_f = F.fused_linear_cross_entropy(x, w, lt, chunk_size=C)
+        (scale * loss_f).backward()
+        logits = paddle.matmul(
+            xr, T.transpose(wr, [1, 0]) if layout == "vh" else wr)
         loss_r = F.cross_entropy(logits, lt, ignore_index=-100)
-        loss_r.backward()
+        (scale * loss_r).backward()
         assert abs(float(loss_f) - float(loss_r)) < 1e-5
-        np.testing.assert_allclose(gx, np.asarray(x.grad), atol=1e-6)
-        np.testing.assert_allclose(gw, np.asarray(w.grad), atol=1e-6)
+        for got, ref in ((x.grad, xr.grad), (w.grad, wr.grad)):
+            assert str(got.dtype).endswith(dtype)
+            got = np.asarray(got.astype("float32"))
+            ref = np.asarray(ref)
+            assert np.isfinite(got).all()
+            tol = 1e-6 if dtype == "float32" else \
+                2.0 ** -7 * max(np.abs(ref).max(), 1e-30)
+            np.testing.assert_allclose(got, ref, atol=tol, rtol=0)
+        if labels == "all_ignored":
+            assert float(loss_f) == 0.0
+            assert not np.asarray(x.grad.astype("float32")).any()
+            assert not np.asarray(w.grad.astype("float32")).any()
+
+    def test_logits_are_computed_once(self):
+        # under differentiation the chunk loop holds three
+        # vocabulary-sized products (logits, dx, dW) and nothing is
+        # rematerialized; the primal alone holds one
+        import jax
+        import paddle_tpu.nn.functional as F
+        N, H, V = 64, 16, 41
+
+        def loss(x, w, lbl):
+            return F.fused_linear_cross_entropy(
+                paddle.Tensor(x), paddle.Tensor(w), paddle.Tensor(lbl),
+                chunk_size=16).value
+
+        def primitives(jaxpr, out):
+            for eqn in jaxpr.eqns:
+                out.append(eqn)
+                for v in eqn.params.values():
+                    for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            primitives(sub, out)
+            return out
+
+        def vocab_products(fn):
+            eqns = primitives(jax.make_jaxpr(fn)(
+                np.zeros((N, H), "float32"), np.zeros((V, H), "float32"),
+                np.zeros((N,), "int32")).jaxpr, [])
+            names = {e.primitive.name for e in eqns}
+            assert not names & {"checkpoint", "remat", "remat2"}, names
+            return sum(e.primitive.name == "dot_general" and any(
+                V in v.aval.shape for v in (*e.invars, *e.outvars))
+                for e in eqns)
+
+        assert vocab_products(loss) == 1
+        assert vocab_products(jax.grad(loss, argnums=(0, 1))) == 3
 
     def test_square_weight_raises(self):
         import paddle_tpu.nn.functional as F
@@ -197,15 +259,17 @@ class TestFusedLoss:
 
 
 class TestScanLayersDistributed:
-    def test_dp_mp_step_matches_unrolled(self):
+    @pytest.mark.parametrize("fused_loss_chunk", [0, 32])
+    def test_dp_mp_step_matches_unrolled(self, fused_loss_chunk):
         # the stacked leaves carry (None,)+inner sharding annotations —
         # prove they are correct by training the scanned model under the
         # hybrid engine on the virtual mesh and matching the unrolled
-        # model's loss trajectory exactly
+        # model's loss trajectory exactly; with fused_loss_chunk the
+        # head's dW rides the chunk loop under the mp-sharded vocabulary
         import paddle_tpu.distributed as dist
         dist.init_mesh({"dp": 2, "mp": 2})
         try:
-            m_u, m_s = _scanned_pair()
+            m_u, m_s = _scanned_pair(fused_loss_chunk=fused_loss_chunk)
             sd = dict(m_s.named_parameters())
             assert sd["gpt.blocks.attn__qkv__weight"].sharding_axes == \
                 (None, None, "mp")
@@ -216,8 +280,7 @@ class TestScanLayersDistributed:
             for tag, m in (("unrolled", m_u), ("scanned", m_s)):
                 opt = paddle.optimizer.AdamW(learning_rate=1e-3,
                                              parameters=m.parameters())
-                step = dist.ParallelTrainStep(
-                    m, GPTForCausalLM.loss_fn, opt)
+                step = dist.ParallelTrainStep(m, m.make_loss_fn(), opt)
                 losses[tag] = [float(step(ids, ids)) for _ in range(3)]
             np.testing.assert_allclose(losses["unrolled"],
                                        losses["scanned"],

@@ -467,6 +467,16 @@ def test_gpt1p3b_width_step_carries_its_scopes_for_v5e(one_chip,
             ("head_loss", "backward"), ("optimizer", "update"),
             ("scan_carry", "backward"),
             ("word_embeddings", "forward")} <= found
+    # the head's logits are computed once: of a chunk's three products
+    # the logits read forward and the two gradient products backward
+    # (transposes taken inside the forward rule), and nothing of the
+    # head is recomputed
+    assert ("head_loss", "recompute") not in found
+    products = [rp.read_scope(table[n]) for n in re.findall(
+        r"%([\w.\-]+) = [^\n]* convolution\(", text)]
+    assert sorted(p["pass"] for p in products
+                  if p["region"] == "head_loss") == [
+        "backward", "backward", "forward"]
 
 
 # -- the library kernel under a multi-device mesh ---------------------------

@@ -194,11 +194,11 @@ RULE_CASES = {
         J + "jvp(gptforcausallm)/gpt/ln_f/mul", "forward",
         "gptforcausallm/gpt/ln_f", "ln_f"),
     "loss_forward_wrapped_by_the_transform": (
-        J + "jvp(head_loss)/head_loss/while/body/checkpoint/dot_general",
-        "forward", "head_loss/head_loss", "head_loss"),
-    "loss_recomputes_its_logits": (
-        J + "transpose(jvp(head_loss))/head_loss/while/body/checkpoint/"
-        "rematted_computation/exp", "recompute", "head_loss/head_loss",
+        J + "jvp(head_loss)/head_loss/while/body/closed_call/jvp()/"
+        "dot_general", "forward", "head_loss/head_loss", "head_loss"),
+    "loss_gradient_products_of_the_forward_rule": (
+        J + "jvp(head_loss)/head_loss/while/body/closed_call/"
+        "transpose(jvp())/dot_general", "backward", "head_loss/head_loss",
         "head_loss"),
     "optimizer_is_the_update": (
         J + "optimizer/mul", "update", "optimizer", "optimizer"),
