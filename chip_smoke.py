@@ -1,23 +1,29 @@
-"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+"""chip_smoke.py — the bring-up of what no benchmark cell covers yet.
 
-    python chip_smoke.py              # one TPU chip (what the driver runs)
+    python chip_smoke.py              # one TPU chip
     python chip_smoke.py --multichip  # four chips: the sharded paths only
 
-Drives the two main paths once through the entry points a user calls, at
-the full width of the models the repo supports (depth may be cut; weights
-are random, made from a seed), and checks every result by the repo's own
+The trainer is not here: every cell of BENCHMARK.json proves more of it
+in its set-up than a smoke could (finite falling loss, one trace, no
+later compile, the Pallas backend, and the step against a plain float32
+reference). For the trainer run
+``python3 benchmark/run.py --workload <cell> --seed <n> --seconds 10``.
+
+What this script drives, through the entry points a user calls, at the
+full width of the models the repo supports (depth may be cut; weights
+are random, made from a seed), checking every result by the repo's own
 means. Phases, cheap first, one printed line each as they go:
 
   device   jax.devices()[0].platform must be "tpu" — there is no CPU mode
   kernels  every Pallas kernel reachable from a public entry point,
            compiled (never interpreted) and run at real widths against
            its XLA reference under a stated tolerance
-  train    jit.TrainStep on GPTForCausalLM, bf16, AdamW: GPT-125M and
-           GPT-1.3B widths as bench.py builds them — loss falls, the
-           attention kernel is Pallas, one compilation per model
   serve    Router + one replica child at 1.3B widths, concurrent
            POST /generate (one streamed), /healthz truth, then a
            model.generate() reference once the tier has stopped
+  --multichip
+           the tp=4 engine and ZeRO-3, each against its one-chip
+           reference, on four chips
 
 The last stdout line is exactly
 ``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}``;
@@ -54,26 +60,6 @@ SEED = 0
 # and the attention dispatch is expected to be XLA.
 PLATFORM = "tpu"
 
-# GPT-1.3B on one 16 GB chip: the published 24 layers do not fit (state,
-# not batch — bench.py GPT1P3B_LAYERS_ONE_CHIP says why); bench.py and
-# this script cut depth to the same value, widths are never cut.
-GPT1P3B_LAYERS = 18
-
-# exactly bench.py's default cell. Its whole-step compile is the slow one
-# (about 290 s for v5e on 8 host cores) — and NOT because it is unrolled:
-# scanned it takes 274 s, at two layers still 236 s, with
-# fused_loss_chunk=2048 9.6 s. The un-chunked [8192, 50304] head/loss
-# region of the whole program is what the compiler labours over (PERF.md
-# "Bring-up on the chip"), so scan_layers would buy nothing here.
-TRAIN_125M = dict(
-    name="gpt125m", batch=8, seq=1024, multi_precision=True,
-    cfg=dict(vocab_size=50304, hidden_size=768, num_layers=12,
-             num_heads=12, max_seq_len=1024))
-TRAIN_1P3B = dict(
-    name="gpt1.3b", batch=4, seq=2048, multi_precision=False,
-    cfg=dict(vocab_size=50304, hidden_size=2048,
-             num_layers=GPT1P3B_LAYERS, num_heads=16, max_seq_len=2048,
-             recompute=True, scan_layers=True, fused_loss_chunk=2048))
 # serve depth: the replica holds f32 weights (ReplicaSpec builds the model
 # as GPTConfig does) and the scanned decode tick double-buffers its page
 # pool carry — at the published 24 layers the chip's compiler counts
@@ -101,8 +87,8 @@ MULTI_TP = dict(
     engine=dict(slots=4, max_len=512, cache_dtype="bfloat16"),
     prompt_lens=(32, 100, 256), new_tokens=32)
 # the loss is chunked here: un-chunked, the [tokens, 50304] head/loss
-# region alone costs minutes of compile (see TRAIN_125M), paid twice and
-# on four chips' clock
+# region alone costs minutes of compile (327 s at 8192 tokens; PERF.md
+# section 6, PR 24/27), paid twice and on four chips' clock
 MULTI_ZERO = dict(
     cfg=dict(vocab_size=50304, hidden_size=768, num_layers=2,
              num_heads=12, max_seq_len=1024, fused_loss_chunk=1024),
@@ -401,59 +387,6 @@ def phase_kernels(sizes=KERNEL_SIZES) -> None:
             err_ctx=e, tol=TOL_FWD, caches_exact=same)
         check(e <= TOL_FWD and same, f"mega_decode {B, L, nh, hd}: {e}")
     say("kernels", ok=True, device=dev, **cache_facts())
-
-
-# -------------------------------------------------------------------- train
-
-def phase_train(spec, steps: int = 4) -> None:
-    dev = require_device()
-    import jax
-    import numpy as np
-
-    import paddle_tpu as paddle
-    import paddle_tpu.nn.functional as F
-    from paddle_tpu.compilation import counters
-    from paddle_tpu.jit import TrainStep
-    from paddle_tpu.models import GPTConfig, GPTForCausalLM
-
-    cfg = GPTConfig(**spec["cfg"])
-    paddle.seed(SEED)
-    model = GPTForCausalLM(cfg)
-    model.bfloat16()
-    opt = paddle.optimizer.AdamW(learning_rate=1e-4,
-                                 multi_precision=spec["multi_precision"],
-                                 parameters=model.parameters())
-    step = TrainStep(model, model.make_loss_fn(), opt)
-    ids = paddle.to_tensor(np.random.RandomState(SEED).randint(
-        0, cfg.vocab_size, (spec["batch"], spec["seq"])).astype("int64"))
-    n_params = sum(int(np.prod(p.shape)) for p in model.parameters())
-
-    c0 = counters.xla_compiles()
-    t0 = time.perf_counter()
-    losses = [float(step(ids, ids))]
-    first_s = time.perf_counter() - t0
-    c1 = counters.xla_compiles()
-    losses += [float(step(ids, ids)) for _ in range(steps - 1)]
-    backend = F.last_attention_dispatch().get("backend")
-    peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
-    say("train", model=spec["name"], params=n_params,
-        layers=cfg.num_layers, hidden=cfg.hidden_size,
-        heads=cfg.num_heads, vocab=cfg.vocab_size, batch=spec["batch"],
-        seq=spec["seq"], scan_layers=cfg.scan_layers,
-        master_weights=spec["multi_precision"], losses=losses,
-        backend=backend, traces=step._trace_count,
-        first_step_s=round(first_s, 1),
-        xla_compiles_first_step=c1 - c0,
-        xla_compiles_later_steps=counters.xla_compiles() - c1,
-        peak_bytes_in_use=peak, device=dev, **cache_facts())
-    check(all(np.isfinite(losses)), f"loss not finite: {losses}")
-    check(losses[-1] < losses[0], f"loss did not fall: {losses}")
-    check(backend == ("pallas" if PLATFORM == "tpu" else "xla"),
-          f"attention dispatched to {backend!r}")
-    check(step._trace_count == 1,
-          f"{step._trace_count} traces of the step program, want 1")
-    check(counters.xla_compiles() == c1,
-          "a later step compiled again")
 
 
 # -------------------------------------------------------------------- serve
@@ -764,8 +697,6 @@ def _child(args) -> int:
     sys.path.insert(0, HERE)
     phases = {
         "kernels": phase_kernels,
-        "train_125m": lambda: phase_train(TRAIN_125M),
-        "train_1p3b": lambda: phase_train(TRAIN_1P3B),
         "serve": lambda: phase_serve(args.file),
         "serve_ref": lambda: phase_serve_ref(args.file),
         "multichip_tp": phase_multichip_tp,
@@ -797,8 +728,6 @@ def main() -> int:
         facts = _run_phase(["--phase", "multichip_zero"], "multichip")
     else:
         facts = _run_phase(["--phase", "kernels"], "kernels")
-        _run_phase(["--phase", "train_125m"])
-        _run_phase(["--phase", "train_1p3b"])
         with tempfile.TemporaryDirectory() as tmp:
             handoff = os.path.join(tmp, "served.json")
             _run_phase(["--phase", "serve", "--file", handoff])

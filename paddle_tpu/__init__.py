@@ -55,7 +55,7 @@ if _flags.flag_value("use_persistent_compilation_cache") and \
     # a line in ANY caller of a kernel — a model file, a driver script —
     # re-keys every program that contains one: a 327 s train-step
     # compile missed a warm cache for exactly that reason (PERF.md
-    # "Bring-up on the chip"). Innermost frame only keeps the key to
+    # section 6, PR 24/27). Innermost frame only keeps the key to
     # the kernel's own source.
     _jax.config.update("jax_include_full_tracebacks_in_locations", False)
 

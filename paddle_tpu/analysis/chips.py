@@ -1,9 +1,8 @@
 """Accelerator roofline constants — the ONE table.
 
-Deliberately dependency-free (stdlib dataclasses only) so tools that
-need three numbers — `tools/northstar_model.py` is a pure-arithmetic
-planning script that must run on machines without jax — can load this
-file standalone via importlib without paying (or requiring) the full
+Deliberately dependency-free (stdlib dataclasses only) so a tool that
+needs three numbers on a machine without jax can load this file
+standalone via importlib without paying (or requiring) the full
 paddle_tpu/jax import. Everything else imports it through
 `paddle_tpu.analysis.hlo_cost`, which re-exports the table for the
 tpucost roofline.
@@ -35,7 +34,7 @@ CHIP_SPECS: Dict[str, ChipSpec] = {
     "v5lite": ChipSpec("v5lite", peak_flops=197e12, hbm_bandwidth=819e9,
                        hbm_capacity=16 * 2**30, ici_gbps=1600,
                        device_kinds=("v5 lite", "v5e")),
-    # v5p: the north-star pod chip (tools/northstar_model.py)
+    # v5p: the pod chip (no cell runs on it)
     "v5p": ChipSpec("v5p", peak_flops=459e12, hbm_bandwidth=2765e9,
                     hbm_capacity=95 * 2**30, ici_gbps=4800,
                     device_kinds=("v5p",)),

@@ -15,8 +15,8 @@ running a step:
    printed instruction order of the entry computation IS the execution
    schedule — `gather_overlap_report` measures how the all-gathers
    actually interleave with compute, and `diff_schedules` puts two
-   programs' schedules side by side (the fp32-GSPMD vs quantized A/B
-   that tools/bench_collectives.py prints).
+   programs' schedules side by side (fp32-GSPMD against quantized
+   collectives).
 """
 from __future__ import annotations
 

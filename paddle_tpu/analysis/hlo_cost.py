@@ -27,9 +27,8 @@ a per-kernel inventory WITHOUT executing anything:
   the sum over kernels x trip counts.
 
 The chip-spec table here is the ONE place accelerator constants live:
-`tools/tpucost.py` defaults to v5-lite (the chip the measured 33.6% MFU
-anchor ran on) and `tools/northstar_model.py` imports its v5p numbers
-from the same table.
+`tools/tpucost.py` defaults to v5-lite, the chip the benchmark's cells
+run on.
 
 `check_cost_baseline` is the gate: per-program ratcheted budgets (total
 HBM bytes, kernel count, matmul-FLOP share floor) plus must-stay-true
@@ -62,9 +61,9 @@ __all__ = [
 ]
 
 # ---------------------------------------------------------------------------
-# chip specs — the one table lives in chips.py (dependency-free so
-# tools/northstar_model.py can load it without the package import);
-# re-exported here as the tpucost-facing surface
+# chip specs — the one table lives in chips.py (dependency-free, so a
+# tool can load it without the package import); re-exported here as
+# the tpucost-facing surface
 # ---------------------------------------------------------------------------
 
 from .chips import CHIP_SPECS, DEFAULT_CHIP, ChipSpec  # noqa: E402

@@ -5,10 +5,10 @@ site registered with the "manifest" tag is rebuilt exactly as its owner
 builds it (the builders live in compilation/sites.py) and handed to the
 program linter — trace + lower only (collective-tagged programs
 additionally compile for their collective inventory). One table serves
-every consumer: tpulint lints it, `compilation.warmup` prebuilds it,
-`tools/warmup.py` persists it to the executable store, and
-`tools/bench_cold_start.py` measures it — so a newly registered program
-is lint-covered, warmable, and store-cacheable BY DEFAULT, and the
+every consumer: tpulint lints it, `compilation.warmup` prebuilds it
+and `tools/warmup.py` persists it to the executable store — so a newly
+registered program is lint-covered, warmable, and store-cacheable BY
+DEFAULT, and the
 baseline keys (code::program::site) are the registry names.
 
 Current registry population (see compilation/sites.py for each):

@@ -93,7 +93,7 @@ def _replica_group_size(line: str, all_devices: int = 1) -> int:
 
 
 # per-chip transferred fraction of the RESULT bytes for a ring
-# algorithm over an n-wide group (the northstar_model.py accounting):
+# algorithm over an n-wide group:
 # all-gather's result is the full gathered tensor -> (n-1)/n of it
 # moves; reduce-scatter's result is the 1/n shard -> (n-1) x result;
 # ring all-reduce = reduce-scatter + all-gather phases; a permute is

@@ -7,8 +7,7 @@ module is the measurement half the MFU campaign's fusion loop needs
 op-TIME-weighted fusion report; MPK-style mega-kernelization, PAPERS.md
 2512.22219, needs that report as its target list): run a program under
 the programmatic ``jax.profiler``, parse the chrome trace it emits
-(stdlib gzip+json — no TensorBoard; the parser generalizes the one that
-used to live inside tools/profile_step.py), and JOIN the measured
+(stdlib gzip+json — no TensorBoard), and JOIN the measured
 per-kernel device time against ``hlo_cost.collect_kernels``' modeled
 inventory by kernel name. Per program that yields:
 
@@ -19,7 +18,7 @@ inventory by kernel name. Per program that yields:
 - the top unfused chains of PR 6 re-ranked by MEASURED time — the
   bytes-ranked candidate list turned into a seconds-ranked work list.
 
-Degrade contract (the profile_step smoke contract): a CPU backend's
+Degrade contract (tests/test_runtime_profile.py): a CPU backend's
 trace has no device plane — only ``/host:CPU`` dispatch events — so the
 report keeps the measured wall-time-per-dispatch (median-of-N) and
 marks the join unavailable; anchors that need kernel attribution are
@@ -188,8 +187,7 @@ def self_times(events: Sequence[Tuple[str, float, float]]
 
 def category_of(name: str, op_cat: Optional[Dict[str, str]] = None) -> str:
     """Display category for one kernel name: the profiler's own
-    ``hlo_category`` when recorded, else a name-pattern fallback (the
-    table tools/profile_step.py has always printed)."""
+    ``hlo_category`` when recorded, else a name-pattern fallback."""
     if op_cat and op_cat.get(name):
         return op_cat[name]
     n = name.lower()

@@ -16,7 +16,8 @@ eliminating them. One process-global set of counters fed by
   truthful "XLA actually compiled a program" count. An executable
   deserialized from the paddle_tpu executable store fires NOTHING here
   (it never enters jax's compile path at all) — which is exactly the
-  cold-start claim tools/bench_cold_start.py asserts.
+  cold-start claim tests/test_compilation.py::TestWarmup::
+  test_warmup_idempotent_second_pass_compiles_zero asserts.
 - ``compile_secs``: wall time spent inside the backend compile path.
 
 A trace (function -> jaxpr), a lowering (jaxpr -> MLIR module) and a

@@ -4,11 +4,11 @@ JSON-ready record.
 `framework/syncs.py` gives the training loop its host-sync ledger; this
 is the same idea for program compiles: every warmup / AOT compile /
 store load appends one record (name, source, trace_s, compile_s,
-signature), and consumers — ``/healthz``, ``tools/warmup.py``,
-``tools/bench_cold_start.py`` — read one summary dict instead of
-re-deriving state. With ``PADDLE_TPU_COMPILE_LOG=<path>`` the log is
-also mirrored to disk (atomic rewrite per append) so a crashed process
-leaves its compile history behind.
+signature), and consumers — ``/healthz``, ``tools/warmup.py`` — read
+one summary dict instead of re-deriving state. With
+``PADDLE_TPU_COMPILE_LOG=<path>`` the log is also mirrored to disk
+(atomic rewrite per append) so a crashed process leaves its compile
+history behind.
 """
 from __future__ import annotations
 

@@ -5,10 +5,9 @@ Before this subsystem, three consumers each hand-maintained their own
 list of "the real programs": `analysis/manifest.py` rebuilt them for
 tpulint, the serving/training warm paths had none (first traffic paid
 the compile), and benches re-derived them ad hoc. The registry is ONE
-table of (name -> builder); tpulint's manifest, `compilation.warmup`,
-`tools/warmup.py`, and `tools/bench_cold_start.py` all enumerate it,
-so a newly registered program is lint-covered, warmable, and
-store-cacheable by default.
+table of (name -> builder); tpulint's manifest, `compilation.warmup`
+and `tools/warmup.py` all enumerate it, so a newly registered program
+is lint-covered, warmable, and store-cacheable by default.
 
 A builder is a zero-arg callable returning a :class:`BuildResult`:
 the jitted program object (a ``jax.jit`` wrapper — the REAL site

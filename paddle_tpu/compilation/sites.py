@@ -3,9 +3,9 @@
 These builders were born in `analysis/manifest.py` (PR 3) as tpulint's
 private "rebuild the real programs" list; they now live here so ONE
 table serves every consumer: tpulint lints them, `compilation.warmup`
-prebuilds them, `tools/warmup.py` stores them, and
-`tools/bench_cold_start.py` measures them. Each builds the tiny-config
-variant of a production program exactly as its owner builds it:
+prebuilds them and `tools/warmup.py` stores them. Each builds the
+tiny-config variant of a production program exactly as its owner
+builds it:
 
 - gpt_decode:      the continuous-batching engine's batched decode tick
 - gpt_admit:       the engine's bucketed prefill/admission program

@@ -20,7 +20,8 @@ executables, whatever their owners put in ``static_key``.
 so ``tools/warmup.py --inspect`` can say "gpt_decode for THIS engine
 geometry is prebuilt" and a brand-new process can reach first token
 without invoking XLA's compiler at all (a deserialized executable fires
-no compile event — asserted by tools/bench_cold_start.py). Anything the
+no compile event — asserted by tests/test_compilation.py::TestWarmup::
+test_warmup_idempotent_second_pass_compiles_zero). Anything the
 backend refuses to serialize (or a corrupt/stale entry) degrades to the
 normal lazy-jit path, where the jax persistent cache — when enabled —
 is the second line of defense.

@@ -243,10 +243,10 @@ class ParallelTrainStep:
             # every matmul. Without this use-site constraint the SPMD
             # partitioner sees the zero axis on BOTH matmul operands
             # (batch rows of x, contraction dim of W) and can resolve
-            # the conflict by un-sharding the ACTIVATIONS — measured
-            # on the 6.7B step: ~2.7 TiB/step of activation all-gathers
-            # vs ~40 GiB/step of weight gathers with the constraint
-            # (tools/northstar_model.py). Reference semantics:
+            # the conflict by un-sharding the ACTIVATIONS — counted
+            # from the 6.7B step's shapes: ~2.7 TiB/step of activation
+            # all-gathers vs ~40 GiB/step of weight gathers with the
+            # constraint (not checked by any test). Reference semantics:
             # group_sharded_stage3.py:194 forward all-gather hooks.
             self._use_shardings = {n: NamedSharding(self.mesh,
                                                     base_specs[n])

@@ -21,8 +21,8 @@ layer with three primitives shared by every consumer:
   every recovery path is exercisable under JAX_PLATFORMS=cpu.
 
 Import cost contract: this module imports ONLY the stdlib at module
-scope — tools (bench.py's probe parent, the watcher) must be able to
-read the retry schedule without pulling jax.
+scope — a tool that must not hold the chip (a probe's parent, the
+watcher) must be able to read the retry schedule without pulling jax.
 
 Env knobs (documented in COMPONENTS.md "Resilience"):
   PADDLE_TPU_STEP_TIMEOUT     step deadline in seconds (arms Model.fit)

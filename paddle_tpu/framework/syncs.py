@@ -3,8 +3,11 @@
 A device->host materialization (``float(loss)``, a lazy-loss window
 fetch, evaluate's batched loss fetch) is the blocking round-trip the
 fused K-step training loop exists to amortize — so the loop's tools
-need to COUNT them. `tools/bench_train_loop.py` asserts zero mid-window
-syncs through this counter, and tests pin the per-window fetch count.
+need to COUNT them. tests/test_scan_train.py::
+test_train_batch_lazy_and_sync_counter pins the per-window fetch count
+through this counter (a dispatch costs no sync, the read costs one);
+zero mid-window syncs over a whole fused `fit` window is not checked
+by any test.
 
 Deliberately tiny: a process-global counter bumped from
 ``Tensor.__float__`` and ``hapi.lazy.LossWindow.fetch``. A plain int

@@ -496,11 +496,10 @@ class ContinuousBatchingEngine:
         # is snapshotted ONCE so the disabled hot path is a single
         # attribute test per site — no spans, no histogram touches, no
         # allocations per tick (counter-asserted in tests/test_obs.py;
-        # tools/bench_obs_overhead.py pins the enabled cost <= 2%).
+        # the enabled path's cost is not checked by any test).
         # modeled per-chip all-reduce bytes per tick / per verify
         # dispatch (inference/tp.py formula; 0 single-chip) — reported
-        # on the tp_allreduce span, in stats(), and tabulated by
-        # tools/bench_tp_decode.py
+        # on the tp_allreduce span and in stats()
         cfg = getattr(model, "cfg", None)
         if self._tp is not None and cfg is not None:
             self.tp_tick_comm_bytes = self._tp.modeled_tick_comm_bytes(

@@ -13,8 +13,8 @@ replica death:
   ``ContinuousBatchingEngine``, and a ``PredictorServer`` that AOT-warms
   through the shared executable store (``PADDLE_TPU_EXEC_STORE_DIR``):
   once one replica has compiled-and-stored, every successor reaches
-  ready with ZERO XLA compiles (bench_cold_start-proven, asserted again
-  by the rolling-restart test).
+  ready with ZERO XLA compiles (asserted by tests/test_router.py::
+  test_rolling_restart_store_warm_zero_compiles).
 * **Health-aware admission**: a control loop polls every replica's
   ``/healthz`` (slot occupancy, queue depth, warming/draining state).
   ``/generate`` routes to the least-loaded READY replica — never to a
@@ -190,8 +190,7 @@ def single_device_child_env(platform: str = "cpu",
     flag if it leaked into the parent env. tp>1 (ISSUE 20): the replica
     is an N-chip TP slice — give the child EXACTLY tp virtual devices
     instead, so its engine mesh matches the spec. The one scrub shared
-    by tools/serve_tier.py, tools/bench_serving.py --tier, and the
-    tests."""
+    by tools/serve_tier.py, chip_smoke.py and the tests."""
     flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
              if not f.startswith("--xla_force_host_platform_device_count")]
     if tp > 1:

@@ -40,7 +40,7 @@ replicated-output constraint in RowParallelLinear). Under
 ``mp_layers.tp_comm_precision(...)``, routing those reductions through
 the PR 17 EQuARX bodies (quantized wire, f32 accumulate) instead.
 
-Correctness oracle (tests/test_tp_engine.py, tools/bench_tp_decode.py):
+Correctness oracle (tests/test_tp_engine.py):
 greedy token IDs from a tp>1 engine are identical to the single-chip
 engine — slot and paged, f32 and int8 caches, speculative verify
 included — with zero recompiles under prompt-length drift.
@@ -220,8 +220,8 @@ class TPContext:
         per block (attention out-proj + MLP down-proj), priced at the
         ring all-reduce's 2*(tp-1)/tp per-chip wire factor and the
         configured wire precision's bytes/element. The same formula the
-        obs tick span reports and bench_tp_decode tabulates — tpucost's
-        comm_bytes anchor measures the real HLO bytes this models."""
+        obs tick span reports — tpucost's comm_bytes anchor measures the
+        real HLO bytes this models."""
         if self.tp == 1:
             return 0
         wire = {"int8": 1.0 + 4.0 / 256.0,   # int8 payload + f32 block

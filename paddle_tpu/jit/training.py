@@ -584,8 +584,8 @@ class TrainStep:
         only lowered, never executed, and no counter/LR/RNG state
         moves. On a store-warm machine the first `fit` step then
         dispatches a deserialized executable with ZERO XLA compiles
-        (tools/bench_cold_start.py asserts exactly this). Returns the
-        compile-log records."""
+        (tests/test_compilation.py::TestFitWarmStart asserts exactly
+        this). Returns the compile-log records."""
         from ..compilation import log as _clog
         from ..compilation import prime_helper_ops
         from ..compilation.store import AotProgram, aot_compile

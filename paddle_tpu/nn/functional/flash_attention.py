@@ -59,9 +59,9 @@ _last_dispatch = {}
 
 
 def last_attention_dispatch() -> dict:
-    """The most recent flash_attention/sdpa dispatch decision. bench.py
-    records this in its JSON so the driver's perf record proves which
-    kernel actually fired."""
+    """The most recent flash_attention/sdpa dispatch decision. The
+    benchmark's driver and chip_smoke.py read it to prove which kernel
+    actually fired."""
     return dict(_last_dispatch)
 
 

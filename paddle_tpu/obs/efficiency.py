@@ -1,29 +1,24 @@
 """THE efficiency formula: model FLOPs / modeled bytes over measured
 wall time, as a fraction of one chip's peak.
 
-Before this module every surface that wanted an efficiency number
-derived its own — ``tools/northstar_model.py`` analytically,
-``bench.py`` with its own FLOPs-per-token accounting, and the live
-loops not at all. This is the ONE implementation the live gauges and
-the bench records share (ISSUE 14's "no third formula" rule):
+This is the ONE implementation the live gauges share. The benchmark
+counts its cells' operations itself, from their shapes
+(``benchmark/work.py``): ``step_mfu`` there is not this gauge.
 
 * training: ``mfu(train_step_flops(params, tokens), seconds)`` — the
   standard nominal-MFU accounting (6 * params * tokens; remat recompute
   excluded, attention's O(L*H*S) term excluded when layer geometry is
-  unknown — the same convention northstar_model.py documents). hapi's
-  fit loop exports it per dispatch as the ``ptpu_train_mfu`` gauge
-  (plus ``ptpu_train_step_seconds``), and tools/bench_train_loop.py
-  puts the identical arithmetic in its JSON record.
+  unknown). hapi's fit loop exports it per dispatch as the
+  ``ptpu_train_mfu`` gauge (plus ``ptpu_train_step_seconds``).
 * serving: the decode tick is bandwidth-bound (tpucost's anchor), so
   its efficiency is modeled HBM bytes moved per measured second as a
   fraction of the chip's bandwidth — ``model_bandwidth_eff(
   modeled_tick_bytes(kind, geometry), seconds)``. The engine exports
   it per tick as ``ptpu_engine_tick_model_eff`` (surfaced in
-  ``stats()`` / ``/healthz``), and tools/bench_serving.py reports the
-  same gauge's value.
+  ``stats()`` / ``/healthz``).
 
 Numbers are chip-RELATIVE: the default chip is analysis/chips.py's
-``DEFAULT_CHIP`` (v5lite — the measured 33.6%-MFU anchor's chip),
+``DEFAULT_CHIP`` (v5lite, the chip the benchmark's cells run on),
 overridable via ``PADDLE_TPU_EFF_CHIP``. On a CPU backend the gauges
 still move (the arithmetic is honest) but read as tiny fractions of a
 TPU's peak — they become meaningful when the TPU suite runs.
@@ -67,9 +62,10 @@ def train_step_flops(param_count: int, tokens: int) -> float:
     """Nominal model FLOPs for training ``tokens`` tokens: the standard
     6 * N * T (fwd 2NT + bwd 4NT) MFU accounting. Remat recompute is
     deliberately EXCLUDED (standard MFU counts useful math, not
-    re-execution) and so is the attention O(L*H*S^2) term — callers
-    that know their layer geometry (bench.py's 125M/1.3B configs) add
-    it themselves; the live gauge stays the comparable lower bound."""
+    re-execution) and so is the attention O(L*H*S^2) term — a caller
+    that knows its layer geometry adds it itself (the benchmark's
+    count, benchmark/work.py, does); the live gauge stays the
+    comparable lower bound."""
     return 6.0 * float(param_count) * float(tokens)
 
 

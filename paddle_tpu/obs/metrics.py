@@ -30,8 +30,7 @@ The text format is the Prometheus exposition subset the in-repo parser
 (``parse_text``) understands: ``# TYPE`` comments, ``name{l="v"} value``
 samples, ``_bucket``/``_sum``/``_count`` histogram triads with
 cumulative ``le`` buckets. Percentiles are estimated from the buckets
-by linear interpolation (``percentile_from_cum``) — what
-tools/bench_serving.py reports as phase percentiles.
+by linear interpolation (``percentile_from_cum``).
 """
 from __future__ import annotations
 

@@ -39,8 +39,9 @@ import pytest  # noqa: E402
 def pytest_addoption(parser):
     parser.addoption(
         "--runslow", action="store_true", default=False,
-        help="include tests marked slow (north-star AOT compiles, "
-             "benchmark smokes) — tools/ci.py --full sets this")
+        help="include tests marked slow (whole-step compiles for the "
+             "described chip, chip_smoke.py rehearsals) — tools/ci.py "
+             "--full sets this")
 
 
 def pytest_collection_modifyitems(config, items):

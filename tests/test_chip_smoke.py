@@ -10,6 +10,7 @@ rest with ``--runslow`` before spending chip time on chip_smoke.py.
 import importlib.util
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -28,15 +29,55 @@ def test_chip_smoke_fails_without_a_chip():
     assert "need 'tpu'" in r.stdout + r.stderr
 
 
-def test_bench_and_smoke_cut_gpt1p3b_to_the_same_depth():
-    import re
-    depth = {}
-    for name, var in (("bench.py", "GPT1P3B_LAYERS_ONE_CHIP"),
-                      ("chip_smoke.py", "GPT1P3B_LAYERS")):
-        with open(os.path.join(ROOT, name)) as f:
-            depth[name] = int(re.search(rf"^{var} = (\d+)$", f.read(),
-                                        re.M).group(1))
-    assert depth["bench.py"] == depth["chip_smoke.py"] < 24
+def _python_files_outside_the_benchmark():
+    """Every *.py git would commit outside benchmark/: the tree walked
+    without hidden directories and without what .gitignore lists (the
+    driver's checkout need not be a git repository)."""
+    import fnmatch
+    with open(os.path.join(ROOT, ".gitignore")) as f:
+        ignored = [ln.strip().rstrip("/") for ln in f
+                   if ln.strip() and not ln.startswith("#")]
+    for here, dirs, files in os.walk(ROOT):
+        dirs[:] = [d for d in dirs if not d.startswith(".")
+                   and not any(fnmatch.fnmatch(d, pat) for pat in ignored)
+                   and (here, d) != (ROOT, "benchmark")]
+        for name in files:
+            if name.endswith(".py"):
+                yield os.path.relpath(os.path.join(here, name), ROOT)
+
+
+def test_one_yardstick():
+    """Speed is read from one place, benchmark/run.py over BENCHMARK.json:
+    nothing outside benchmark/ keeps a timing script's knobs, chip_smoke.py
+    retypes no cell (no train phase, no GPT depth of its own), and the
+    README names only files that exist."""
+    knob = "PADDLE_TPU_" + "BENCH_"
+    readers = []
+    for path in _python_files_outside_the_benchmark():
+        with open(os.path.join(ROOT, path)) as f:
+            if knob in f.read():
+                readers.append(path)
+    assert readers == []
+
+    with open(SMOKE) as f:
+        smoke = f.read()
+    assert not re.search(r"phase_train|\btrain_\w+|TRAIN_", smoke)
+    assert not re.search(r"^GPT\w*LAYERS\w* = ", smoke, re.M)
+
+    # every word in backticks that looks like a file of this repo; the
+    # component map names modules from inside the package, and what
+    # starts with a dot is made at run time
+    with open(os.path.join(ROOT, "README.md")) as f:
+        words = {w for span in re.findall(r"`([^`\n]+)`", f.read())
+                 for w in span.split()}
+    paths = {w for w in words if not w.startswith(".")
+             and re.fullmatch(r"[\w./-]+(\.py|\.md|\.jsonl?|/)", w)}
+    assert {"benchmark/run.py", "BENCHMARK.json", "PERF_LEDGER.jsonl",
+            "chip_smoke.py"} <= paths
+    missing = sorted(p for p in paths if not any(
+        os.path.exists(os.path.join(base, p))
+        for base in (ROOT, os.path.join(ROOT, "paddle_tpu"))))
+    assert missing == []
 
 
 @pytest.fixture
@@ -62,16 +103,6 @@ def test_rehearse_kernels(smoke, monkeypatch):
         flash_gqa=((1, 128, 4, 2, 64, 48), (1, 128, 4, 1, 64, None)),
         cache=(3, 32, 2, 128), pool=(8, 4, 2, 128), ce=(48, 640),
         mega=((3, 32, 2, 128),)))
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("extra,mp", [
-    (dict(scan_layers=True), True),
-    (dict(scan_layers=True, recompute=True, fused_loss_chunk=64), False)])
-def test_rehearse_train(smoke, extra, mp):
-    smoke.phase_train(dict(name="tiny", batch=2, seq=128,
-                           multi_precision=mp,
-                           cfg=dict(TINY_GPT, **extra)))
 
 
 @pytest.mark.slow

@@ -380,8 +380,7 @@ def test_mesh_gauge_and_mixed_tp_tier_summing():
 
 def test_tp_allreduce_span_recorded():
     """Every sharded tick records an engine.tp_allreduce span carrying
-    the modeled per-chip wire bytes (the number tpucost anchors and
-    bench_tp_decode tabulates)."""
+    the modeled per-chip wire bytes (the number tpucost anchors)."""
     from paddle_tpu import obs as _obs
     with _engine(tp=2) as eng:
         eng.generate(PROMPTS[0], max_new_tokens=6, timeout=300)

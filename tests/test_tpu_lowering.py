@@ -13,10 +13,11 @@ a second file could land on another xdist worker and skip in silence):
   this depth and were all refused by the compiler).
 - ``.lower(...).compile()`` against a DESCRIBED v5e (``topo`` fixture):
   what the chip's compiler would say, at the real widths — every kernel
-  in paddle_tpu/kernels/, the library splash call at both bench
+  in paddle_tpu/kernels/, the library splash call at both GPT cells'
   geometries, the engine's decode ticks at 1.3B widths, and (marked
-  slow) the whole GPT-125M and GPT-1.3B train steps bench.py and
-  chip_smoke.py run. A compile that passes is not a chip run.
+  slow) the whole train steps of the benchmark's two GPT cells, their
+  sizes and job read from BENCHMARK.json's files. A compile that passes
+  is not a chip run.
 """
 import functools
 import re
@@ -30,6 +31,34 @@ from jax.sharding import SingleDeviceSharding
 
 import paddle_tpu.distributed as dist
 from paddle_tpu.kernels.flash_block import flash_block_attention
+
+
+def _cell(workload):
+    """One GPT cell of the benchmark, read only, by the benchmark's own
+    lookup: the GPTConfig arguments as benchmark/drivers/train_steps.py
+    passes them, the job, and the step's batch and sequence — the one
+    copy of the cells' sizes."""
+    from benchmark.run import find_cell, load_json
+    cell = find_cell(load_json("BENCHMARK.json"), workload)
+    arch, traffic = cell["config"], cell["traffic"]
+    job = arch["job"]
+    cfg_kw = dict(
+        {k: arch[k] for k in ("vocab_size", "hidden_size", "num_layers",
+                              "num_heads", "max_seq_len", "ffn_mult",
+                              "initializer_range")},
+        dropout=0.0, tie_embeddings=True,
+        **{k: job[k] for k in ("recompute", "recompute_policy",
+                               "scan_layers", "fused_loss_chunk")})
+    return cfg_kw, job, traffic["batch"], traffic["seq"]
+
+
+def _cell_optimizer(paddle, job, model):
+    return paddle.optimizer.AdamW(
+        learning_rate=job["learning_rate"], beta1=job["beta1"],
+        beta2=job["beta2"], epsilon=job["epsilon"],
+        weight_decay=job["weight_decay"],
+        multi_precision=job["master_weights"],
+        parameters=model.parameters())
 
 
 @pytest.fixture(autouse=True)
@@ -126,7 +155,7 @@ def test_fused_ring_lowers_for_tpu():
 
 def _export_train_step_for_tpu(step, batch=(2, 256)):
     """Cross-lower a built TrainStep's whole donated program for the TPU
-    target (the one export recipe both bench-shaped gates share)."""
+    target (the one export recipe both cell-shaped gates share)."""
     import paddle_tpu.framework.random as _rng
     step._build()
     aval = lambda t: jax.tree_util.tree_map(
@@ -140,10 +169,10 @@ def _export_train_step_for_tpu(step, batch=(2, 256)):
 
 
 def test_gpt_train_step_with_pallas_attention_lowers_for_tpu(monkeypatch):
-    """The exact bench path: full donated GPT train step with the library
-    splash attention (dispatch forced as on a real TPU backend),
-    cross-lowered for the TPU target — the forward and the fused backward
-    Mosaic payloads."""
+    """train-gpt-125m's job at tiny geometry: full donated GPT train step
+    with the library splash attention (dispatch forced as on a real TPU
+    backend), cross-lowered for the TPU target — the forward and the
+    fused backward Mosaic payloads."""
     import importlib
     import paddle_tpu as paddle
     from paddle_tpu.jit import TrainStep
@@ -151,14 +180,15 @@ def test_gpt_train_step_with_pallas_attention_lowers_for_tpu(monkeypatch):
     fa = importlib.import_module("paddle_tpu.nn.functional.flash_attention")
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
 
-    cfg = GPTConfig(vocab_size=512, hidden_size=256, num_layers=2,
-                    num_heads=4, max_seq_len=256)
+    cell_kw, job, _, _ = _cell("train-gpt-125m")
+    cfg = GPTConfig(**dict(cell_kw, vocab_size=512, hidden_size=256,
+                           num_layers=2, num_heads=4, max_seq_len=256,
+                           fused_loss_chunk=64))
     paddle.seed(0)
     model = GPTForCausalLM(cfg)
     model.bfloat16()
-    opt = paddle.optimizer.AdamW(learning_rate=1e-4, multi_precision=True,
-                                 parameters=model.parameters())
-    step = TrainStep(model, GPTForCausalLM.loss_fn, opt)
+    step = TrainStep(model, model.make_loss_fn(),
+                     _cell_optimizer(paddle, job, model))
     exp = _export_train_step_for_tpu(step)
     assert exp.mlir_module().count("tpu_custom_call") == 2
     assert fa.last_attention_dispatch()["backend"] == "pallas"
@@ -166,12 +196,11 @@ def test_gpt_train_step_with_pallas_attention_lowers_for_tpu(monkeypatch):
 
 @pytest.mark.parametrize("policy", ["full", "dots"])
 def test_gpt_1p3b_shaped_step_lowers_for_tpu(monkeypatch, policy):
-    """The exact gpt1.3b bench composition (bench.py PADDLE_TPU_BENCH_
-    MODEL=gpt1.3b) at tiny geometry: scan-over-layers + per-block remat
-    (both recompute_policy values) + fused linear-CE + pure-bf16 Adam,
-    with pallas attention dispatch forced — cross-lowered for the TPU
-    target so a Mosaic/lowering blocker is caught HERE, not on the
-    chip's clock."""
+    """train-gpt-1.3b's job at tiny geometry: scan-over-layers +
+    per-block remat (both recompute_policy values) + chunked head/loss +
+    pure-bf16 Adam, with pallas attention dispatch forced — cross-lowered
+    for the TPU target so a Mosaic/lowering blocker is caught HERE, not
+    on the chip's clock."""
     import importlib
     import paddle_tpu as paddle
     from paddle_tpu.jit import TrainStep
@@ -179,17 +208,17 @@ def test_gpt_1p3b_shaped_step_lowers_for_tpu(monkeypatch, policy):
     fa = importlib.import_module("paddle_tpu.nn.functional.flash_attention")
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
 
-    cfg = GPTConfig(vocab_size=512, hidden_size=256, num_layers=3,
-                    num_heads=4, max_seq_len=256, scan_layers=True,
-                    recompute=True, recompute_policy=policy,
-                    fused_loss_chunk=64)
+    cell_kw, job, _, _ = _cell("train-gpt-1.3b")
+    assert cell_kw["scan_layers"] and cell_kw["recompute"]
+    assert not job["master_weights"]
+    cfg = GPTConfig(**dict(cell_kw, vocab_size=512, hidden_size=256,
+                           num_layers=3, num_heads=4, max_seq_len=256,
+                           recompute_policy=policy, fused_loss_chunk=64))
     paddle.seed(0)
     model = GPTForCausalLM(cfg)
     model.bfloat16()
-    opt = paddle.optimizer.AdamW(learning_rate=1e-4,
-                                 multi_precision=False,  # 1.3b bench mode
-                                 parameters=model.parameters())
-    step = TrainStep(model, model.make_loss_fn(), opt)
+    step = TrainStep(model, model.make_loss_fn(),
+                     _cell_optimizer(paddle, job, model))
     exp = _export_train_step_for_tpu(step)
     # scan body compiles ONCE (depth-independent): fwd + fused bwd, plus
     # the remat'd bwd replaying the fwd kernel = 3 Mosaic payloads
@@ -356,12 +385,13 @@ def test_compiles_for_v5e(case, one_chip, chip_like_config):
     assert compiled.as_text().count("tpu_custom_call") >= n_kernels
 
 
-# -- whole train steps (slow: the unrolled GPT-125M compile alone takes
-# ~5 min on 8 cores; tier-1 already runs out of its clock) ------------------
+# -- whole train steps (slow: minutes of compile each, and tier-1 has a
+# clock) ---------------------------------------------------------------------
 
-def _compile_train_step_for_v5e(cfg_kw, batch, seq, multi_precision,
-                                one_chip, monkeypatch):
-    """bench.py's / chip_smoke.py's exact composition, compiled for the
+def _compile_train_step_for_v5e(workload, one_chip, monkeypatch,
+                                **cfg_override):
+    """The cell's own step program (benchmark/drivers/train_steps.py's
+    composition, sizes and job from the cell's files), compiled for the
     described chip; returns (compiled, per-device memory analysis)."""
     import importlib
     import paddle_tpu as paddle
@@ -370,13 +400,13 @@ def _compile_train_step_for_v5e(cfg_kw, batch, seq, multi_precision,
     from paddle_tpu.models import GPTConfig, GPTForCausalLM
     fa = importlib.import_module("paddle_tpu.nn.functional.flash_attention")
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    cfg_kw, job, batch, seq = _cell(workload)
+    assert job["attention_backend"] == "pallas"
     paddle.seed(0)
-    model = GPTForCausalLM(GPTConfig(**cfg_kw))
+    model = GPTForCausalLM(GPTConfig(**dict(cfg_kw, **cfg_override)))
     model.bfloat16()
-    opt = paddle.optimizer.AdamW(learning_rate=1e-4,
-                                 multi_precision=multi_precision,
-                                 parameters=model.parameters())
-    step = TrainStep(model, model.make_loss_fn(), opt)
+    step = TrainStep(model, model.make_loss_fn(),
+                     _cell_optimizer(paddle, job, model))
     step._build()
     aval = functools.partial(jax.tree_util.tree_map, lambda x:
                              jax.ShapeDtypeStruct(x.shape, x.dtype,
@@ -394,16 +424,12 @@ def _compile_train_step_for_v5e(cfg_kw, batch, seq, multi_precision,
 
 @pytest.mark.slow
 @pytest.mark.timeout(1800)
-@pytest.mark.parametrize("scan", [True, False])
-def test_gpt125m_train_step_compiles_for_v5e(scan, one_chip,
-                                             chip_like_config,
+def test_gpt125m_train_step_compiles_for_v5e(one_chip, chip_like_config,
                                              monkeypatch):
-    """GPT-125M, batch 8 x seq 1024, f32 master weights: scanned as
-    chip_smoke.py runs it, unrolled as bench.py does (the slow one)."""
+    """train-gpt-125m's step: unrolled, f32 master weights, chunked
+    head/loss."""
     compiled, mem = _compile_train_step_for_v5e(
-        dict(vocab_size=50304, hidden_size=768, num_layers=12,
-             num_heads=12, max_seq_len=1024, scan_layers=scan),
-        8, 1024, True, one_chip, monkeypatch)
+        "train-gpt-125m", one_chip, monkeypatch)
     assert compiled.as_text().count("tpu_custom_call") >= 2
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
@@ -412,23 +438,12 @@ def test_gpt125m_train_step_compiles_for_v5e(scan, one_chip,
 @pytest.mark.timeout(1800)
 def test_gpt1p3b_train_step_compiles_for_v5e(one_chip, chip_like_config,
                                              monkeypatch):
-    """GPT-1.3B widths at bench.py's one-chip depth: batch 4 x seq 2048,
-    scan_layers, per-block remat, fused_loss_chunk 2048, bf16 weights
-    without master. The published 24 layers are refused for memory
-    (16.23 G of 15.75 G); the cut depth must fit."""
-    import importlib.util
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
+    """train-gpt-1.3b's step: scanned, per-block remat, chunked
+    head/loss, bf16 weights without master. The published 24 layers are
+    refused for memory (16.23 G of 15.75 G); the cell's cut depth must
+    fit."""
     compiled, mem = _compile_train_step_for_v5e(
-        dict(vocab_size=50304, hidden_size=2048,
-             num_layers=bench.GPT1P3B_LAYERS_ONE_CHIP, num_heads=16,
-             max_seq_len=2048, recompute=True, scan_layers=True,
-             fused_loss_chunk=2048),
-        4, 2048, False, one_chip, monkeypatch)
+        "train-gpt-1.3b", one_chip, monkeypatch)
     assert compiled.as_text().count("tpu_custom_call") >= 3
     print("GPT-1.3B one-chip step:", mem)
 
@@ -443,10 +458,7 @@ def test_gpt1p3b_width_step_carries_its_scopes_for_v5e(one_chip,
     backward), so a device trace's operations can be summed by them."""
     from paddle_tpu.analysis import runtime_profile as rp
     compiled, _ = _compile_train_step_for_v5e(
-        dict(vocab_size=50304, hidden_size=2048, num_layers=2,
-             num_heads=16, max_seq_len=2048, recompute=True,
-             scan_layers=True, fused_loss_chunk=2048),
-        4, 2048, False, one_chip, monkeypatch)
+        "train-gpt-1.3b", one_chip, monkeypatch, num_layers=2)
     text = compiled.as_text()
     table = rp.hlo_op_scopes(text)
     kernels = [rp.read_scope(table[n], n) for n in re.findall(

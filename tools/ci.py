@@ -141,69 +141,6 @@ def _run_elastic_smoke(env) -> int:
         cwd=ROOT, env=env).returncode
 
 
-def _run_recovery_smoke(env) -> int:
-    """Recovery smoke (ISSUE 15): tools/bench_serving.py --recovery
-    --smoke drives a live 2-replica tier through kill-mid-decode
-    (journaled failover: every client 200 with bitwise-identical
-    tokens, prefix-hit re-prefill, zero new compiles, recovery
-    counters + flight artifact) and an injected replica_stall
-    (hedged decode bounds p99, the loser is cancelled, allocator ends
-    leak-free)."""
-    print("\n=== recovery smoke (kill-mid-decode + stall-hedge) ===")
-    return subprocess.run(
-        [sys.executable, os.path.join("tools", "bench_serving.py"),
-         "--recovery", "--smoke"],
-        cwd=ROOT, env=env).returncode
-
-
-def _run_stream_smoke(env) -> int:
-    """Streaming QoS smoke (ISSUE 16): tools/bench_serving.py --stream
-    --smoke drives NDJSON client streams through a live 2-replica tier
-    across kill -9, an injected decode stall (hedge-bounded), and a
-    rolling restart — every stream must splice bitwise-identically to
-    the undisturbed oracle (zero token loss, zero duplicates, zero new
-    compiles) — then saturates a tiny QoS capacity with mixed
-    tenant/class traffic (interactive all served, batch shed with
-    truthful Retry-After, nobody starved) and A/Bs prefix-affinity
-    routing against load-only _pick (hit rate must be higher)."""
-    print("\n=== stream smoke (mid-stream chaos + QoS + affinity) ===")
-    return subprocess.run(
-        [sys.executable, os.path.join("tools", "bench_serving.py"),
-         "--stream", "--smoke"],
-        cwd=ROOT, env=env).returncode
-
-
-def _run_comm_smoke(env) -> int:
-    """Comm smoke (ISSUE 17): tools/bench_collectives.py --smoke A/Bs
-    the SAME GPT-tiny ParallelTrainStep (ZeRO-2 + ZeRO-3) at
-    comm_precision fp32/bf16/int8 on an 8-virtual-device dp2 x
-    sharding4 mesh — gating the per-chip collective-byte reduction
-    (>=1.8x bf16 / >=3.5x int8), the loss drift bounds vs fp32, and
-    the stage-3 gather chain + interleaved schedule. The tool re-execs
-    itself onto the virtual mesh and strips the persistent compile
-    cache (multi-device reload hazard + fresh-compile wall times)."""
-    print("\n=== comm smoke (quantized ZeRO collectives A/B) ===")
-    return subprocess.run(
-        [sys.executable, os.path.join("tools", "bench_collectives.py"),
-         "--smoke"],
-        cwd=ROOT, env=env).returncode
-
-
-def _run_tp_smoke(env) -> int:
-    """TP smoke (ISSUE 20): tools/bench_tp_decode.py --smoke decodes
-    the same greedy workload on a tp=1 and a tp=2 engine slice over
-    the virtual mesh — gating bitwise token identity, the
-    zero-recompile contract under prompt-length drift, and the
-    per-chip sharded-footprint fraction. The tool re-execs itself
-    onto the virtual mesh and strips the persistent executable store
-    (multi-device serialization is best-effort on CPU)."""
-    print("\n=== tp smoke (tensor-parallel decode A/B) ===")
-    return subprocess.run(
-        [sys.executable, os.path.join("tools", "bench_tp_decode.py"),
-         "--smoke"],
-        cwd=ROOT, env=env).returncode
-
-
 def _run_obs_smoke(env) -> int:
     """Obs smoke (ISSUE 8): tools/trace_tool.py --self-test drives a
     LIVE tiny server — /metrics scraped twice and parsed (series must
@@ -215,20 +152,6 @@ def _run_obs_smoke(env) -> int:
     return subprocess.run(
         [sys.executable, os.path.join("tools", "trace_tool.py"),
          "--self-test"],
-        cwd=ROOT, env=env).returncode
-
-
-def _run_fusion_smoke(env) -> int:
-    """Fusion smoke (ISSUE 19): tools/bench_fusion.py --smoke A/Bs the
-    PADDLE_TPU_FUSED_CACHE_WRITE / _MEGA_DECODE / _FUSED_CE knobs
-    through the real dispatch — modeled decode-tick HBM drop >= 20%,
-    fused-CE kernel removal at no byte cost, live-engine greedy token
-    identity across knob states with ZERO new traces or compiles after
-    warmup, and bounded CE value+grad drift."""
-    print("\n=== fusion smoke (fused-kernel A/B + identity) ===")
-    return subprocess.run(
-        [sys.executable, os.path.join("tools", "bench_fusion.py"),
-         "--smoke"],
         cwd=ROOT, env=env).returncode
 
 
@@ -350,8 +273,9 @@ def main():
     ap.add_argument("--coverage", action="store_true")
     ap.add_argument("--retries", type=int, default=0)
     ap.add_argument("--full", action="store_true",
-                    help="include tests marked slow (north-star AOT "
-                         "compiles, benchmark smokes); the default fast "
+                    help="include tests marked slow (whole-step "
+                         "compiles for the described chip, chip_smoke.py "
+                         "rehearsals); the default fast "
                          "profile skips them — this machine has ONE cpu "
                          "core, so wall time is cut by cutting work, not "
                          "by sharding")
@@ -404,36 +328,6 @@ def main():
                     help="skip the topology-elastic chaos smoke "
                          "(tools/chaos_train.py --elastic) that "
                          "--quick/--full append after the tests")
-    ap.add_argument("--no-recovery-smoke", action="store_true",
-                    help="skip the serving recovery smoke "
-                         "(tools/bench_serving.py --recovery --smoke: "
-                         "kill-mid-decode + stall-hedge) that "
-                         "--quick/--full append after the tests")
-    ap.add_argument("--no-stream-smoke", action="store_true",
-                    help="skip the streaming QoS smoke "
-                         "(tools/bench_serving.py --stream --smoke: "
-                         "mid-stream chaos + per-class degradation + "
-                         "affinity A/B) that --quick/--full append "
-                         "after the tests")
-    ap.add_argument("--no-fusion-smoke", action="store_true",
-                    help="skip the fused-kernel smoke "
-                         "(tools/bench_fusion.py --smoke: modeled HBM "
-                         "drop + engine token identity + zero-"
-                         "recompile knob flips) that --quick/--full "
-                         "append after the tests")
-    ap.add_argument("--no-comm-smoke", action="store_true",
-                    help="skip the quantized-collectives smoke "
-                         "(tools/bench_collectives.py --smoke: "
-                         "fp32/bf16/int8 byte + drift + overlap gates "
-                         "on the 8-virtual-device mesh) that "
-                         "--quick/--full append after the tests")
-    ap.add_argument("--no-tp-smoke", action="store_true",
-                    help="skip the tensor-parallel decode smoke "
-                         "(tools/bench_tp_decode.py --smoke: tp=1 vs "
-                         "tp=2 token identity + zero-recompile + "
-                         "per-chip footprint gates on the virtual "
-                         "mesh) that --quick/--full append after the "
-                         "tests")
     ap.add_argument("-k", default=None)
     args = ap.parse_args()
     if args.full and args.quick:
@@ -550,29 +444,6 @@ def main():
         # cache itself, but don't even offer it the multi-device trap
         elastic_rc = _run_elastic_smoke(env)
         rc = rc or elastic_rc
-    if (args.quick or args.full) and not args.no_recovery_smoke:
-        # cache_env: replica children warm through the shared store +
-        # single-device jax cache (no multi-device entries can arise)
-        recovery_rc = _run_recovery_smoke(cache_env)
-        rc = rc or recovery_rc
-    if (args.quick or args.full) and not args.no_stream_smoke:
-        # cache_env for the same reason as the recovery smoke
-        stream_rc = _run_stream_smoke(cache_env)
-        rc = rc or stream_rc
-    if (args.quick or args.full) and not args.no_fusion_smoke:
-        # cache_env: single-device registry programs, safe to share
-        fusion_rc = _run_fusion_smoke(cache_env)
-        rc = rc or fusion_rc
-    if (args.quick or args.full) and not args.no_comm_smoke:
-        # plain env: the tool strips the persistent cache itself
-        # (multi-device reload hazard + fresh-compile wall times)
-        comm_rc = _run_comm_smoke(env)
-        rc = rc or comm_rc
-    if (args.quick or args.full) and not args.no_tp_smoke:
-        # plain env: the tool drops the executable store itself
-        # (multi-device serialization is best-effort on CPU)
-        tp_rc = _run_tp_smoke(env)
-        rc = rc or tp_rc
     return rc
 
 

@@ -19,8 +19,9 @@ kind of numerics gate.
 
 Run at 125M geometry:  python tools/kv_cache_quality.py
 CPU smoke:             JAX_PLATFORMS=cpu python tools/kv_cache_quality.py --smoke
-Decode throughput per cache dtype is bench_serving.py's job (hardware);
-this tool is the quality half of the table.
+Decode throughput per cache dtype is for a serving cell of the
+benchmark to measure (none yet); this tool is the quality half of the
+table.
 """
 import argparse
 import json

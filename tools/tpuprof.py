@@ -15,7 +15,7 @@ a time-weighted fusion-class histogram, measured-vs-modeled roofline
 ratios per kernel, and PR 6's unfused chains re-ranked by measured
 seconds. On a CPU backend the trace has no device plane, so the report
 degrades to wall-time-per-dispatch with the join marked unavailable
-(the profile_step smoke contract).
+(tests/test_runtime_profile.py holds it to that).
 
 Usage:
     python tools/tpuprof.py                      # full run + gate
