@@ -241,6 +241,7 @@ def phase_kernels(sizes=KERNEL_SIZES) -> None:
         e_b = max(_nerr(a, r) for a, r in zip(g, rg))
         say("kernels", kernel="F.flash_attention", shape=(b, s, h, d),
             backend=backend, library_kernel=dispatch.get("kernel"),
+            layout=dispatch.get("layout"),
             blocks=dispatch.get("blocks"), err_fwd=e_f, err_bwd=e_b,
             tol=(TOL_FWD, TOL_BWD), compile_and_run_s=round(secs, 1))
         check(backend == ("pallas" if PLATFORM == "tpu" else "xla"),

@@ -35,7 +35,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..autograd.tape import no_grad
 from ..core.tensor import Tensor
 from ..framework import random as _rng
-from ..jit.functional import functional_call, load_state, raw_state, _wrap
+from ..jit.functional import (EXPORT_DISABLED_CHECKS, functional_call,
+                              load_state, raw_state, _wrap)
 from ..jit.training import (TrainStep, _raw_tuple, op_scopes_of,
                             remember_trace)
 from ..obs.trace import span as _span
@@ -792,8 +793,9 @@ class ParallelTrainStep:
         args = (self.params, self.buffers, self.opt_state, scalar, scalar,
                 key) + raw_batch
         if platform is not None:
-            return jax.export.export(self._jitted, platforms=[platform])(
-                *args)
+            return jax.export.export(
+                self._jitted, platforms=[platform],
+                disabled_checks=EXPORT_DISABLED_CHECKS)(*args)
         lowered = self._jitted.lower(*args)
         return lowered.compile()
 

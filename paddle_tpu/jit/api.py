@@ -28,7 +28,8 @@ import numpy as np
 
 from ..autograd import tape as _tape
 from ..core.tensor import Tensor
-from .functional import functional_call, raw_state, _wrap
+from .functional import (EXPORT_DISABLED_CHECKS, functional_call, raw_state,
+                         _wrap)
 
 __all__ = ["to_static", "not_to_static", "ignore_module", "InputSpec",
            "save", "load", "TranslatedLayer"]
@@ -380,7 +381,7 @@ def save(layer, path, input_spec=None, platforms=None, **config):
 
         def _export(plats):
             return jax.export.export(
-                jax.jit(infer),
+                jax.jit(infer), disabled_checks=EXPORT_DISABLED_CHECKS,
                 **({"platforms": plats} if plats else {}),
             )(merged, *examples)
 

@@ -24,6 +24,16 @@ from ..autograd.tape import no_grad
 from ..core.tensor import Tensor
 
 
+# jax.export refuses a custom call whose target carries no compatibility
+# guarantee. The one this package's own layers emit is an identity that
+# pins a layout and computes nothing
+# (jax.experimental.layout.with_layout_constraint, models/gpt.py), so
+# every export of a traced program (jit.save, ParallelTrainStep's
+# aot_compile) admits that target and no other
+EXPORT_DISABLED_CHECKS = (
+    jax.export.DisabledSafetyCheck.custom_call("LayoutConstraint"),)
+
+
 def raw_state(layer) -> Tuple[Dict[str, Any], Dict[str, Any]]:
     """Flatten a Layer's parameters and persistable+non-persistable buffers
     into two name->jax.Array dicts (pytrees)."""

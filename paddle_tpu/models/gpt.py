@@ -24,17 +24,19 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional
 
-import jax.numpy as jnp
-
 import jax
+import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from .. import tensor as T
+from ..autograd.tape import apply
 from ..core.tensor import Tensor
 from ..jit.functional import functional_call
 from ..distributed import mesh as mesh_mod
 from ..distributed.meta_parallel import (ColumnParallelLinear, LayerDesc,
                                          PipelineLayer, RowParallelLinear,
                                          VocabParallelEmbedding)
+from ..distributed.meta_parallel.mp_layers import _constrain
 from ..distributed.sequence_parallel import ring_attention
 from ..nn import functional as F
 from ..nn import initializer as I
@@ -102,12 +104,29 @@ def _sp_active() -> bool:
 
 # re-export: incremental-decode attention now lives beside the flash
 # kernel (generic serving infrastructure, not GPT-specific)
-from ..nn.functional.flash_attention import cached_attention  # noqa: E402
+from ..nn.functional.flash_attention import (  # noqa: E402
+    cached_attention, head_major_attention)
 from .generation import new_kv_caches as _new_cache  # noqa: E402
 
 
+def _split3(t):
+    """The q, k and v thirds of a fused last axis [..., 3H], laid out
+    [q(H); k(H); v(H)]: of ``qkv.weight`` [H, 3H], ``qkv.bias`` [3H] and
+    the fused product [B, S, 3H] alike."""
+    H = t.shape[-1] // 3
+    return t[..., :H], t[..., H:2 * H], t[..., 2 * H:]
+
+
 class GPTAttention(Layer):
-    """Causal self-attention, TP-sharded heads, sp-aware dispatch."""
+    """Causal self-attention, TP-sharded heads, sp-aware dispatch.
+
+    Training (no cache, no real "sp" axis): the projections write and read
+    the attention kernel's own [B, nh, S, hd] layout, so nothing but the
+    matmuls and the kernel touches q, k, v, the context or their
+    cotangents (``_qkv_heads``, ``_out_heads``). Serving and ring
+    attention keep [B, S, nh, hd] rows from the one fused product
+    (``_qkv``). Both read the same ``qkv.weight`` [H, 3H], ``qkv.bias``
+    [3H] and ``out_proj.weight`` [H, H]."""
 
     def __init__(self, cfg: GPTConfig):
         super().__init__()
@@ -125,32 +144,70 @@ class GPTAttention(Layer):
 
     def _qkv(self, x):
         B, S, _ = x.shape
-        qkv = self.qkv(x)                       # [B, S, 3H] (mp-sharded)
-        # contiguous last-dim slices + free reshapes (the 5-D
-        # reshape-then-slice forced real relayout copies, ~5ms/step on the
-        # 125M bench); values identical: [3H] is laid out [q(H);k(H);v(H)]
-        hd, nh = self.head_dim, self.num_heads
-        H = qkv.shape[-1] // 3
-        q = T.reshape(T.slice(qkv, [2], [0], [H]), [B, S, nh, hd])
-        k = T.reshape(T.slice(qkv, [2], [H], [2 * H]), [B, S, nh, hd])
-        v = T.reshape(T.slice(qkv, [2], [2 * H], [3 * H]), [B, S, nh, hd])
-        return q, k, v
+        nh, hd = self.num_heads, self.head_dim
+
+        def rows(qkv):                          # [B, S, 3H] (mp-sharded)
+            return tuple(t.reshape(B, S, nh, hd) for t in _split3(qkv))
+
+        return apply(rows, self.qkv(x), _op_name="split_qkv")
+
+    def _qkv_heads(self, x):
+        """q, k, v as [B, nh, S, hd]: each a product of x with its third
+        of ``qkv.weight`` viewed [H, nh, hd], the bias and (q's) the
+        softmax scale put on the f32 accumulator before the one rounding.
+        Column-parallel as ``qkv`` itself: heads sharded over "mp"."""
+        nh, hd = self.num_heads, self.head_dim
+
+        def project(x, w, b):
+            acc_t = jnp.promote_types(x.dtype, jnp.float32)
+            out = []
+            # a product that writes [b, h, s, d] wants its weight with the
+            # contracted dimension minor. Left alone, the compiler gives that
+            # layout to the whole stacked [L, H, 3H] operand of a scanned
+            # stack and keeps a second copy of it through the step (0.53 GiB
+            # more at the peak of train-gpt-1.3b, and slower: PERF.md section
+            # 6, PR 33); pinned as stored, a layer's thirds are transposed
+            # where they are used
+            w = with_layout_constraint(w, Layout(major_to_minor=(0, 1)))
+            for wi, bi, scale in zip(_split3(w), _split3(b),
+                                     (hd ** -0.5, 1.0, 1.0)):
+                acc = jnp.einsum("bsk,khd->bhsd", x, wi.reshape(-1, nh, hd),
+                                 preferred_element_type=acc_t)
+                acc = (acc + bi.reshape(nh, 1, hd).astype(acc_t)) * scale
+                out.append(acc.astype(x.dtype))
+            return tuple(out)
+
+        with jax.named_scope(self.qkv._scope_name):
+            qkv = apply(project, x, self.qkv.weight, self.qkv.bias,
+                        _op_name="linear")
+            return tuple(_constrain(t, None, "mp", None, None) for t in qkv)
+
+    def _out_heads(self, ctx):
+        """``out_proj`` of a context [B, nh, S, hd]: one contraction over
+        (nh, hd) with its weight viewed [nh, hd, H]. Row-parallel as
+        ``out_proj`` itself: partial products reduced over "mp" (exactly:
+        the quantized wire is the serving programs'), bias added once,
+        after."""
+        nh, hd = self.num_heads, self.head_dim
+        with jax.named_scope(self.out_proj._scope_name):
+            y = apply(lambda c, w: jnp.einsum("bhsd,hdk->bsk", c,
+                                              w.reshape(nh, hd, -1)),
+                      ctx, self.out_proj.weight, _op_name="linear")
+            return _constrain(y, None, None, None) + self.out_proj.bias
 
     def forward(self, x, cache=None, pos=None):
         B, S, H = x.shape
+        if cache is None and not _sp_active():
+            ctx = head_major_attention(*self._qkv_heads(x), causal=True)
+            return self.dropout(self._out_heads(ctx))
         q, k, v = self._qkv(x)
         if cache is not None:
             ctx, kc, vc = cached_attention(q, k, v, cache[0], cache[1],
                                            pos)
             return self.dropout(self.out_proj(
                 T.reshape(ctx, [B, S, H]))), (kc, vc)
-        if _sp_active():
-            ctx = ring_attention(q, k, v, causal=True)
-        else:
-            ctx, _ = F.flash_attention(q, k, v, causal=True,
-                                       training=self.training)
-        ctx = T.reshape(ctx, [B, S, H])
-        return self.dropout(self.out_proj(ctx))
+        ctx = ring_attention(q, k, v, causal=True)
+        return self.dropout(self.out_proj(T.reshape(ctx, [B, S, H])))
 
 
 class GPTMLP(Layer):

@@ -30,6 +30,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import paddle_tpu.distributed as dist
+from paddle_tpu.jit.functional import EXPORT_DISABLED_CHECKS
 from paddle_tpu.kernels.flash_block import flash_block_attention
 
 
@@ -163,7 +164,8 @@ def _export_train_step_for_tpu(step, batch=(2, 256)):
     scalar = jax.ShapeDtypeStruct((), jnp.float32)
     key = jax.eval_shape(lambda: _rng.default_generator().fold_in(1))
     ids = jax.ShapeDtypeStruct(batch, jnp.int64)
-    return jax.export.export(step._jitted, platforms=["tpu"])(
+    return jax.export.export(step._jitted, platforms=["tpu"],
+                             disabled_checks=EXPORT_DISABLED_CHECKS)(
         aval(step.params), aval(step.buffers), aval(step.opt_state),
         scalar, scalar, key, ids, ids)
 
@@ -491,6 +493,32 @@ def test_gpt1p3b_width_step_carries_its_scopes_for_v5e(one_chip,
         "backward", "backward", "forward"]
 
 
+@pytest.mark.parametrize("workload", ["train-gpt-125m", "train-gpt-1.3b"])
+def test_gpt_attention_has_no_activation_sized_copy_for_v5e(
+        workload, one_chip, chip_like_config, monkeypatch):
+    """Two GPT blocks forward + backward at each GPT cell's sizes, in the
+    cell's own composition (125M unrolled; 1.3B scanned and recomputed):
+    the projections write and read the kernel's [b, h, s, d], so the
+    compiled step holds no ``copy`` in the ``attn`` scope as large as q
+    (the fused [B, S, 3H] path had ten a layer: a slice and a transpose
+    of each of q, k, v, the output's transpose, and their cotangents').
+    What is left there, if anything, copies a third of the qkv weight."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.nn.functional.flash_attention")
+    cfg_kw, _, batch, seq = _cell(workload)
+    compiled, _ = _compile_train_step_for_v5e(
+        workload, one_chip, monkeypatch, num_layers=2)
+    assert fa.last_attention_dispatch()["layout"] == "head_major"
+    q_elements = batch * seq * cfg_kw["hidden_size"]
+    copies = re.findall(
+        r"= \w+\[([\d,]+)\]\S* copy\([^\n]*op_name=\"([^\"]*)\"",
+        compiled.as_text())
+    assert copies         # the regex reads this compiler's text
+    assert [(shape, scope) for shape, scope in copies if "/attn/" in scope
+            and np.prod([int(n) for n in shape.split(",")]) >= q_elements
+            ] == []
+
+
 # -- the library kernel under a multi-device mesh ---------------------------
 
 def test_mesh_wrap_decides_from_the_trace_time_mesh():
@@ -517,25 +545,31 @@ def test_mesh_wrap_decides_from_the_trace_time_mesh():
     assert "sp" in fa._mesh_wrap(shape)[2]
 
 
-def test_kernel_inside_the_shard_map_is_built_for_the_shard(topo,
-                                                            chip_like_config,
-                                                            monkeypatch):
+@pytest.mark.parametrize("head_axis", [2, 1])
+def test_kernel_inside_the_shard_map_is_built_for_the_shard(
+        head_axis, topo, chip_like_config, monkeypatch):
     """tp=4 on the described chips: _pallas_flash wraps the call in a
     shard_map over batch and heads, and the kernel (its mask tables are a
-    row a head) is built inside it from the shard's own head count."""
+    row a head) is built inside it from the shard's own head count; the
+    spec names heads on whichever axis the caller's layout has them
+    ([b, s, h, d], or the kernel's own [b, h, s, d])."""
     import importlib
-    from jax.sharding import NamedSharding
+    from jax.sharding import NamedSharding, PartitionSpec as P
     fa = importlib.import_module("paddle_tpu.nn.functional.flash_attention")
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
     dist.init_mesh({"dp": 2, "mp": 2}, devices=list(topo.devices))
-    mesh, spec, _ = fa._mesh_wrap((4, 1024, 8, 64))
+    shape = (4, 1024, 8, 64) if head_axis == 2 else (4, 8, 1024, 64)
+    mesh, spec, _ = fa._mesh_wrap(shape, head_axis=head_axis)
+    assert spec == (P("dp", None, "mp", None) if head_axis == 2
+                    else P("dp", "mp", None, None))
     built = []
     real = fa._splash_kernel.__wrapped__
     monkeypatch.setattr(fa, "_splash_kernel", lambda heads, *a: (
         built.append(heads), real(heads, *a))[1])
-    x = _sd((4, 1024, 8, 64), BF16, sharding=NamedSharding(mesh, spec))
+    x = _sd(shape, BF16, sharding=NamedSharding(mesh, spec))
     compiled = jax.jit(jax.grad(
-        lambda q, k, v: fa._pallas_flash(q, k, v, True, 0.125).astype(
+        lambda q, k, v: fa._pallas_flash(
+            q, k, v, True, 0.125, head_axis=head_axis).astype(
             jnp.float32).sum(), argnums=(0, 1, 2))).lower(x, x, x).compile()
     assert set(built) == {4}                       # 8 heads over mp=2
     text = compiled.as_text()
