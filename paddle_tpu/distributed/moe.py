@@ -203,7 +203,7 @@ class MoELayer(Layer):
 
 # what the last traced ``TokenChoiceMoE`` call did (as
 # ``F.last_attention_dispatch()`` for attention): {"kernel",
-# "experts_held", "experts_published", "top_k", "rows_bound"}
+# "experts_held", "experts_published", "top_k", "rows_bound", "tiling"}
 _last_moe = {}
 
 
@@ -212,7 +212,8 @@ def last_moe_dispatch() -> dict:
     multiplies the sorted rows with their experts), ``experts_held`` of
     ``experts_published``, ``top_k``, and ``rows_bound`` (the sorted rows
     the grouped product is built for; more assignments than that landing
-    here take the dense path, none is dropped)."""
+    here take the dense path, none is dropped) and ``tiling`` (the tiles
+    of the forward products by w1 and w3 and by w2, ``_gmm_tiles``)."""
     return dict(_last_moe)
 
 
@@ -220,9 +221,25 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-# rows, contraction and columns a tile of the grouped product: read on
-# the chip at [32768, 2048] x [16, 2048, 1024] (PERF.md section 6, PR 30)
-_GMM_TILING = (512, 1024, 1024)
+# rows a tile of the grouped products
+_GMM_ROWS = 512
+
+
+def _gmm_tiles(rows: int, k: int, n: int) -> tuple:
+    """(rows, contraction, columns) a tile of ONE grouped product
+    [rows, k] x [groups, k, n], from its own shapes: 512 rows, and of k and
+    of n the largest divisor up to 1024 in whole 128-lane tiles, so that no
+    tile is partial. Read on the chip (PERF.md section 6): (512, 1024, 1024)
+    at [32768, 2048] x [16, 2048, 1024] (PR 30), and at expert width 768
+    (512, 1024, 768) / (512, 768, 1024) against one tuple for all three
+    products of a layer (PR 34)."""
+    def whole(d):
+        t = min(1024, d)
+        while d % t and t > 128:
+            t -= 128
+        return t if d % t == 0 else d
+    return (min(_GMM_ROWS, rows), whole(k), whole(n))
+
 
 # sorted rows the grouped product is built for, in even shares of the
 # assignments (what a uniform routing lands on the experts held here).
@@ -235,6 +252,43 @@ _GMM_TILING = (512, 1024, 1024)
 _ROWS_OVER_EVEN = 3
 
 
+def _megablox():
+    """The module of the library's grouped kernels (the package's own
+    ``gmm`` attribute is its differentiable wrapper, not this)."""
+    import importlib
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+@jax.custom_vjp
+def _gmm(lhs, rhs, sizes):
+    """The library's megablox product with the tiles of each of its three
+    products (forward, and in the backward pass d lhs and d rhs) from that
+    product's own shapes: the library's own ``custom_vjp`` hands one tuple
+    to all three, and d lhs contracts over n where forward contracts
+    over k."""
+    return _megablox().gmm(lhs, rhs, sizes, lhs.dtype,
+                           _gmm_tiles(lhs.shape[0], *rhs.shape[1:]))
+
+
+def _gmm_fwd(lhs, rhs, sizes):
+    return _gmm(lhs, rhs, sizes), (lhs, rhs, sizes)
+
+
+def _gmm_bwd(res, grad):
+    backend = _megablox()
+    lhs, rhs, sizes = res
+    groups, k, n = rhs.shape
+    d_lhs = backend.gmm(grad, rhs, sizes, lhs.dtype,
+                        _gmm_tiles(grad.shape[0], n, k), transpose_rhs=True)
+    d_rhs = backend.tgmm(lhs.swapaxes(0, 1), grad, sizes, rhs.dtype,
+                         _gmm_tiles(lhs.shape[0], k, n), None, groups)
+    return d_lhs, d_rhs, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
 def _grouped_dot(lhs, rhs, sizes):
     """lhs [rows, k] sorted by group, rhs [groups, k, n], sizes [groups]
     -> [rows, n]: rows of group g times rhs[g]. Rows past the groups'
@@ -242,10 +296,7 @@ def _grouped_dot(lhs, rhs, sizes):
     visits the tiles that hold rows and no others; elsewhere XLA's
     ragged dot."""
     if _on_tpu():
-        from jax.experimental.pallas.ops.tpu.megablox import ops as mb
-        tm, tk, tn = _GMM_TILING
-        return mb.gmm(lhs, rhs, sizes, lhs.dtype,
-                      (min(tm, lhs.shape[0]), tk, tn))
+        return _gmm(lhs, rhs, sizes)
     return lax.ragged_dot(lhs, rhs, sizes)
 
 
@@ -383,7 +434,7 @@ class _Experts(Layer):
         worst = tokens * min(self.top_k, self.held)
         even = -(-tokens * self.top_k * self.held // self.published)
         rows = min(worst, _ROWS_OVER_EVEN * even)
-        unit = _GMM_TILING[0] if rows >= _GMM_TILING[0] else 16
+        unit = _GMM_ROWS if rows >= _GMM_ROWS else 16
         return -(-rows // unit) * unit
 
     def forward(self, x, sel, wgt):
@@ -394,7 +445,9 @@ class _Experts(Layer):
         _last_moe.update(
             kernel="megablox_gmm" if _on_tpu() else "xla_ragged_dot",
             experts_held=self.held, experts_published=self.published,
-            top_k=self.top_k, rows_bound=rows)
+            top_k=self.top_k, rows_bound=rows,
+            tiling={"w1_w3": _gmm_tiles(rows, *self.w1.shape[1:]),
+                    "w2": _gmm_tiles(rows, *self.w2.shape[1:])})
 
         def fn(xv, w1, w3, w2, wv, selv):
             local = selv - self.offset

@@ -7,6 +7,9 @@ from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel,
                     LlamaPipelineForCausalLM, llama_tiny, llama_7b,
                     llama_13b)
 from .afmoe import AfmoeConfig, AfmoeForCausalLM, AfmoeModel
+from .deepseek_v3 import (DeepseekV3Attention, DeepseekV3Block,
+                          DeepseekV3Config, DeepseekV3ForCausalLM,
+                          DeepseekV3Model)
 from .bert import (BertConfig, BertModel, BertForSequenceClassification,
                    BertForMaskedLM, ErnieModel, bert_tiny, bert_base,
                    ernie_3_tiny, ernie_3_base)
@@ -18,6 +21,8 @@ __all__ = ["GPTConfig", "GPTModel", "GPTForCausalLM",
            "LlamaPipelineForCausalLM", "llama_tiny", "llama_7b",
            "llama_13b",
            "AfmoeConfig", "AfmoeModel", "AfmoeForCausalLM",
+           "DeepseekV3Config", "DeepseekV3Attention", "DeepseekV3Block",
+           "DeepseekV3Model", "DeepseekV3ForCausalLM",
            "BertConfig", "BertModel", "BertForSequenceClassification",
            "BertForMaskedLM", "ErnieModel", "bert_tiny", "bert_base",
            "ernie_3_tiny", "ernie_3_base"]

@@ -24,10 +24,15 @@ to 1024, compute blocks up to 512); the kernel object is built once a geometry
 (``_splash_kernel``). Under a multi-device mesh the call runs per shard inside
 a shard_map (``_mesh_wrap``).
 
+q and k may be wider than v (latent attention: 192-wide q/k heads with
+128-wide v heads): every path carries the two sizes, the kernel reads
+``head_dim_v`` from v and writes its output at it.
+
 ``last_attention_dispatch()`` says what the last traced call did:
 ``backend`` ("pallas" | "xla"), ``reason``, ``window`` (None: no window),
-``kv_heads`` and ``layout``, and on the Pallas path ``kernel``
-("splash_fused") and ``blocks`` ({"q", "kv", "kv_compute"}).
+``kv_heads``, ``layout``, ``head_dim_qk`` and ``head_dim_v``, and on the
+Pallas path ``kernel`` ("splash_fused") and ``blocks`` ({"q", "kv",
+"kv_compute"}).
 
 Layout note: paddle flash_attention uses (batch, seqlen, nheads, head_dim),
 and every public functional here takes and returns that; the kernel's own
@@ -63,8 +68,8 @@ __all__ = ["flash_attention", "scaled_dot_product_attention",
 # (VERDICT r2 weak #3). {"backend": "pallas"|"xla", "reason": str,
 # "window": int or None, "kv_heads": int, "layout": "head_major" when the
 # Pallas call got operands no transpose of this module produced, else
-# "seq_major"} and, on the Pallas path, {"kernel": str,
-# "blocks": {"q", "kv", "kv_compute"}}
+# "seq_major", "head_dim_qk": int, "head_dim_v": int} and, on the Pallas
+# path, {"kernel": str, "blocks": {"q", "kv", "kv_compute"}}
 _last_dispatch = {}
 
 
@@ -136,15 +141,27 @@ def _mega_decode_on() -> bool:
     return not _tp_blocks_fused_knob("PADDLE_TPU_MEGA_DECODE")
 
 
-def _pallas_geometry_ok(seq: int, d: int, drop: float) -> bool:
+def _lanes_ok(d: int) -> bool:
+    """A head size the kernel's blocks take as their minor dimension:
+    within one 128-lane tile, or whole tiles."""
+    return d <= 128 or d % 128 == 0
+
+
+def _pallas_geometry_ok(seq: int, d: int, drop: float, d_v=None) -> bool:
     """Pure geometry gate for the Pallas TPU kernel: seq a multiple of
-    the 128-lane tile, head_dim either within one lane tile or a multiple
-    of 128, no attention dropout. Inside it the kernel admits every
-    causal window (any width of 1 or more; a width past the sequence is
-    plain causal) and every grouping of query heads over key/value heads
-    that divides them (``_check_heads`` refuses the rest on both paths),
-    so neither is read here."""
-    return (seq >= 128 and seq % 128 == 0 and (d <= 128 or d % 128 == 0)
+    the 128-lane tile, no attention dropout, and head sizes the kernel's
+    blocks take: v's (``d_v``; q's where it is not given) within one lane
+    tile or a multiple of 128, and q and k's either the same or wider
+    than v's by half tiles (192 = a tile and a half: Mosaic lays the
+    block out in two tiles, read on the chip at 192 / 128, PERF.md
+    section 6, PR 34). Inside it the kernel admits every causal window
+    (any width of 1 or more; a width past the sequence is plain causal)
+    and every grouping of query heads over key/value heads that divides
+    them (``_check_heads`` refuses the rest on both paths), so neither is
+    read here."""
+    d_v = d if d_v is None else d_v
+    qk_ok = _lanes_ok(d) if d == d_v else (d > d_v and d % 64 == 0)
+    return (seq >= 128 and seq % 128 == 0 and qk_ok and _lanes_ok(d_v)
             and drop == 0.0)
 
 
@@ -226,10 +243,11 @@ def _kv_for_mesh(q, k, v):
     return jnp.repeat(k, copies, axis=2), jnp.repeat(v, copies, axis=2)
 
 
-def _pallas_ok(q, d, drop, kv_heads, window, head_axis=2):
+def _pallas_ok(q, d, drop, kv_heads, window, head_axis=2, d_v=None):
+    d_v = d if d_v is None else d_v
     _last_dispatch.clear()      # a record of this call, none of an earlier
     _last_dispatch.update(window=window, kv_heads=kv_heads,
-                          layout="seq_major")
+                          layout="seq_major", head_dim_qk=d, head_dim_v=d_v)
     seq = q.shape[3 - head_axis]        # axes 1 and 2 hold seq and heads
     if not _on_tpu():
         _last_dispatch.update(backend="xla", reason="not on TPU")
@@ -240,14 +258,14 @@ def _pallas_ok(q, d, drop, kv_heads, window, head_axis=2):
                 "PADDLE_TPU_REQUIRE_PALLAS is set but the active backend "
                 f"is {jax.default_backend()!r}, not a TPU")
         return False
-    if not _pallas_geometry_ok(seq, d, drop):
+    if not _pallas_geometry_ok(seq, d, drop, d_v):
         _last_dispatch.update(
             backend="xla",
-            reason=f"geometry seq={seq} d={d} drop={drop}")
+            reason=f"geometry seq={seq} d={d} d_v={d_v} drop={drop}")
         if _require_pallas():
             raise RuntimeError(
                 "PADDLE_TPU_REQUIRE_PALLAS is set but the attention "
-                f"geometry (seq={seq}, head_dim={d}, "
+                f"geometry (seq={seq}, head_dim={d}, v head_dim={d_v}, "
                 f"dropout={drop}) cannot use the Pallas kernel")
         return False
     mesh, spec, why_not = _mesh_wrap(q.shape, kv_heads, head_axis)
@@ -337,7 +355,7 @@ def _pallas_flash_local(q, k, v, causal, scale, window=None, head_axis=2):
     qh, kh, vh = ((q, k, v) if head_axis == 1 else
                   (jnp.swapaxes(t, 1, 2) for t in (q, k, v)))
     b, heads, s_q, d = qh.shape
-    kv_heads, s_k = kh.shape[1], kh.shape[2]
+    kv_heads, s_k, d_v = kh.shape[1], kh.shape[2], vh.shape[3]
     grouped = kv_heads != heads
     # a window that reaches past every key is plain causal: one kernel
     if window is not None and window >= s_k:
@@ -363,7 +381,7 @@ def _pallas_flash_local(q, k, v, causal, scale, window=None, head_axis=2):
         # they are
         qg = qh.reshape(b, kv_heads, heads // kv_heads, s_q, d)
         out = jax.vmap(jax.vmap(kernel))(qg, kh, vh)
-        out = out.reshape(b, heads, s_q, d)
+        out = out.reshape(b, heads, s_q, d_v)
     else:
         out = jax.vmap(kernel)(qh, kh, vh)
     return out if head_axis == 1 else jnp.swapaxes(out, 1, 2)
@@ -394,6 +412,13 @@ def _xla_attention(q, k, v, bias, mask, causal, scale, dropout=0.0,
         keep = jax.random.bernoulli(dropout_key, 1.0 - dropout, probs.shape)
         probs = jnp.where(keep, probs / (1.0 - dropout), 0.0)
         return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v)
+    d_v = v.shape[-1]
+    if d_v != q.shape[-1]:
+        # the library call takes one head size: v in q's width, noughts
+        # beside it, and the result cut back
+        v = jnp.pad(v, ((0, 0),) * 3 + ((0, q.shape[-1] - d_v),))
+        return _xla_attention(q, k, v, bias, mask, causal, scale,
+                              window=window)[..., :d_v]
     return jax.nn.dot_product_attention(
         q, k, v, bias=bias,
         mask=mask, is_causal=causal, scale=scale,
@@ -405,10 +430,11 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
                     training=True, name=None, window=None):
     """q: (batch, seq, heads, head_dim); k/v the same, or with fewer
     heads that divide q's (GQA: query head h reads key/value head
-    h // group). ``window`` (with ``causal``): query i sees keys j with
+    h // group); v's head_dim may be smaller than q and k's, and is the
+    result's. ``window`` (with ``causal``): query i sees keys j with
     0 <= i - j < window. Returns (out, softmax_lse-like placeholder)
     matching paddle's (result, softmax) tuple shape."""
-    d = query.shape[-1]
+    d, d_v = query.shape[-1], value.shape[-1]
     scale = 1.0 / (d ** 0.5)
     drop = dropout if training else 0.0
     _check_heads(query.shape[2], key.shape[2], window, causal)
@@ -419,7 +445,7 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
 
     def f(q, k, v):
         k, v = _kv_for_mesh(q, k, v)
-        if _pallas_ok(q, d, drop, k.shape[2], window):
+        if _pallas_ok(q, d, drop, k.shape[2], window, d_v=d_v):
             # on the chip the kernel compiles or the call raises — an
             # XLA fallback here would make a broken kernel look healthy
             return _pallas_flash(q, k, v, causal, scale, window)
@@ -434,16 +460,18 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
 
 def head_major_attention(query, key, value, causal=True):
     """``flash_attention`` for a caller that holds the kernel's own layout:
-    q, k, v and the result are (batch, heads, seq, head_dim), and q carries
-    the softmax scale already (its projection put 1/sqrt(head_dim) on the
-    accumulator). On the Pallas path nothing but the kernel touches the
-    operands; the XLA path computes the same attention, at scale 1, in
-    its own (batch, seq, heads, head_dim). No dropout, no window."""
-    d = query.shape[-1]
+    q, k, v and the result are (batch, heads, seq, head_dim), v and the
+    result at v's head_dim where it is smaller than q and k's, and q
+    carries the softmax scale already (its projection put
+    1/sqrt(head_dim) on the accumulator). On the Pallas path nothing but
+    the kernel touches the operands; the XLA path computes the same
+    attention, at scale 1, in its own (batch, seq, heads, head_dim). No
+    dropout, no window."""
+    d, d_v = query.shape[-1], value.shape[-1]
     _check_heads(query.shape[1], key.shape[1], None, causal)
 
     def f(q, k, v):
-        if _pallas_ok(q, d, 0.0, k.shape[1], None, head_axis=1):
+        if _pallas_ok(q, d, 0.0, k.shape[1], None, head_axis=1, d_v=d_v):
             return _pallas_flash(q, k, v, causal, None, head_axis=1)
         out = _xla_attention(*(jnp.swapaxes(t, 1, 2) for t in (q, k, v)),
                              None, None, causal, 1.0)
@@ -480,8 +508,9 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
                                  training=True, name=None, window=None):
     """Parity: paddle scaled_dot_product_attention ((b, s, h, d) layout).
-    Grouped key/value heads and ``window`` as ``flash_attention``."""
-    d = query.shape[-1]
+    Grouped key/value heads, a narrower v and ``window`` as
+    ``flash_attention``."""
+    d, d_v = query.shape[-1], value.shape[-1]
     scale = 1.0 / (d ** 0.5)
     drop = dropout_p if training else 0.0
     _check_heads(query.shape[2], key.shape[2], window, is_causal)
@@ -493,7 +522,7 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     if attn_mask is None:
         def f(q, k, v):
             k, v = _kv_for_mesh(q, k, v)
-            if _pallas_ok(q, d, drop, k.shape[2], window):
+            if _pallas_ok(q, d, drop, k.shape[2], window, d_v=d_v):
                 return _pallas_flash(q, k, v, is_causal, scale, window)
             return _xla_attention(q, k, v, None, None, is_causal, scale,
                                   drop, dkey, window)

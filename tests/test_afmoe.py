@@ -255,7 +255,8 @@ def test_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(total, np.asarray(want), atol=5e-6)
     assert last_moe_dispatch() == {
         "kernel": "xla_ragged_dot", "experts_held": 2,
-        "experts_published": 8, "top_k": 2, "rows_bound": 96}
+        "experts_published": 8, "top_k": 2, "rows_bound": 96,
+        "tiling": {"w1_w3": (96, 32, 16), "w2": (96, 16, 32)}}
 
 
 @pytest.mark.parametrize("held,offset,bias,dense", [

@@ -271,10 +271,29 @@ def _library_flash(shape, kv_heads=None, window=None):
             [_sd(shape, BF16), _sd(kv_shape, BF16), _sd(kv_shape, BF16)], 2)
 
 
-def _grouped_experts():
-    """distributed/moe.py's sorted path at Trinity-Mini's share: 16,384
-    tokens, 8 of 128 experts a token, 16 held, 49,152 sorted rows through
-    the library's megablox kernels, forward and backward."""
+def _library_flash_mla():
+    """The head-major entry at the latent attention cell's operands: q and
+    k heads 192 wide, v heads 128, [2, 32, 8192, d], forward and the one
+    fused backward."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.nn.functional.flash_attention")
+
+    def loss(q, k, v):
+        with mock.patch.object(fa, "_on_tpu", lambda: True):
+            assert fa._pallas_ok(q, 192, 0.0, 32, None, head_axis=1, d_v=128)
+            return fa._pallas_flash(q, k, v, True, None,
+                                    head_axis=1).astype(jnp.float32).sum()
+    return (jax.grad(loss, argnums=(0, 1, 2)),
+            [_sd((2, 32, 8192, 192), BF16), _sd((2, 32, 8192, 192), BF16),
+             _sd((2, 32, 8192, 128), BF16)], 2)
+
+
+def _grouped_experts(width=1024, top_k=8, rows=49152):
+    """distributed/moe.py's sorted path at one chip's share of 128
+    experts: 16,384 tokens, ``top_k`` experts a token, 16 held of
+    ``width``, ``rows`` sorted rows through the library's megablox
+    kernels, forward and backward. Trinity-Mini's share by default; the
+    deepseek_v3 cell's at width 768, top-6, 36,864 rows."""
     import importlib
     moe = importlib.import_module("paddle_tpu.distributed.moe")
 
@@ -282,11 +301,11 @@ def _grouped_experts():
         with mock.patch.object(moe, "_on_tpu", lambda: True):
             here = sel < 16
             return moe._routed_sorted(x, w1, w3, w2, wgt, sel, here,
-                                      49152).astype(jnp.float32).sum()
+                                      rows).astype(jnp.float32).sum()
     return (jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
-            [_sd((16384, 2048), BF16), _sd((16, 2048, 1024), BF16),
-             _sd((16, 2048, 1024), BF16), _sd((16, 1024, 2048), BF16),
-             _sd((16384, 8), F32), _sd((16384, 8), I32)], 9)
+            [_sd((16384, 2048), BF16), _sd((16, 2048, width), BF16),
+             _sd((16, 2048, width), BF16), _sd((16, width, 2048), BF16),
+             _sd((16384, top_k), F32), _sd((16384, top_k), I32)], 9)
 
 
 def _slot_write(dtype, tail):
@@ -357,7 +376,9 @@ CHIP_COMPILE_CASES = {
         (2, 8192, 32, 128), kv_heads=4, window=2048),
     "library_flash_gqa_full_8k": lambda: _library_flash(
         (2, 8192, 32, 128), kv_heads=4),
+    "library_flash_mla_192_128_8k": _library_flash_mla,
     "grouped_experts_trinity_share": _grouped_experts,
+    "grouped_experts_kanana_share": lambda: _grouped_experts(768, 6, 36864),
     "fused_slot_write_bf16": lambda: _slot_write(BF16, (_H, _D)),
     "fused_slot_write_int8": lambda: _slot_write(I8, (_H, _D)),
     "fused_slot_write_scale": lambda: _slot_write(F32, (_H,)),
