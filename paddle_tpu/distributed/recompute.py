@@ -19,20 +19,31 @@ import jax
 from ..autograd import tape as _tape
 from ..core.tensor import Tensor
 from ..jit.functional import functional_call, raw_state, _wrap
+from ..nn.functional.flash_attention import ATTENTION_RESIDUAL
 from ..nn.layer_base import Layer
 
 __all__ = ["recompute", "recompute_sequential"]
 
 
-# named remat policies: "full" saves nothing (minimum memory, recomputes
-# the whole block); "dots" saves matmul outputs (recomputes only
-# elementwise/norm ops — trades HBM for a ~1/3 cut in recompute FLOPs)
+# named remat policies. Both keep the attention kernel's result and
+# logsumexp (flash_attention.ATTENTION_RESIDUAL: one [b, h, s, d_v]
+# activation and one f32 [b, h, s] a layer), so the backward pass never
+# runs the forward kernel a second time: per kept byte they are the
+# dearest thing in a block to recompute (about s_k kernel operations a
+# byte at a third of a matmul's rate; a matmul's output costs H).
+# "full" keeps the block's input and that, and recomputes everything
+# else; "dots" keeps matmul outputs besides (recomputes only
+# elementwise/norm ops — trades HBM for a ~1/3 cut in recompute FLOPs).
+# To keep nothing but the block's input (least memory) hand in the
+# callable jax.checkpoint_policies.nothing_saveable.
 _POLICIES = {"full": None, "dots": "dots_with_no_batch_dims_saveable"}
 
 
 def resolve_checkpoint_policy(policy):
     """Resolve a policy name ("full"/"dots"), a jax.checkpoint_policies
-    callable, or None into the `policy=` argument for jax.checkpoint."""
+    callable, or None into the `policy=` argument for jax.checkpoint. A
+    callable (and None, jax.checkpoint's own default) passes through
+    untouched."""
     if policy is None or callable(policy):
         return policy
     try:
@@ -41,7 +52,10 @@ def resolve_checkpoint_policy(policy):
         raise ValueError(
             f"recompute policy {policy!r} not in {sorted(_POLICIES)} "
             "(or pass a jax.checkpoint_policies callable)") from None
-    return getattr(jax.checkpoint_policies, name) if name else None
+    cp = jax.checkpoint_policies
+    keep = cp.save_only_these_names(ATTENTION_RESIDUAL)
+    return cp.save_from_both_policies(getattr(cp, name), keep) if name \
+        else keep
 
 
 def recompute(function, *args, **kwargs):

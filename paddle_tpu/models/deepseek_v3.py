@@ -85,6 +85,10 @@ class DeepseekV3Config:
     max_seq_len: int = 32768
     # rematerialize each block in backward (jax.checkpoint)
     recompute: bool = False
+    # what a recomputed block keeps beside its input: "full" the attention
+    # kernel's result and logsumexp, "dots" matmul outputs too; a
+    # jax.checkpoint_policies callable (nothing_saveable: keep nothing)
+    # passes through (distributed/recompute.py)
     recompute_policy: str = "full"
     # when >0, a training forward returns (hidden, lm_weight) and the loss
     # streams the head through F.fused_linear_cross_entropy in chunks

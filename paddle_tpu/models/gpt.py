@@ -65,8 +65,10 @@ class GPTConfig:
     # rematerialize each block's activations in backward (jax.checkpoint;
     # parity: fleet recompute_interval=1 over the decoder stack)
     recompute: bool = False
-    # remat policy for the scanned stack: "full" (save nothing) or
-    # "dots" (save matmul outputs, recompute only elementwise)
+    # what a recomputed block keeps beside its input: "full" the attention
+    # kernel's result and logsumexp, "dots" matmul outputs too; a
+    # jax.checkpoint_policies callable (nothing_saveable: keep nothing)
+    # passes through (distributed/recompute.py)
     recompute_policy: str = "full"
     # compile the block stack as ONE lax.scan over [L, ...]-stacked params
     # instead of L unrolled copies — O(1) HLO in depth (GPTScannedBlocks)

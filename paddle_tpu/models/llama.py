@@ -54,8 +54,10 @@ class LlamaConfig:
     initializer_range: float = 0.02
     # rematerialize each block in backward (jax.checkpoint) — scan path
     recompute: bool = False
-    # remat policy for the scanned stack: "full" (save nothing) or
-    # "dots" (save matmul outputs, recompute only elementwise)
+    # what a recomputed block keeps beside its input: "full" the attention
+    # kernel's result and logsumexp, "dots" matmul outputs too; a
+    # jax.checkpoint_policies callable (nothing_saveable: keep nothing)
+    # passes through (distributed/recompute.py)
     recompute_policy: str = "full"
     # compile the block stack as ONE lax.scan over [L, ...]-stacked params
     # (models/scanned.py ScannedStack) — depth-independent HLO

@@ -31,8 +31,15 @@ q and k may be wider than v (latent attention: 192-wide q/k heads with
 ``last_attention_dispatch()`` says what the last traced call did:
 ``backend`` ("pallas" | "xla"), ``reason``, ``window`` (None: no window),
 ``kv_heads``, ``layout``, ``head_dim_qk`` and ``head_dim_v``, and on the
-Pallas path ``kernel`` ("splash_fused") and ``blocks`` ({"q", "kv",
-"kv_compute"}).
+Pallas path ``kernel`` ("splash_fused"), ``blocks`` ({"q", "kv",
+"kv_compute"}) and ``residual`` (``ATTENTION_RESIDUAL``).
+
+Under recomputation the kernel's result and logsumexp carry the name
+``ATTENTION_RESIDUAL`` (``jax.ad_checkpoint.checkpoint_name``, inside the
+library's forward rule): a ``jax.checkpoint`` policy that keeps that name
+(``distributed.recompute``'s "full" and "dots") hands both to the fused
+backward kernel, and the recomputed block never runs the forward kernel a
+second time. Outside a checkpoint the name lowers to nothing.
 
 Layout note: paddle flash_attention uses (batch, seqlen, nheads, head_dim),
 and every public functional here takes and returns that; the kernel's own
@@ -69,8 +76,14 @@ __all__ = ["flash_attention", "scaled_dot_product_attention",
 # "window": int or None, "kv_heads": int, "layout": "head_major" when the
 # Pallas call got operands no transpose of this module produced, else
 # "seq_major", "head_dim_qk": int, "head_dim_v": int} and, on the Pallas
-# path, {"kernel": str, "blocks": {"q", "kv", "kv_compute"}}
+# path, {"kernel": str, "blocks": {"q", "kv", "kv_compute"},
+# "residual": ATTENTION_RESIDUAL}
 _last_dispatch = {}
+
+# the checkpoint_name of the kernel's result [b, h, s, d_v] and logsumexp
+# [b, h, s] (MHA and MQA kernel, every head size, window or none); why the
+# named policies keep it: distributed/recompute.py
+ATTENTION_RESIDUAL = "attention_kernel_residual"
 
 
 def last_attention_dispatch() -> dict:
@@ -330,7 +343,8 @@ def _splash_kernel(heads, s_q, s_k, causal, interpret, window=None,
     layer and every later trace of the same geometry. ``window``: query i
     sees keys j with 0 <= i - j < window (causal; the caller hands None
     for one that reaches every key). ``grouped``: the MQA kernel,
-    ``heads`` query heads on ONE key/value head ([s, d])."""
+    ``heads`` query heads on ONE key/value head ([s, d]). Its forward
+    rule names the result and the logsumexp ``ATTENTION_RESIDUAL``."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
     if window is not None:
@@ -345,6 +359,7 @@ def _splash_kernel(heads, s_q, s_k, causal, interpret, window=None,
         return make(
             sm.MultiHeadMask([mask] * heads),
             block_sizes=sk.BlockSizes(**_splash_blocks(s_q, s_k)),
+            residual_checkpoint_name=ATTENTION_RESIDUAL,
             interpret=interpret)
 
 
@@ -370,7 +385,8 @@ def _pallas_flash_local(q, k, v, causal, scale, window=None, head_axis=2):
         kernel="splash_fused" if blocks["use_fused_bwd_kernel"]
         else "splash",
         blocks={"q": blocks["block_q"], "kv": blocks["block_kv"],
-                "kv_compute": blocks["block_kv_compute"]})
+                "kv_compute": blocks["block_kv_compute"]},
+        residual=ATTENTION_RESIDUAL)
     # the kernel takes no scale: q carries it, from the caller's
     # projection (scale None) or put on here in q's dtype
     if scale is not None:

@@ -170,6 +170,123 @@ def test_splash_kernel_is_built_once_a_geometry():
     assert fa._splash_kernel.cache_info().currsize == 2
 
 
+def _pallas_calls(jaxpr, found=None):
+    """Pallas kernels of a jaxpr by name, through every nested jaxpr
+    (checkpoint, scan, custom_vjp, vmap bodies)."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            found[name] = found.get(name, 0) + 1
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (list, tuple)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _pallas_calls(sub, found)
+    return found
+
+
+# what reaches _pallas_flash_local: GPT's head-major MHA call, afmoe's
+# grouped call with a window, latent attention's 192-wide q/k on 128-wide v
+_RESIDUAL_CALLS = {
+    "mha_head_major": dict(q=(2, 4, 256, 64), kv=(2, 4, 256, 64),
+                           v=(2, 4, 256, 64), head_axis=1, scale=None,
+                           window=None, kernel="splash_mha"),
+    "grouped_window": dict(q=(2, 256, 4, 64), kv=(2, 256, 2, 64),
+                           v=(2, 256, 2, 64), head_axis=2, scale=0.125,
+                           window=128, kernel="splash_mqa"),
+    "heads_192_128": dict(q=(1, 2, 256, 192), kv=(1, 2, 256, 192),
+                          v=(1, 2, 256, 128), head_axis=1, scale=None,
+                          window=None, kernel="splash_mha"),
+}
+
+
+def _residual_block(call):
+    """A block as recomputation sees one: a product into the kernel and a
+    product out of it, and the operands to take its gradient at."""
+    from paddle_tpu.nn.functional.flash_attention import _pallas_flash_local
+    q, k, v = (_rand(*call[n], seed=i) * 0.3
+               for i, n in enumerate(("q", "kv", "v")))
+    w = _rand(call["q"][-1], call["q"][-1], seed=5) * 0.1
+
+    def block(q, k, v, w):
+        out = _pallas_flash_local(q @ w, k, v, True, call["scale"],
+                                  window=call["window"],
+                                  head_axis=call["head_axis"])
+        return jnp.tanh(out) * 2.0
+
+    return block, (q, k, v, w)
+
+
+def _policies():
+    from paddle_tpu.distributed.recompute import resolve_checkpoint_policy
+    return {"full": resolve_checkpoint_policy("full"),
+            "dots": resolve_checkpoint_policy("dots"),
+            "nothing": jax.checkpoint_policies.nothing_saveable}
+
+
+@pytest.mark.parametrize("policy,forwards",
+                         [("full", 1), ("dots", 1), ("nothing", 2)])
+@pytest.mark.parametrize("call", list(_RESIDUAL_CALLS))
+def test_recomputed_block_keeps_the_kernels_result(call, policy, forwards):
+    """Under the named policies the gradient of a checkpointed block runs
+    the forward kernel once (its result and logsumexp are kept for the
+    fused backward kernel), under nothing_saveable twice; the backward
+    kernel once either way."""
+    call = _RESIDUAL_CALLS[call]
+    block, args = _residual_block(call)
+    remat = jax.checkpoint(block, policy=_policies()[policy])
+    grad = jax.grad(lambda *a: remat(*a).sum(), argnums=(0, 1, 2, 3))
+    found = _pallas_calls(jax.make_jaxpr(grad)(*args).jaxpr)
+    assert found == {call["kernel"] + "_fwd_residuals": forwards,
+                     call["kernel"] + "_dkv_no_residuals": 1}
+
+
+@pytest.mark.parametrize("call", list(_RESIDUAL_CALLS))
+def test_kept_result_gives_the_unnamed_kernels_gradients(call, monkeypatch):
+    """The kept result and logsumexp are the ones a second run would have
+    produced: gradients bitwise those of a kernel built without the name,
+    whose block is recomputed whole."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.nn.functional.flash_attention")
+    block, args = _residual_block(_RESIDUAL_CALLS[call])
+
+    def grads(policy):
+        remat = jax.checkpoint(block, policy=policy)
+        return jax.jit(jax.grad(lambda *a: remat(*a).sum(),
+                                argnums=(0, 1, 2, 3)))(*args)
+
+    kept = grads(_policies()["full"])
+    monkeypatch.setattr(fa, "ATTENTION_RESIDUAL", None)
+    fa._splash_kernel.cache_clear()
+    try:
+        plain = grads(None)
+    finally:
+        fa._splash_kernel.cache_clear()     # no un-named kernel stays
+    for a, b in zip(kept, plain):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("policy,forwards",
+                         [("full", 1), ("dots", 1), ("nothing", 2)])
+def test_scanned_recomputed_body_keeps_the_kernels_result(policy, forwards):
+    """The same count where ScannedStack puts the checkpoint: on the body
+    of a lax.scan over stacked weights (the forward scan holds the kernel
+    once; the backward scan holds it again only if nothing was kept)."""
+    call = _RESIDUAL_CALLS["mha_head_major"]
+    block, (q, k, v, w) = _residual_block(call)
+    body = jax.checkpoint(lambda h, w: block(h, k, v, w),
+                          policy=_policies()[policy])
+
+    def stack(h, ws):
+        return jax.lax.scan(lambda h, w: (body(h, w), None), h, ws)[0].sum()
+
+    found = _pallas_calls(jax.make_jaxpr(jax.grad(stack, argnums=(0, 1)))(
+        q, jnp.stack([w, w, w])).jaxpr)
+    assert found == {"splash_mha_fwd_residuals": forwards,
+                     "splash_mha_dkv_no_residuals": 1}
+
+
 def test_dispatch_record_names_kernel_and_blocks(monkeypatch):
     """What the driver prints and chip_smoke.py records: backend, the
     library kernel that engaged and the blocks the rule gave it."""
@@ -191,6 +308,8 @@ def test_dispatch_record_names_kernel_and_blocks(monkeypatch):
         rec = fa_mod.last_attention_dispatch()
         assert rec["backend"] == "pallas" and rec["reason"] == "ok"
         assert rec["kernel"] == "splash_fused" and rec["blocks"] == blocks
+        # the kernel in the window names its result for recomputation
+        assert rec["residual"] == fa_mod.ATTENTION_RESIDUAL
 
 
 def test_require_pallas_flag_raises(monkeypatch):

@@ -87,6 +87,26 @@ class TestScanLayersParity:
             GPTForCausalLM(gpt_tiny(scan_layers=True,
                                     recompute_policy="bogus"))
 
+    def test_callable_policy_passes_through_and_names_are_checked(self):
+        # a jax.checkpoint_policies callable is the caller's own choice
+        # (nothing_saveable: keep nothing, least memory) and reaches
+        # jax.checkpoint as it is; the named ones keep the attention
+        # kernel's result; any other name is refused
+        import jax
+        from paddle_tpu.distributed.recompute import (
+            resolve_checkpoint_policy)
+        from paddle_tpu.models.scanned import ScannedStack
+        nothing = jax.checkpoint_policies.nothing_saveable
+        assert resolve_checkpoint_policy(nothing) is nothing
+        assert resolve_checkpoint_policy(None) is None
+        stack = ScannedStack(lambda: paddle.nn.Linear(4, 4), 2, 0.02,
+                             recompute=True, recompute_policy=nothing)
+        assert stack._ckpt_policy is nothing
+        for name in ("full", "dots"):
+            assert callable(resolve_checkpoint_policy(name))
+        with pytest.raises(ValueError, match="recompute policy"):
+            resolve_checkpoint_policy("nothing_saveable")
+
     def test_jit_save_load_roundtrip(self, tmp_path):
         # scanned models must export (lax.scan -> StableHLO) and serve
         import paddle_tpu.jit as jit
