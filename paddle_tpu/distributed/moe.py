@@ -203,7 +203,8 @@ class MoELayer(Layer):
 
 # what the last traced ``TokenChoiceMoE`` call did (as
 # ``F.last_attention_dispatch()`` for attention): {"kernel",
-# "experts_held", "experts_published", "top_k", "rows_bound", "tiling"}
+# "experts_held", "experts_published", "top_k", "rows_bound", "tiling",
+# "activation", "score", "router_input"}
 _last_moe = {}
 
 
@@ -212,8 +213,13 @@ def last_moe_dispatch() -> dict:
     multiplies the sorted rows with their experts), ``experts_held`` of
     ``experts_published``, ``top_k``, and ``rows_bound`` (the sorted rows
     the grouped product is built for; more assignments than that landing
-    here take the dense path, none is dropped) and ``tiling`` (the tiles
-    of the forward products by w1 and w3 and by w2, ``_gmm_tiles``)."""
+    here take the dense path, none is dropped), ``tiling`` (the tiles
+    of the forward products by w1 and w3 and by w2, ``_gmm_tiles``),
+    ``activation`` (the experts' gate: "silu" | "relu"), ``score`` (the
+    router's rule: "sigmoid" | "softmax_of_chosen") and ``router_input``
+    ("expert_input": the layer routed on the tensor its experts read;
+    "given": the caller handed in a ``route()``, of whatever tensor it
+    chose)."""
     return dict(_last_moe)
 
 
@@ -321,11 +327,16 @@ def _sorted_weights_bwd(pos, d_rows):
 _sorted_weights.defvjp(_sorted_weights_fwd, _sorted_weights_bwd)
 
 
-def _swiglu(x, w1, w3, w2, dot):
-    return dot(jax.nn.silu(dot(x, w1)) * dot(x, w3), w2)
+# the gate's activation of a gated expert, by its name in the layer's
+# settings: SwiGLU (arXiv:2002.05202) or its ReLU form, ReGLU
+_GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
-def _routed_sorted(x, w1, w3, w2, wgt, local, here, rows):
+def _glu(x, w1, w3, w2, dot, act):
+    return dot(act(dot(x, w1)) * dot(x, w3), w2)
+
+
+def _routed_sorted(x, w1, w3, w2, wgt, local, here, rows, act):
     """The held experts' part for x [T, d] by sorted rows: ``rows`` of
     them, which must hold every assignment that landed here."""
     T, k = local.shape
@@ -346,7 +357,7 @@ def _routed_sorted(x, w1, w3, w2, wgt, local, here, rows):
     # rows past the groups are written by no product, forward or
     # backward, and hold whatever the buffer held: nought on both sides
     live = (jnp.arange(rows) < landed)[:, None]
-    y = _swiglu(jnp.where(live, x[tok], 0), w1, w3, w2, dot)
+    y = _glu(jnp.where(live, x[tok], 0), w1, w3, w2, dot, act)
     y = jnp.where(live, y, 0)
     y = y * _sorted_weights(wgt, slot, pos)[:, None].astype(y.dtype)
     # combine: each row back to its token. (The chip adds rows of the
@@ -355,7 +366,7 @@ def _routed_sorted(x, w1, w3, w2, wgt, local, here, rows):
     return jnp.zeros_like(x).at[tok].add(y)
 
 
-def _routed_dense(x, w1, w3, w2, wgt, local, here):
+def _routed_dense(x, w1, w3, w2, wgt, local, here, act):
     """The same part with no bound on the rows: every held expert over
     every token, by the token's weight for it (nought where it did not
     choose it). ``held`` times the work; the path of a routing that lands
@@ -364,7 +375,7 @@ def _routed_dense(x, w1, w3, w2, wgt, local, here):
     cw = jnp.einsum("tk,tke->te", jnp.where(here, wgt, 0),
                     jax.nn.one_hot(local, held, dtype=wgt.dtype))
     one = jax.checkpoint(lambda a, b, c, w: w[:, None].astype(x.dtype)
-                         * _swiglu(x, a, b, c, jnp.dot))
+                         * _glu(x, a, b, c, jnp.dot, act))
 
     def add(acc, e):
         return acc + one(*e), None
@@ -372,11 +383,19 @@ def _routed_dense(x, w1, w3, w2, wgt, local, here):
 
 
 class _Router(Layer):
-    """Scores of every published expert and the choice among them."""
+    """Scores of every published expert and the choice among them.
+    ``score`` "sigmoid": a sigmoid of each logit, the chosen ones' over
+    their sum where ``route_norm``; "softmax_of_chosen": the choice by the
+    raw logits and a softmax over the chosen ones alone (which sums to 1:
+    ``route_norm`` changes nothing)."""
 
     def __init__(self, d_model, num_experts, top_k, route_norm, route_scale,
-                 init):
+                 init, score="sigmoid"):
         super().__init__()
+        if score not in ("sigmoid", "softmax_of_chosen"):
+            raise ValueError(f"score {score!r}: sigmoid or "
+                             "softmax_of_chosen")
+        self.score = score
         self.top_k, self.route_norm = int(top_k), bool(route_norm)
         self.route_scale = float(route_scale)
         self.num_experts = int(num_experts)
@@ -386,9 +405,12 @@ class _Router(Layer):
     def forward(self, x, bias):
         """x [T, d] -> (sel [T, k] int32, weights [T, k] f32, counts
         [E] f32). The bias enters the choice, never the weight."""
+        softmax = self.score == "softmax_of_chosen"
+
         def scores(xv, w):
-            return jax.nn.sigmoid(jnp.dot(
-                xv, w.astype(xv.dtype), preferred_element_type=jnp.float32))
+            logits = jnp.dot(xv, w.astype(xv.dtype),
+                             preferred_element_type=jnp.float32)
+            return logits if softmax else jax.nn.sigmoid(logits)
 
         def choose(s, b):
             sel = lax.top_k(s + b, self.top_k)[1].astype(jnp.int32)
@@ -398,7 +420,9 @@ class _Router(Layer):
 
         def weigh(s, sel):
             w = jnp.take_along_axis(s, sel, axis=-1)
-            if self.route_norm:
+            if softmax:
+                w = jax.nn.softmax(w, axis=-1)
+            elif self.route_norm:
                 w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
             return w * self.route_scale
 
@@ -410,11 +434,16 @@ class _Router(Layer):
 
 
 class _Experts(Layer):
-    """The SwiGLU experts held here, stacked [held, ...] over "ep"."""
+    """The gated experts held here, w2(act(w1 x) * w3 x), stacked
+    [held, ...] over "ep"."""
 
     def __init__(self, d_model, d_expert, held, offset, published, top_k,
-                 init):
+                 init, activation="silu"):
         super().__init__()
+        if activation not in _GATES:
+            raise ValueError(f"activation {activation!r}: one of "
+                             f"{sorted(_GATES)}")
+        self.activation = activation
         self.held, self.offset = int(held), int(offset)
         self.published, self.top_k = int(published), int(top_k)
 
@@ -445,9 +474,11 @@ class _Experts(Layer):
         _last_moe.update(
             kernel="megablox_gmm" if _on_tpu() else "xla_ragged_dot",
             experts_held=self.held, experts_published=self.published,
-            top_k=self.top_k, rows_bound=rows,
+            top_k=self.top_k, rows_bound=rows, activation=self.activation,
             tiling={"w1_w3": _gmm_tiles(rows, *self.w1.shape[1:]),
                     "w2": _gmm_tiles(rows, *self.w2.shape[1:])})
+
+        act = _GATES[self.activation]
 
         def fn(xv, w1, w3, w2, wv, selv):
             local = selv - self.offset
@@ -456,14 +487,15 @@ class _Experts(Layer):
             return lax.cond(
                 jnp.sum(here) <= rows,
                 lambda: _routed_sorted(xv, w1, w3, w2, wv, local, here,
-                                       rows),
-                lambda: _routed_dense(xv, w1, w3, w2, wv, local, here))
+                                       rows, act),
+                lambda: _routed_dense(xv, w1, w3, w2, wv, local, here, act))
         return _tape.apply(fn, x, self.w1, self.w3, self.w2, wgt, sel,
                            _op_name="moe_experts")
 
 
 class TokenChoiceMoE(Layer):
-    """Dropless token-choice mixture of SwiGLU experts.
+    """Dropless token-choice mixture of gated experts (SwiGLU;
+    ``activation="relu"``: ReGLU).
 
     ``num_experts`` is the published count the router scores;
     ``experts_held`` of them, from ``expert_offset`` on, live here (one
@@ -471,6 +503,13 @@ class TokenChoiceMoE(Layer):
     Assignments to experts held elsewhere add nothing here: with every
     share's output and the shared expert counted once, the shares add up
     to the whole layer. Nothing is dropped under any routing.
+
+    ``score`` is the router's rule (``_Router``). The router reads what
+    the experts read unless the caller routes apart: ``route(t)`` gives
+    the choice, weights and counts for another tensor of the same tokens
+    (the block's input, before attention, in ``models/smallthinker.py``),
+    and ``forward(x, routing=...)`` runs the experts on x under it. The
+    sort and the sizes of the dispatch then depend on ``t`` alone.
 
     ``forward(x)`` returns ``(y, counts)``: counts [num_experts] of the
     tokens that chose each expert, a VALUE, so that the layer runs under
@@ -490,7 +529,8 @@ class TokenChoiceMoE(Layer):
     def __init__(self, d_model, d_expert, num_experts, top_k,
                  experts_held=None, expert_offset=0, shared_expert=None,
                  route_norm=True, route_scale=1.0, bias_update_rate=0.001,
-                 initializer_range=0.02):
+                 initializer_range=0.02, score="sigmoid",
+                 activation="silu"):
         super().__init__()
         held = num_experts if experts_held is None else int(experts_held)
         if not 0 <= expert_offset <= num_experts - held:
@@ -498,9 +538,9 @@ class TokenChoiceMoE(Layer):
                              f" are not among the {num_experts} published")
         init = I.Normal(0.0, initializer_range)
         self.router = _Router(d_model, num_experts, top_k, route_norm,
-                              route_scale, init)
+                              route_scale, init, score)
         self.experts = _Experts(d_model, d_expert, held, expert_offset,
-                                num_experts, top_k, init)
+                                num_experts, top_k, init, activation)
         self.shared_expert = shared_expert
         self.bias_update_rate = float(bias_update_rate)
         self.register_buffer("expert_bias", Tensor(
@@ -509,12 +549,24 @@ class TokenChoiceMoE(Layer):
             self.register_buffer(name, Tensor(
                 jnp.zeros((num_experts,), jnp.float32), stop_gradient=True))
 
-    def forward(self, x):
-        """x [..., d_model] -> (y, counts)."""
+    def route(self, x):
+        """x [..., d_model] -> (sel [T, k], weights [T, k], counts
+        [num_experts]) over its T tokens: what ``forward`` takes as
+        ``routing``."""
+        from .. import tensor as T
+        return self.router(T.reshape(x, [-1, x.shape[-1]]), self.expert_bias)
+
+    def forward(self, x, routing=None):
+        """x [..., d_model] -> (y, counts); ``routing``: a ``route()`` of
+        the same tokens, made from another tensor than x."""
         from .. import tensor as T
         flat = T.reshape(x, [-1, x.shape[-1]])
-        sel, wgt, counts = self.router(flat, self.expert_bias)
+        sel, wgt, counts = self.router(flat, self.expert_bias) \
+            if routing is None else routing
         y = self.experts(flat, sel, wgt)
+        _last_moe.update(score=self.router.score,
+                         router_input="expert_input" if routing is None
+                         else "given")
         if self.shared_expert is not None:
             y = y + self.shared_expert(flat)
         return T.reshape(y, list(x.shape)), counts

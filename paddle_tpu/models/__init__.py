@@ -10,6 +10,9 @@ from .afmoe import AfmoeConfig, AfmoeForCausalLM, AfmoeModel
 from .deepseek_v3 import (DeepseekV3Attention, DeepseekV3Block,
                           DeepseekV3Config, DeepseekV3ForCausalLM,
                           DeepseekV3Model)
+from .smallthinker import (SmallThinkerAttention, SmallThinkerBlock,
+                           SmallThinkerConfig, SmallThinkerForCausalLM,
+                           SmallThinkerModel)
 from .bert import (BertConfig, BertModel, BertForSequenceClassification,
                    BertForMaskedLM, ErnieModel, bert_tiny, bert_base,
                    ernie_3_tiny, ernie_3_base)
@@ -23,6 +26,9 @@ __all__ = ["GPTConfig", "GPTModel", "GPTForCausalLM",
            "AfmoeConfig", "AfmoeModel", "AfmoeForCausalLM",
            "DeepseekV3Config", "DeepseekV3Attention", "DeepseekV3Block",
            "DeepseekV3Model", "DeepseekV3ForCausalLM",
+           "SmallThinkerConfig", "SmallThinkerAttention",
+           "SmallThinkerBlock", "SmallThinkerModel",
+           "SmallThinkerForCausalLM",
            "BertConfig", "BertModel", "BertForSequenceClassification",
            "BertForMaskedLM", "ErnieModel", "bert_tiny", "bert_base",
            "ernie_3_tiny", "ernie_3_base"]

@@ -46,8 +46,10 @@ and every public functional here takes and returns that; the kernel's own
 [b, h, s, d] is then a transpose of each operand and of the result away
 (``layout`` "seq_major"). ``head_major_attention`` is the entry for a caller
 whose projections already write and read [b, h, s, d] with the scale folded
-into q (models/gpt.py): nothing is transposed or scaled round the kernel
-(``layout`` "head_major"). ``head_axis`` below is 2 for the first and 1 for
+into q (models/gpt.py, models/deepseek_v3.py, models/smallthinker.py):
+nothing is transposed or scaled round the kernel (``layout`` "head_major");
+it takes a causal window and fewer key/value heads as the public
+functionals do. ``head_axis`` below is 2 for the first and 1 for
 the second.
 """
 from __future__ import annotations
@@ -474,23 +476,27 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
     return out, None
 
 
-def head_major_attention(query, key, value, causal=True):
+def head_major_attention(query, key, value, causal=True, window=None):
     """``flash_attention`` for a caller that holds the kernel's own layout:
-    q, k, v and the result are (batch, heads, seq, head_dim), v and the
-    result at v's head_dim where it is smaller than q and k's, and q
-    carries the softmax scale already (its projection put
-    1/sqrt(head_dim) on the accumulator). On the Pallas path nothing but
-    the kernel touches the operands; the XLA path computes the same
-    attention, at scale 1, in its own (batch, seq, heads, head_dim). No
-    dropout, no window."""
+    q and the result are (batch, heads, seq, head_dim), k and v the same or
+    with fewer heads that divide q's (GQA: query head h reads key/value
+    head h // group; any group, 7 as well as 8), v and the result at v's
+    head_dim where it is smaller than q and k's, and q carries the softmax
+    scale already (its projection put 1/sqrt(head_dim) on the
+    accumulator). ``window`` (with ``causal``): query i sees keys j with
+    0 <= i - j < window. On the Pallas path nothing but the kernel touches
+    the operands (grouped heads: one MQA call a key/value head, q viewed
+    [batch, kv heads, group, seq, head_dim], k and v as they are); the XLA
+    path computes the same attention, at scale 1, in its own
+    (batch, seq, heads, head_dim). No dropout."""
     d, d_v = query.shape[-1], value.shape[-1]
-    _check_heads(query.shape[1], key.shape[1], None, causal)
+    _check_heads(query.shape[1], key.shape[1], window, causal)
 
     def f(q, k, v):
-        if _pallas_ok(q, d, 0.0, k.shape[1], None, head_axis=1, d_v=d_v):
-            return _pallas_flash(q, k, v, causal, None, head_axis=1)
+        if _pallas_ok(q, d, 0.0, k.shape[1], window, head_axis=1, d_v=d_v):
+            return _pallas_flash(q, k, v, causal, None, window, head_axis=1)
         out = _xla_attention(*(jnp.swapaxes(t, 1, 2) for t in (q, k, v)),
-                             None, None, causal, 1.0)
+                             None, None, causal, 1.0, window=window)
         return jnp.swapaxes(out, 1, 2)
 
     return apply(f, query, key, value, _op_name="flash_attention")

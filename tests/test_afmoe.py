@@ -256,7 +256,9 @@ def test_shares_add_up_to_the_uncut_layer():
     assert last_moe_dispatch() == {
         "kernel": "xla_ragged_dot", "experts_held": 2,
         "experts_published": 8, "top_k": 2, "rows_bound": 96,
-        "tiling": {"w1_w3": (96, 32, 16), "w2": (96, 16, 32)}}
+        "tiling": {"w1_w3": (96, 32, 16), "w2": (96, 16, 32)},
+        "activation": "silu", "score": "sigmoid",
+        "router_input": "expert_input"}
 
 
 @pytest.mark.parametrize("held,offset,bias,dense", [

@@ -289,23 +289,43 @@ def _library_flash_mla():
              _sd((2, 32, 8192, 128), BF16)], 2)
 
 
-def _grouped_experts(width=1024, top_k=8, rows=49152):
-    """distributed/moe.py's sorted path at one chip's share of 128
-    experts: 16,384 tokens, ``top_k`` experts a token, 16 held of
-    ``width``, ``rows`` sorted rows through the library's megablox
+def _library_flash_head_major_grouped(window):
+    """The head-major entry at the SmallThinker cell's operands: 28 query
+    heads in groups of 7 on 4 key/value heads of 128 at 16,384 positions,
+    a causal window of 4096 or none, forward and the one fused backward."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.nn.functional.flash_attention")
+
+    def loss(q, k, v):
+        with mock.patch.object(fa, "_on_tpu", lambda: True):
+            assert fa._pallas_ok(q, 128, 0.0, 4, window, head_axis=1)
+            return fa._pallas_flash(q, k, v, True, None, window,
+                                    head_axis=1).astype(jnp.float32).sum()
+    return (jax.grad(loss, argnums=(0, 1, 2)),
+            [_sd((1, 28, 16384, 128), BF16), _sd((1, 4, 16384, 128), BF16),
+             _sd((1, 4, 16384, 128), BF16)], 2)
+
+
+def _grouped_experts(width=1024, top_k=8, rows=49152, hidden=2048,
+                     gate="silu"):
+    """distributed/moe.py's sorted path at one chip's share of the
+    published experts: 16,384 tokens, ``top_k`` experts a token, 16 held
+    of ``width``, ``rows`` sorted rows through the library's megablox
     kernels, forward and backward. Trinity-Mini's share by default; the
-    deepseek_v3 cell's at width 768, top-6, 36,864 rows."""
+    deepseek_v3 cell's at width 768, top-6, 36,864 rows; the SmallThinker
+    cell's at hidden 2560, width 768, top-6, 73,728 rows, ReLU gate."""
     import importlib
     moe = importlib.import_module("paddle_tpu.distributed.moe")
 
     def loss(x, w1, w3, w2, wgt, sel):
         with mock.patch.object(moe, "_on_tpu", lambda: True):
             here = sel < 16
-            return moe._routed_sorted(x, w1, w3, w2, wgt, sel, here,
-                                      rows).astype(jnp.float32).sum()
+            return moe._routed_sorted(
+                x, w1, w3, w2, wgt, sel, here, rows,
+                moe._GATES[gate]).astype(jnp.float32).sum()
     return (jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
-            [_sd((16384, 2048), BF16), _sd((16, 2048, width), BF16),
-             _sd((16, 2048, width), BF16), _sd((16, width, 2048), BF16),
+            [_sd((16384, hidden), BF16), _sd((16, hidden, width), BF16),
+             _sd((16, hidden, width), BF16), _sd((16, width, hidden), BF16),
              _sd((16384, top_k), F32), _sd((16384, top_k), I32)], 9)
 
 
@@ -380,6 +400,12 @@ CHIP_COMPILE_CASES = {
     "library_flash_mla_192_128_8k": _library_flash_mla,
     "grouped_experts_trinity_share": _grouped_experts,
     "grouped_experts_kanana_share": lambda: _grouped_experts(768, 6, 36864),
+    "library_flash_head_major_gqa7_window_16k":
+        lambda: _library_flash_head_major_grouped(4096),
+    "library_flash_head_major_gqa7_full_16k":
+        lambda: _library_flash_head_major_grouped(None),
+    "grouped_experts_smallthinker_share": lambda: _grouped_experts(
+        768, 6, 73728, hidden=2560, gate="relu"),
     "fused_slot_write_bf16": lambda: _slot_write(BF16, (_H, _D)),
     "fused_slot_write_int8": lambda: _slot_write(I8, (_H, _D)),
     "fused_slot_write_scale": lambda: _slot_write(F32, (_H,)),
