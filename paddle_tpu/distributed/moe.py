@@ -24,11 +24,13 @@ and short batches; ``TokenChoiceMoE`` is for everything else.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..autograd import tape as _tape
 from ..core.tensor import Parameter, Tensor
@@ -38,7 +40,7 @@ from ..nn.layer_base import Layer
 from . import mesh as mesh_mod
 
 __all__ = ["MoELayer", "SwitchGate", "GShardGate", "NaiveGate",
-           "TokenChoiceMoE", "last_moe_dispatch"]
+           "TokenChoiceMoE", "last_moe_dispatch", "EXPERTS_RESULT"]
 
 
 class _BaseGate(Layer):
@@ -203,18 +205,23 @@ class MoELayer(Layer):
 
 # what the last traced ``TokenChoiceMoE`` call did (as
 # ``F.last_attention_dispatch()`` for attention): {"kernel",
-# "experts_held", "experts_published", "top_k", "rows_bound", "tiling",
-# "activation", "score", "router_input"}
+# "experts_held", "experts_published", "top_k", "rows_ladder", "rows_bound",
+# "tiling", "activation", "score", "router_input"}
 _last_moe = {}
 
 
 def last_moe_dispatch() -> dict:
     """The most recent ``TokenChoiceMoE`` dispatch: ``kernel`` (what
     multiplies the sorted rows with their experts), ``experts_held`` of
-    ``experts_published``, ``top_k``, and ``rows_bound`` (the sorted rows
-    the grouped product is built for; more assignments than that landing
-    here take the dense path, none is dropped), ``tiling`` (the tiles
-    of the forward products by w1 and w3 and by w2, ``_gmm_tiles``),
+    ``experts_published``, ``top_k``, ``rows_ladder`` (the static sizes, in
+    sorted rows, the layer is built for, ascending: a call runs at the
+    first that holds the assignments that landed here, so its gathers,
+    masks and elementwise passes cost by that rung and not by the last),
+    ``rows_bound`` (the last rung, the most the sorted path holds;
+    more assignments than that landing here take the dense path, none is
+    dropped), ``tiling`` (the tiles of the forward products by w1 and w3
+    and by w2 at ``rows_bound``, ``_gmm_tiles``; a lower rung takes its
+    own by the same rule),
     ``activation`` (the experts' gate: "silu" | "relu"), ``score`` (the
     router's rule: "sigmoid" | "softmax_of_chosen") and ``router_input``
     ("expert_input": the layer routed on the tensor its experts read;
@@ -247,15 +254,24 @@ def _gmm_tiles(rows: int, k: int, n: int) -> tuple:
     return (min(_GMM_ROWS, rows), whole(k), whole(n))
 
 
-# sorted rows the grouped product is built for, in even shares of the
-# assignments (what a uniform routing lands on the experts held here).
-# Sized from what landed on the chip (PERF.md section 6, PR 30): a router
+# the ladder of sorted rows a layer is built for, in even shares of the
+# assignments (what a uniform routing lands on the experts held here). A
+# call runs at the first rung that holds what landed (``_laddered``), so
+# the gathers, masks and elementwise passes round the products cost by the
+# rung taken: a balanced routing (0.99 - 1.02 shares a layer on every seed
+# of the SmallThinker cell, PERF.md section 2) pays for 1.25, a drifting
+# layer for the top. (The two scatter-adds a layer and step, the combine
+# and the gather's transpose, do not follow: on the chip they take 8 - 9 ms
+# a call at any rung, PERF.md section 6, PR 37.) The top is three shares
+# because of what landed on the chip (PERF.md section 6, PR 30): a router
 # trained from a random start without a warm-up put up to 2.2 even shares
-# on one layer's held experts within 25 steps, and at 2 shares two seeds
-# of nine took the dense path for some steps. The gather, scatter-add
-# and masks round the products cost by this bound, not by what lands:
-# 3 shares for 2 cost 4.0% of the step there
-_ROWS_OVER_EVEN = 3
+# on one layer's held experts within 25 steps, and with a top of 2 two
+# seeds of nine took the dense path for some steps. Before the ladder
+# every call paid for the top. Two rungs and no third between them: every
+# rung is twelve more kernels a layer in the step's program, 3 s of every
+# warm set-up on the chip, and a rung at 2 shares saved the one cell whose
+# layers drift 0.4% of a step
+_ROWS_OVER_EVEN = (1.25, 3)
 
 
 def _megablox():
@@ -336,20 +352,31 @@ def _glu(x, w1, w3, w2, dot, act):
     return dot(act(dot(x, w1)) * dot(x, w3), w2)
 
 
-def _routed_sorted(x, w1, w3, w2, wgt, local, here, rows, act):
-    """The held experts' part for x [T, d] by sorted rows: ``rows`` of
-    them, which must hold every assignment that landed here."""
-    T, k = local.shape
-    held = w1.shape[0]
+def _sorted_index(local, here, held):
+    """The index work of the sorted path, which no rung's size enters:
+    ``order`` [T * k], the assignments sorted by the held expert they chose
+    (a stable sort; those held elsewhere last), ``pos`` [T, k], where each
+    assignment stands in that order, ``sizes`` [held], the rows of each
+    expert's group, and ``landed``, their sum. Made once a call, outside
+    the conditional, so that it waits for nothing but the routing."""
     key = jnp.where(here, local, held).reshape(-1)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
     bounds = jnp.searchsorted(key[order], jnp.arange(held + 1,
                                                      dtype=key.dtype))
     sizes = jnp.diff(bounds).astype(jnp.int32)
-    landed = bounds[held]
+    pos = jnp.argsort(order).astype(jnp.int32).reshape(here.shape)
+    return order, pos, sizes, bounds[held]
+
+
+def _routed_sorted(x, w1, w3, w2, wgt, here, index, rows, act):
+    """The held experts' part for x [T, d] by sorted rows: ``rows`` of
+    them, which must hold every assignment that landed here. ``index``:
+    ``_sorted_index`` of the routing, of which this takes the first
+    ``rows``."""
+    order, pos, sizes, landed = index
+    T, k = here.shape
     # where each assignment stands in the sorted order; `rows` (the row
     # of noughts) for one that is held elsewhere
-    pos = jnp.argsort(order).astype(jnp.int32).reshape(T, k)
     pos = jnp.where(here & (pos < rows), pos, rows)
     slot = jnp.pad(order, (0, max(0, rows - T * k)))[:rows]
     tok = slot // k
@@ -366,6 +393,12 @@ def _routed_sorted(x, w1, w3, w2, wgt, local, here, rows, act):
     return jnp.zeros_like(x).at[tok].add(y)
 
 
+def _rung(landed, ladder):
+    """Which of ``ladder``'s ascending sizes is the first to hold
+    ``landed`` rows; ``len(ladder)`` when none does."""
+    return sum((landed > rows).astype(jnp.int32) for rows in ladder)
+
+
 def _routed_dense(x, w1, w3, w2, wgt, local, here, act):
     """The same part with no bound on the rows: every held expert over
     every token, by the token's weight for it (nought where it did not
@@ -380,6 +413,92 @@ def _routed_dense(x, w1, w3, w2, wgt, local, here, act):
     def add(acc, e):
         return acc + one(*e), None
     return lax.scan(add, jnp.zeros_like(x), (w1, w3, w2, cw.T))[0]
+
+
+# the ``checkpoint_name`` of the held experts' result [T, d] where it is
+# differentiated: ``distributed/recompute.py``'s named policies keep it, so
+# that a block that reads it again in its backward pass (a norm behind the
+# layer, ``models/afmoe.py``) does not run the conditional a third time
+# for it; where nothing reads it, nothing is kept
+EXPERTS_RESULT = "expert_layer_result"
+
+
+@functools.lru_cache(maxsize=None)
+def _traced_once(path, on_tpu, *static):
+    """``path`` with its last arguments ``static`` under ``jax.jit``, one
+    function object for each: every layer of a model, and in each the
+    forward conditional, its forward rule and the backward conditional,
+    then share one trace and one lowering of a path at given shapes, where
+    each would trace it anew (PERF.md section 6, PR 37). ``on_tpu``
+    only keys the cache: the path asks ``_on_tpu()`` itself when traced."""
+    def traced(*args):
+        return path(*args, *static)
+    traced.__name__ = path.__name__.strip("_")
+    return jax.jit(traced)
+
+
+def _paths(ladder, act, local, here, index):
+    """The ladder's conditional as functions of (x, w1, w3, w2, wgt): the
+    sorted path at each of ``ladder``'s sizes, the dense path last."""
+    def rung(rows):
+        run = _traced_once(_routed_sorted, _on_tpu(), rows, act)
+        return lambda *data: run(*data, here, index)
+    dense = _traced_once(_routed_dense, _on_tpu(), act)
+    return [rung(rows) for rows in ladder] + [
+        lambda *data: dense(*data, local, here)]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _laddered(ladder, act, x, w1, w3, w2, wgt, local, here, index):
+    """One conditional: the first of ``ladder``'s sizes that holds the
+    assignments that landed, the dense path past the last.
+
+    Differentiated as it stands, a conditional hands its backward pass
+    the union of its branches' residuals, and the branch taken writes
+    noughts for the others': the tight rung wrote and held what the wide
+    ones keep (on the chip 14 ms a step and 1.7 GiB in the SmallThinker
+    cell, where the rung saved 33; padding every rung's residuals to one
+    shape cost as much in copies; PERF.md section 6, PR 37). So this keeps
+    its inputs alone, and the backward pass is one conditional too, whose
+    branch runs its path again and then its transpose: nothing sized by a
+    rung crosses a conditional. In a recomputed block (every cell's) that
+    costs nothing: the result is named ``EXPERTS_RESULT`` and kept where
+    the backward pass reads it, so the recomputed forward conditional has
+    no reader and goes, and a path still runs twice a step. A layer
+    trained without recomputation runs its experts' forward twice where it
+    ran once, and keeps none of their residuals between the passes."""
+    return lax.switch(_rung(index[-1], ladder),
+                      _paths(ladder, act, local, here, index),
+                      x, w1, w3, w2, wgt)
+
+
+def _laddered_fwd(ladder, act, *args):
+    return checkpoint_name(_laddered(ladder, act, *args), EXPERTS_RESULT), \
+        args
+
+
+def _laddered_bwd(ladder, act, args, grad):
+    *data, local, here, index = args
+    pulls = [lambda *d, path=path: jax.vjp(path, *d)[1](grad)
+             for path in _paths(ladder, act, local, here, index)]
+    return (*lax.switch(_rung(index[-1], ladder), pulls, *data),
+            None, None, None)
+
+
+_laddered.defvjp(_laddered_fwd, _laddered_bwd)
+
+
+def _routed(x, w1, w3, w2, wgt, sel, offset, ladder, act):
+    """The part of the ``held`` experts w1, w3, w2, published as
+    ``offset .. offset + held``, for x [T, d] under the choice sel and
+    weights wgt [T, k], at the first of ``ladder``'s sizes that holds the
+    assignments that landed here, dense past the last."""
+    held = w1.shape[0]
+    local = sel - offset
+    here = (local >= 0) & (local < held)
+    w1, w3, w2 = (w.astype(x.dtype) for w in (w1, w3, w2))
+    return _laddered(ladder, act, x, w1, w3, w2, wgt, local, here,
+                     _sorted_index(local, here, held))
 
 
 class _Router(Layer):
@@ -446,6 +565,7 @@ class _Experts(Layer):
         self.activation = activation
         self.held, self.offset = int(held), int(offset)
         self.published, self.top_k = int(published), int(top_k)
+        self.ladder = None      # ``rows_ladder`` of the last call traced
 
         def stacked(shape):
             p = Parameter(init(shape, "float32"))
@@ -455,40 +575,44 @@ class _Experts(Layer):
         self.w3 = self.add_parameter("w3", stacked([held, d_model, d_expert]))
         self.w2 = self.add_parameter("w2", stacked([held, d_expert, d_model]))
 
-    def rows_bound(self, tokens: int) -> int:
-        """Sorted rows the grouped product is built for:
-        ``_ROWS_OVER_EVEN`` times what an even routing lands here, at
-        most what any routing can (each token's top-k are distinct
-        experts), in whole tiles."""
+    def rows_ladder(self, tokens: int) -> tuple:
+        """The sizes, in sorted rows, a call over ``tokens`` tokens may
+        run at, ascending: ``_ROWS_OVER_EVEN`` times what an even routing
+        lands here, each at most what any routing can (each token's top-k
+        are distinct experts) and in whole tiles; equal rungs are one. From
+        shapes alone."""
         worst = tokens * min(self.top_k, self.held)
         even = -(-tokens * self.top_k * self.held // self.published)
-        rows = min(worst, _ROWS_OVER_EVEN * even)
-        unit = _GMM_ROWS if rows >= _GMM_ROWS else 16
-        return -(-rows // unit) * unit
+
+        def whole(rows):
+            unit = _GMM_ROWS if rows >= _GMM_ROWS else 16
+            return -(-rows // unit) * unit
+        return tuple(sorted({whole(min(worst, math.ceil(share * even)))
+                             for share in _ROWS_OVER_EVEN}))
+
+    def rows_bound(self, tokens: int) -> int:
+        """The most sorted rows the grouped products are built for, the
+        ladder's last rung: what lands past it takes the dense path."""
+        return self.rows_ladder(tokens)[-1]
 
     def forward(self, x, sel, wgt):
         """x [T, d], sel and wgt [T, k] -> sum over the experts held
         here of wgt * expert(x), [T, d]."""
-        rows = self.rows_bound(int(x.shape[0]))
+        ladder = self.rows_ladder(int(x.shape[0]))
+        # static, of the shapes alone: ``note_load`` counts the call's rung
+        # by the ladder the call was built with
+        self.ladder = ladder   # tpulint: disable=traced-attr-mutation
         _last_moe.clear()
         _last_moe.update(
             kernel="megablox_gmm" if _on_tpu() else "xla_ragged_dot",
             experts_held=self.held, experts_published=self.published,
-            top_k=self.top_k, rows_bound=rows, activation=self.activation,
-            tiling={"w1_w3": _gmm_tiles(rows, *self.w1.shape[1:]),
-                    "w2": _gmm_tiles(rows, *self.w2.shape[1:])})
+            top_k=self.top_k, rows_ladder=ladder, rows_bound=ladder[-1],
+            activation=self.activation,
+            tiling={"w1_w3": _gmm_tiles(ladder[-1], *self.w1.shape[1:]),
+                    "w2": _gmm_tiles(ladder[-1], *self.w2.shape[1:])})
 
-        act = _GATES[self.activation]
-
-        def fn(xv, w1, w3, w2, wv, selv):
-            local = selv - self.offset
-            here = (local >= 0) & (local < self.held)
-            w1, w3, w2 = (w.astype(xv.dtype) for w in (w1, w3, w2))
-            return lax.cond(
-                jnp.sum(here) <= rows,
-                lambda: _routed_sorted(xv, w1, w3, w2, wv, local, here,
-                                       rows, act),
-                lambda: _routed_dense(xv, w1, w3, w2, wv, local, here, act))
+        fn = functools.partial(_routed, offset=self.offset, ladder=ladder,
+                               act=_GATES[self.activation])
         return _tape.apply(fn, x, self.w1, self.w3, self.w2, wgt, sel,
                            _op_name="moe_experts")
 
@@ -503,6 +627,18 @@ class TokenChoiceMoE(Layer):
     Assignments to experts held elsewhere add nothing here: with every
     share's output and the shared expert counted once, the shares add up
     to the whole layer. Nothing is dropped under any routing.
+
+    The experts run on the assignments that land here sorted by expert, at
+    a static number of rows chosen per call from a short ladder
+    (``experts.rows_ladder(tokens)``: 1.25 and 3 even shares of the
+    assignments, from shapes alone): the first rung that holds what
+    landed, and past the last (three shares: what a router trained from a
+    random start was seen to land, ``_ROWS_OVER_EVEN``) a dense path of
+    every held expert over every token. So the cost of the gathers, masks
+    and elementwise passes round the products follows the rung taken, the
+    count that landed alone chooses it, and the buffer ``rows_rung_total`` says how often
+    each was (one entry a rung, the dense path last; a running count of
+    calls that ``note_load`` adds to).
 
     ``score`` is the router's rule (``_Router``). The router reads what
     the experts read unless the caller routes apart: ``route(t)`` gives
@@ -524,7 +660,8 @@ class TokenChoiceMoE(Layer):
     """
 
     _fixed_dtype_buffers = frozenset({"expert_bias", "expert_load",
-                                      "expert_load_total"})
+                                      "expert_load_total",
+                                      "rows_rung_total"})
 
     def __init__(self, d_model, d_expert, num_experts, top_k,
                  experts_held=None, expert_offset=0, shared_expert=None,
@@ -548,6 +685,9 @@ class TokenChoiceMoE(Layer):
         for name in ("expert_load", "expert_load_total"):
             self.register_buffer(name, Tensor(
                 jnp.zeros((num_experts,), jnp.float32), stop_gradient=True))
+        self.register_buffer("rows_rung_total", Tensor(
+            jnp.zeros((len(_ROWS_OVER_EVEN) + 1,), jnp.float32),
+            stop_gradient=True))
 
     def route(self, x):
         """x [..., d_model] -> (sel [T, k], weights [T, k], counts
@@ -572,12 +712,22 @@ class TokenChoiceMoE(Layer):
         return T.reshape(y, list(x.shape)), counts
 
     def note_load(self, counts):
-        """Keep a step's counts and move the bias by them (no
-        gradient). Call it once a training step, outside any recomputed
-        region."""
+        """Keep a step's counts, count the rung its call took and move
+        the bias by them (no gradient). Call it once a training step,
+        outside any recomputed region."""
         c = counts.value if isinstance(counts, Tensor) else counts
         c = lax.stop_gradient(c).astype(jnp.float32)
         self.expert_load.value = c
         self.expert_load_total.value = self.expert_load_total.value + c
+        e = self.experts
+        if e.ladder is not None:
+            # the rung by the forward's own rule; where equal rungs were
+            # one the entries between stay nought and the last is still
+            # the dense path's
+            taken = _rung(jnp.sum(c[e.offset:e.offset + e.held]), e.ladder)
+            last = self.rows_rung_total.shape[0] - 1
+            self.rows_rung_total.value = self.rows_rung_total.value + \
+                jax.nn.one_hot(jnp.where(taken < len(e.ladder), taken, last),
+                               last + 1, dtype=jnp.float32)
         self.expert_bias.value = self.expert_bias.value + \
             self.bias_update_rate * jnp.sign(jnp.mean(c) - c)

@@ -21,6 +21,7 @@ from ..core.tensor import Tensor
 from ..jit.functional import functional_call, raw_state, _wrap
 from ..nn.functional.flash_attention import ATTENTION_RESIDUAL
 from ..nn.layer_base import Layer
+from .moe import EXPERTS_RESULT
 
 __all__ = ["recompute", "recompute_sequential"]
 
@@ -31,7 +32,11 @@ __all__ = ["recompute", "recompute_sequential"]
 # runs the forward kernel a second time: per kept byte they are the
 # dearest thing in a block to recompute (about s_k kernel operations a
 # byte at a third of a matmul's rate; a matmul's output costs H).
-# "full" keeps the block's input and that, and recomputes everything
+# They keep the token-choice expert layer's result too where the backward
+# pass reads it (moe.EXPERTS_RESULT, one [tokens, d] activation a layer):
+# its conditional runs its path again inside its own backward branch, so
+# a recomputed forward conditional would be a third run.
+# "full" keeps the block's input and those, and recomputes everything
 # else; "dots" keeps matmul outputs besides (recomputes only
 # elementwise/norm ops — trades HBM for a ~1/3 cut in recompute FLOPs).
 # To keep nothing but the block's input (least memory) hand in the
@@ -53,7 +58,7 @@ def resolve_checkpoint_policy(policy):
             f"recompute policy {policy!r} not in {sorted(_POLICIES)} "
             "(or pass a jax.checkpoint_policies callable)") from None
     cp = jax.checkpoint_policies
-    keep = cp.save_only_these_names(ATTENTION_RESIDUAL)
+    keep = cp.save_only_these_names(ATTENTION_RESIDUAL, EXPERTS_RESULT)
     return cp.save_from_both_policies(getattr(cp, name), keep) if name \
         else keep
 

@@ -255,20 +255,40 @@ def test_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(total, np.asarray(want), atol=5e-6)
     assert last_moe_dispatch() == {
         "kernel": "xla_ragged_dot", "experts_held": 2,
-        "experts_published": 8, "top_k": 2, "rows_bound": 96,
-        "tiling": {"w1_w3": (96, 32, 16), "w2": (96, 16, 32)},
+        "experts_published": 8, "top_k": 2, "rows_ladder": (48, 96),
+        "rows_bound": 96, "tiling": {"w1_w3": (96, 32, 16), "w2": (96, 16, 32)},
         "activation": "silu", "score": "sigmoid",
         "router_input": "expert_input"}
 
 
-@pytest.mark.parametrize("held,offset,bias,dense", [
-    (8, 0, (10., 9.), False), (4, 2, (10., 9.), False),
-    (2, 0, (10., 0.), False), (2, 0, (10., 9.), True)])
-def test_every_token_to_one_expert_drops_nothing(held, offset, bias, dense):
+@pytest.mark.parametrize("tokens,top_k,held,published,ladder", [
+    (16384, 6, 16, 64, (30720, 73728)),         # train-smallthinker-16k
+    (16384, 6, 16, 128, (15360, 36864)),        # train-kanana-2-8k
+    (16384, 8, 16, 128, (20480, 49152)),        # train-trinity-mini-8k
+    (60, 2, 2, 8, (48, 96)),
+    (60, 2, 4, 8, (80, 128)),       # 2 shares are every row it can land
+    (60, 2, 8, 8, (128,)),          # an uncut layer: one share is all
+    (4, 2, 2, 8, (16,))])
+def test_rows_ladder_from_shapes(tokens, top_k, held, published, ladder):
+    """1.25 and 3 even shares of the assignments in whole tiles, at most
+    what any routing lands; equal rungs are one, and the last is
+    ``rows_bound``."""
+    e = TokenChoiceMoE(8, 4, published, top_k, experts_held=held).experts
+    assert e.rows_ladder(tokens) == ladder
+    assert e.rows_bound(tokens) == ladder[-1]
+
+
+@pytest.mark.parametrize("held,offset,bias,rung", [
+    (8, 0, (10., 9.), 0), (4, 2, (10., 9.), 0), (2, 2, (10., 0.), 0),
+    (2, 1, (10., 9.), 1), (2, 0, (10., 0.), 1), (2, 0, (10., 9.), 2)])
+def test_every_token_to_one_expert_drops_nothing(held, offset, bias, rung):
     """A bias that sends every token to expert 0 (and to expert 1): on a
-    share of 2 that lands twice an even routing's rows, inside the sorted
-    rows, or four times, which the dense path takes; on the uncut layer
-    every row the layer has.
+    share of 2 that lands a part of an even routing's rows (experts 2 and
+    3: the first rung), twice them or somewhat more (experts 1 and 2;
+    experts 0 and 1 without the second bias: the last rung) or four
+    times, which the dense path takes; on the uncut layer every row the
+    layer has. The call runs at the first rung that holds what landed,
+    as ``rows_rung_total`` says.
     Output and every gradient equal the reference's: nothing is
     dropped."""
     p, x = _moe_leaves(1), _rand(2, 30, D_MODEL, seed=7)
@@ -277,10 +297,18 @@ def test_every_token_to_one_expert_drops_nothing(held, offset, bias, dense):
     xt = paddle.to_tensor(np.asarray(x))
     xt.stop_gradient = False
     y, counts = m(xt)
+    m.note_load(counts)
     counts = np.asarray(counts.value)
     assert counts[0] == 60 and counts.sum() == 120
     landed = counts[offset:offset + held].sum()
-    assert dense == (landed > m.experts.rows_bound(60))
+    ladder = m.experts.rows_ladder(60)
+    assert last_moe_dispatch()["rows_ladder"] == ladder
+    took = sum(landed > r for r in ladder)
+    assert (rung == 2) == (took == len(ladder)) == (
+        landed > m.experts.rows_bound(60))
+    assert rung == 2 or rung == took
+    assert np.array_equal(np.asarray(m.rows_rung_total.value),
+                          np.eye(3, dtype="f4")[rung])
     sl = slice(offset, offset + held)
 
     def ref(xv, rw, w1, w3, w2):
@@ -300,15 +328,17 @@ def test_every_token_to_one_expert_drops_nothing(held, offset, bias, dense):
                                    rtol=1e-5, atol=2e-4)
 
 
-@pytest.mark.parametrize("held,offset", [(8, 0), (4, 2)])
+@pytest.mark.parametrize("held,offset", [(8, 0), (4, 2), (2, 2)])
 def test_expert_layer_under_checkpoint_gives_the_same_gradients(held,
                                                                 offset):
     """The layer is a function of values (the counts come out, nothing
     goes through a side channel): under ``jax.checkpoint`` it traces and
-    gives the gradients it gives without."""
+    gives the gradients it gives without; with one rung and with two
+    beside the dense path in one conditional."""
     from paddle_tpu.jit.functional import functional_call, raw_state
     p, x = _moe_leaves(2), _rand(2, 24, D_MODEL, seed=8)
     m = _share(p, _rand(E, seed=9, scale=0.1), held, offset, shared=True)
+    assert len(m.experts.rows_ladder(48)) == {8: 1, 4: 2, 2: 2}[held]
     params, buffers = raw_state(m)
 
     def loss(params, x):
@@ -324,6 +354,71 @@ def test_expert_layer_under_checkpoint_gives_the_same_gradients(held,
                                    atol=1e-5)
 
 
+def _conds(jaxpr):
+    """Every ``cond`` equation of a jaxpr, those inside others too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _conds(sub)
+
+
+def test_nothing_sized_by_a_rung_crosses_the_conditional():
+    """Differentiated as it stands, the ladder's conditional would hand
+    its backward pass every rung's residuals (each branch writes noughts
+    for the others': on the chip, 14 ms a step and 1.7 GiB in the
+    SmallThinker cell). The layer's conditional keeps its inputs alone:
+    in the gradient of a recomputed layer every conditional (the
+    forward's, its recomputed copy, which has no reader and which the
+    compiler drops, and the backward's) has rungs + 1 branches and none
+    hands on an array as long as a rung."""
+    from paddle_tpu.jit.functional import functional_call, raw_state
+    m = _share(_moe_leaves(2), jnp.zeros((E,)), 2, 0)
+    params, buffers = raw_state(m)
+    x = _rand(2, 30, D_MODEL, seed=8)
+    ladder = m.experts.rows_ladder(60)
+    assert len(ladder) == 2 and not set(ladder) & {60, 120, D_MODEL, D_EXP}
+
+    def loss(params, x):
+        (y, _), _ = functional_call(m, params, buffers, x)
+        return (y ** 2).sum()
+    conds = list(_conds(jax.make_jaxpr(jax.grad(jax.checkpoint(loss),
+                                                (0, 1)))(params, x).jaxpr))
+    assert [len(c.params["branches"]) for c in conds] == [3, 3, 3]
+    for c in conds:
+        assert not any(set(v.aval.shape) & set(ladder) for v in c.outvars)
+
+
+@pytest.mark.parametrize("policy,conditionals", [
+    ("full", 2), ("dots", 2), ("nothing_saveable", 3)])
+def test_a_block_that_reads_the_result_keeps_it(policy, conditionals):
+    """The layer's conditional runs its path again inside its backward
+    branch. A block whose backward pass reads the layer's result (a norm
+    behind it, as in ``models/afmoe.py``) would run it a third time in
+    the recomputed forward; the named policies keep the result
+    (``moe.EXPERTS_RESULT``), so the compiled gradient holds the forward's
+    conditional and the backward's and no other."""
+    from paddle_tpu.distributed.moe import EXPERTS_RESULT
+    from paddle_tpu.distributed.recompute import resolve_checkpoint_policy
+    from paddle_tpu.jit.functional import functional_call, raw_state
+    m = _share(_moe_leaves(2), jnp.zeros((E,)), 2, 0)
+    params, buffers = raw_state(m)
+    x = _rand(2, 30, D_MODEL, seed=8)
+    policy = getattr(jax.checkpoint_policies, policy, None) \
+        or resolve_checkpoint_policy(policy)
+
+    def block(params, x):
+        (y, _), _ = functional_call(m, params, buffers, x)
+        return x + y * jax.lax.rsqrt((y * y).mean(-1, keepdims=True) + 1.0)
+
+    def loss(params, x):
+        return (jax.checkpoint(block, policy=policy)(params, x) ** 2).sum()
+    grad = jax.jit(jax.grad(loss, (0, 1)))
+    assert EXPERTS_RESULT in str(jax.make_jaxpr(grad)(params, x))
+    text = grad.lower(params, x).compile().as_text()
+    assert text.count(" conditional(") == conditionals
+
+
 def test_note_load_keeps_counts_and_moves_the_bias():
     m = _share(_moe_leaves(), jnp.zeros((E,)), 8, 0)
     c = jnp.asarray([5., 1., 3., 3., 0., 9., 3., 0.])
@@ -335,6 +430,30 @@ def test_note_load_keeps_counts_and_moves_the_bias():
     np.testing.assert_allclose(
         np.asarray(m.expert_bias.value),
         2e-3 * np.asarray([-1, 1, 0, 0, 1, -1, 0, 1], "f4"))
+
+
+def test_note_load_counts_one_rung_a_call():
+    """Every call adds one to one entry of ``rows_rung_total``, the rung
+    the forward took by the same rule (the dense path last), so the
+    entries sum to the steps; before any call there is no ladder to count
+    by and the buffer stands."""
+    p = _moe_leaves(3)
+    m = _share(p, jnp.zeros((E,)), 2, 0)
+    m.note_load(jnp.ones((E,)))
+    assert not np.asarray(m.rows_rung_total.value).any()
+    want = np.zeros(3, "f4")
+    for step, bias in enumerate([(0., 0.), (10., 0.), (10., 9.), (0., 0.),
+                                 (-10., -10.)]):
+        m.expert_bias.value = jnp.asarray(bias + (0.,) * 6, jnp.float32)
+        y, counts = m(paddle.to_tensor(np.asarray(
+            _rand(2, 30, D_MODEL, seed=20 + step))))
+        m.note_load(counts)
+        landed = float(np.asarray(counts.value)[:2].sum())
+        took = sum(landed > r for r in (48, 96))
+        want[took] += 1
+        got = np.asarray(m.rows_rung_total.value)
+        assert np.array_equal(got, want) and got.sum() == step + 1
+    assert want.all()           # the tight rung, the last, the dense path
 
 
 def test_moe_layer_refuses_experts_not_published():

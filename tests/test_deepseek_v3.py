@@ -225,6 +225,9 @@ def test_moe_dispatch_says_the_tiles():
     assert rec["tiling"] == {"w1_w3": moe._gmm_tiles(rec["rows_bound"], 64,
                                                      48),
                              "w2": moe._gmm_tiles(rec["rows_bound"], 48, 64)}
+    # 32 tokens' top-2 with 4 of 8 held: 1.25 even shares in whole tiles,
+    # and every row the share can land, which is 2
+    assert rec["rows_ladder"] == (48, 64) and rec["rows_bound"] == 64
 
 
 # ----------------------------------------------------------------- model

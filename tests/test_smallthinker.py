@@ -198,6 +198,10 @@ def test_four_shares_add_up_to_the_uncut_layer():
         rec = moe.last_moe_dispatch()
         assert (rec["activation"], rec["score"], rec["router_input"]) == (
             "relu", "softmax_of_chosen", "given")
+        # 60 tokens' top-3 with 2 of 8 held: 1.25 and (every row the
+        # share can land) 2.67 even shares of 45, in whole tiles
+        assert rec["rows_ladder"] == (64, 128)
+        assert rec["rows_bound"] == 128
     np.testing.assert_allclose(total, np.asarray(want), atol=5e-6)
     assert float(np.abs(np.asarray(want)).max()) > 1e-3
 

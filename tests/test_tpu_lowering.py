@@ -306,14 +306,16 @@ def _library_flash_head_major_grouped(window):
              _sd((1, 4, 16384, 128), BF16)], 2)
 
 
-def _grouped_experts(width=1024, top_k=8, rows=49152, hidden=2048,
+def _grouped_experts(width=1024, top_k=8, rows=20480, hidden=2048,
                      gate="silu"):
     """distributed/moe.py's sorted path at one chip's share of the
     published experts: 16,384 tokens, ``top_k`` experts a token, 16 held
-    of ``width``, ``rows`` sorted rows through the library's megablox
-    kernels, forward and backward. Trinity-Mini's share by default; the
-    deepseek_v3 cell's at width 768, top-6, 36,864 rows; the SmallThinker
-    cell's at hidden 2560, width 768, top-6, 73,728 rows, ReLU gate."""
+    of ``width``, through the library's megablox kernels, forward and
+    backward, at ``rows`` sorted rows: the ladder's rung that the cell's
+    even routing takes, 1.25 even shares. Trinity-Mini's share by default;
+    the deepseek_v3 cell's at width 768, top-6, 15,360 rows; the
+    SmallThinker cell's at hidden 2560, width 768, top-6, 30,720 rows,
+    ReLU gate."""
     import importlib
     moe = importlib.import_module("paddle_tpu.distributed.moe")
 
@@ -321,12 +323,16 @@ def _grouped_experts(width=1024, top_k=8, rows=49152, hidden=2048,
         with mock.patch.object(moe, "_on_tpu", lambda: True):
             here = sel < 16
             return moe._routed_sorted(
-                x, w1, w3, w2, wgt, sel, here, rows,
-                moe._GATES[gate]).astype(jnp.float32).sum()
+                x, w1, w3, w2, wgt, here, moe._sorted_index(sel, here, 16),
+                rows, moe._GATES[gate]).astype(jnp.float32).sum()
     return (jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
-            [_sd((16384, hidden), BF16), _sd((16, hidden, width), BF16),
-             _sd((16, hidden, width), BF16), _sd((16, width, hidden), BF16),
-             _sd((16384, top_k), F32), _sd((16384, top_k), I32)], 9)
+            _expert_share_args(width, top_k, hidden), 9)
+
+
+def _expert_share_args(width, top_k, hidden):
+    return [_sd((16384, hidden), BF16), _sd((16, hidden, width), BF16),
+            _sd((16, hidden, width), BF16), _sd((16, width, hidden), BF16),
+            _sd((16384, top_k), F32), _sd((16384, top_k), I32)]
 
 
 def _slot_write(dtype, tail):
@@ -399,13 +405,13 @@ CHIP_COMPILE_CASES = {
         (2, 8192, 32, 128), kv_heads=4),
     "library_flash_mla_192_128_8k": _library_flash_mla,
     "grouped_experts_trinity_share": _grouped_experts,
-    "grouped_experts_kanana_share": lambda: _grouped_experts(768, 6, 36864),
+    "grouped_experts_kanana_share": lambda: _grouped_experts(768, 6, 15360),
     "library_flash_head_major_gqa7_window_16k":
         lambda: _library_flash_head_major_grouped(4096),
     "library_flash_head_major_gqa7_full_16k":
         lambda: _library_flash_head_major_grouped(None),
     "grouped_experts_smallthinker_share": lambda: _grouped_experts(
-        768, 6, 73728, hidden=2560, gate="relu"),
+        768, 6, 30720, hidden=2560, gate="relu"),
     "fused_slot_write_bf16": lambda: _slot_write(BF16, (_H, _D)),
     "fused_slot_write_int8": lambda: _slot_write(I8, (_H, _D)),
     "fused_slot_write_scale": lambda: _slot_write(F32, (_H,)),
@@ -433,6 +439,72 @@ def test_compiles_for_v5e(case, one_chip, chip_like_config):
         fn = jax.jit(fn)
     compiled = fn.lower(*_abstract(args, one_chip)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= n_kernels
+
+
+def _conditionals(text):
+    """A compiled program's conditionals, each as its branches' names, and
+    ``reached(name)``: a computation and whatever it calls, as
+    {name: body}."""
+    blocks = dict(re.findall(r"^(?:ENTRY )?%([\w.\-]+) [^\n]*\{\n(.*?)^\}",
+                             text, re.M | re.S))
+
+    def reached(name, seen=None):
+        seen = {} if seen is None else seen
+        if name not in seen and name in blocks:
+            seen[name] = blocks[name]
+            for callee in re.findall(
+                    r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
+                    blocks[name]):
+                reached(callee, seen)
+        return seen
+    conds = [[b.strip().lstrip("%") for b in c.split(",")] for c in re.findall(
+        r"conditional\([^\n]*branch_computations=\{([^}]*)\}", text)]
+    return conds, reached, blocks
+
+
+def test_expert_ladder_is_one_conditional_for_v5e(one_chip,
+                                                  chip_like_config):
+    """The SmallThinker cell's expert share as the layer calls it.
+    Forward: the compiled program holds ONE conditional, of the ladder's
+    two rungs and the dense path, three grouped products a rung, and
+    the index work (the stable sort by expert) outside its branches,
+    where it waits for the routing alone. (The sorts left inside are the
+    compiler's own, of each rung's scatter-add.) The gradient of the
+    recomputed share: TWO, the forward's and the backward's, whose rung
+    runs its three products again and their six transposes; the
+    recomputed forward's has no reader and is gone."""
+    import importlib
+    moe = importlib.import_module("paddle_tpu.distributed.moe")
+    ladder = (30720, 73728)
+    args = _abstract(_expert_share_args(768, 6, 2560), one_chip)
+
+    def part(*args):
+        return moe._routed(*args, offset=0, ladder=ladder,
+                           act=moe._GATES["relu"])
+
+    def compiled(fn):
+        # round the whole trace: the backward conditional is traced when
+        # the gradient is, after ``part`` has returned
+        with mock.patch.object(moe, "_on_tpu", lambda: True):
+            return jax.jit(fn).lower(*args).compile().as_text()
+
+    def kernels(branch):
+        return sum(body.count('custom_call_target="tpu_custom_call"')
+                   for body in reached(branch).values())
+    conds, reached, blocks = _conditionals(compiled(part))
+    assert [len(c) for c in conds] == [len(ladder) + 1]
+    inside = set().union(*(reached(b) for b in conds[0]))
+    sorts = {name for name, body in blocks.items()
+             if re.search(r" sort\([^\n]*argsort", body)}
+    assert sorts and not sorts & inside
+    assert [kernels(b) for b in conds[0]] == [3, 3, 0]
+
+    grad = jax.grad(jax.checkpoint(
+        lambda *a: (part(*a).astype(jnp.float32) ** 2).sum()),
+        argnums=(0, 1, 2, 3, 4))
+    conds, reached, _ = _conditionals(compiled(grad))
+    assert sorted([kernels(b) for b in c] for c in conds) == [
+        [3, 3, 0], [9, 9, 0]]
 
 
 # -- whole train steps (slow: minutes of compile each, and tier-1 has a
