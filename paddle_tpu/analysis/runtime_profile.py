@@ -391,8 +391,11 @@ def read_scope(path: str, instruction: str = "") -> dict:
     is the HLO instruction's own name where the caller has it: a Pallas
     kernel that was given a name is entered through a scope of that name
     (the library's wrapper may open it once more) and its instruction
-    carries the name too, so a scope equal to it (less the ``.N``) names
-    the kernel and no layer.
+    carries the name too, so on a kernel's path (one that ends in
+    ``pallas_call``) a scope equal to it (less the ``.N``) names the
+    kernel and no layer. Any other instruction keeps a scope of its own
+    name: XLA names a ``sort`` instruction ``sort.N`` whatever made it,
+    and the expert layer's ``sort`` scope holds two.
 
     Pass: ``update`` if ``optimizer`` or ``grad_accumulate`` is on the
     path, else ``recompute`` if ``rematted_computation``, else
@@ -418,7 +421,8 @@ def read_scope(path: str, instruction: str = "") -> dict:
     every norm) groups by ``scope``, which keeps the whole path."""
     raw = _split_path(path.split(";", 1)[0])
     tail, raw = raw[-1], raw[:-1]                   # the primitive
-    own = re.sub(r"(\.\d+)+$", "", instruction.lstrip("%")) or None
+    own = (re.sub(r"(\.\d+)+$", "", instruction.lstrip("%")) or None) \
+        if tail == "pallas_call" else None
     scopes: List[str] = []      # the program's scopes, outermost first
     loops = set()               # indices into `scopes` that hold a loop
     backward = recompute = False

@@ -359,13 +359,14 @@ def _sorted_index(local, here, held):
     assignment stands in that order, ``sizes`` [held], the rows of each
     expert's group, and ``landed``, their sum. Made once a call, outside
     the conditional, so that it waits for nothing but the routing."""
-    key = jnp.where(here, local, held).reshape(-1)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    bounds = jnp.searchsorted(key[order], jnp.arange(held + 1,
-                                                     dtype=key.dtype))
-    sizes = jnp.diff(bounds).astype(jnp.int32)
-    pos = jnp.argsort(order).astype(jnp.int32).reshape(here.shape)
-    return order, pos, sizes, bounds[held]
+    with jax.named_scope("sort"):
+        key = jnp.where(here, local, held).reshape(-1)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        bounds = jnp.searchsorted(key[order], jnp.arange(held + 1,
+                                                         dtype=key.dtype))
+        sizes = jnp.diff(bounds).astype(jnp.int32)
+        pos = jnp.argsort(order).astype(jnp.int32).reshape(here.shape)
+        return order, pos, sizes, bounds[held]
 
 
 def _routed_sorted(x, w1, w3, w2, wgt, here, index, rows, act):
@@ -375,22 +376,27 @@ def _routed_sorted(x, w1, w3, w2, wgt, here, index, rows, act):
     ``rows``."""
     order, pos, sizes, landed = index
     T, k = here.shape
-    # where each assignment stands in the sorted order; `rows` (the row
-    # of noughts) for one that is held elsewhere
-    pos = jnp.where(here & (pos < rows), pos, rows)
-    slot = jnp.pad(order, (0, max(0, rows - T * k)))[:rows]
-    tok = slot // k
-    dot = functools.partial(_grouped_dot, sizes=sizes)
-    # rows past the groups are written by no product, forward or
-    # backward, and hold whatever the buffer held: nought on both sides
-    live = (jnp.arange(rows) < landed)[:, None]
-    y = _glu(jnp.where(live, x[tok], 0), w1, w3, w2, dot, act)
-    y = jnp.where(live, y, 0)
-    y = y * _sorted_weights(wgt, slot, pos)[:, None].astype(y.dtype)
-    # combine: each row back to its token. (The chip adds rows of the
-    # model width one at a time, 3.0 ms for 32,768 of them, PERF.md PR 30;
-    # a gather of [T, k] rows with a sum over k takes 6.1 ms.)
-    return jnp.zeros_like(x).at[tok].add(y)
+    with jax.named_scope("dispatch"):
+        # where each assignment stands in the sorted order; `rows` (the
+        # row of noughts) for one that is held elsewhere
+        pos = jnp.where(here & (pos < rows), pos, rows)
+        slot = jnp.pad(order, (0, max(0, rows - T * k)))[:rows]
+        tok = slot // k
+        # rows past the groups are written by no product, forward or
+        # backward, and hold whatever the buffer held: nought on both
+        # sides
+        live = (jnp.arange(rows) < landed)[:, None]
+        rows_in = jnp.where(live, x[tok], 0)
+    with jax.named_scope("products"):
+        y = _glu(rows_in, w1, w3, w2,
+                 functools.partial(_grouped_dot, sizes=sizes), act)
+    with jax.named_scope("combine"):
+        y = jnp.where(live, y, 0)
+        y = y * _sorted_weights(wgt, slot, pos)[:, None].astype(y.dtype)
+        # each row back to its token. (The chip adds rows of the model
+        # width one at a time, 3.0 ms for 32,768 of them, PERF.md PR 30;
+        # a gather of [T, k] rows with a sum over k takes 6.1 ms.)
+        return jnp.zeros_like(x).at[tok].add(y)
 
 
 def _rung(landed, ladder):
@@ -405,13 +411,21 @@ def _routed_dense(x, w1, w3, w2, wgt, local, here, act):
     choose it). ``held`` times the work; the path of a routing that lands
     more here than the sorted rows hold."""
     held = w1.shape[0]
-    cw = jnp.einsum("tk,tke->te", jnp.where(here, wgt, 0),
-                    jax.nn.one_hot(local, held, dtype=wgt.dtype))
-    one = jax.checkpoint(lambda a, b, c, w: w[:, None].astype(x.dtype)
-                         * _glu(x, a, b, c, jnp.dot, act))
+    with jax.named_scope("dispatch"):
+        cw = jnp.einsum("tk,tke->te", jnp.where(here, wgt, 0),
+                        jax.nn.one_hot(local, held, dtype=wgt.dtype))
+
+    @jax.checkpoint
+    def one(a, b, c, w):
+        with jax.named_scope("products"):
+            y = _glu(x, a, b, c, jnp.dot, act)
+        with jax.named_scope("combine"):
+            return w[:, None].astype(x.dtype) * y
 
     def add(acc, e):
-        return acc + one(*e), None
+        y = one(*e)
+        with jax.named_scope("combine"):
+            return acc + y, None
     return lax.scan(add, jnp.zeros_like(x), (w1, w3, w2, cw.T))[0]
 
 

@@ -38,7 +38,7 @@ from ..framework import random as _rng
 from ..jit.functional import (EXPORT_DISABLED_CHECKS, functional_call,
                               load_state, raw_state, _wrap)
 from ..jit.training import (TrainStep, _raw_tuple, op_scopes_of,
-                            remember_trace)
+                            publish_step_program)
 from ..obs.trace import span as _span
 from . import mesh as mesh_mod
 
@@ -168,10 +168,10 @@ class ParallelTrainStep:
         self._scan_progs = {}
         # trace-time program counter (same contract as jit.TrainStep)
         self._trace_count = 0
-        # what `op_scopes` lowers, and its last table
-        # (jit.training.remember_trace / op_scopes_of)
-        self._last_traced = None
-        self._op_scopes = None
+        # `_trace_count` at the last record of its program, and that
+        # record (jit.training.publish_step_program / op_scopes_of)
+        self._published_at = 0
+        self._step_program = None
         # LR-scheduler ownership knob, honored by BOTH __call__ and
         # scan_steps (same contract as jit.TrainStep.auto_lr_step):
         # False = an external owner steps the schedule between calls
@@ -681,8 +681,7 @@ class ParallelTrainStep:
         if k == 1:
             def full_step(params, buffers, opt_state, lr, step_no, rng_key,
                           *batch):
-                remember_trace(step_self, "_jitted", params, buffers,
-                               opt_state, lr, step_no, rng_key, *batch)
+                step_self._count_trace()
                 loss, new_bufs, grads = fwd_bwd(params, buffers, lr, step_no,
                                                 rng_key, *batch)
                 with jax.named_scope("optimizer"):
@@ -710,8 +709,7 @@ class ParallelTrainStep:
 
         def acc_step(params, buffers, opt_state, acc, lr, step_no, rng_key,
                      *batch):
-            remember_trace(step_self, "_jitted_acc", params, buffers,
-                           opt_state, acc, lr, step_no, rng_key, *batch)
+            step_self._count_trace()
             loss, new_bufs, grads = fwd_bwd(params, buffers, lr, step_no,
                                             rng_key, *batch)
             with jax.named_scope("grad_accumulate"):
@@ -720,8 +718,7 @@ class ParallelTrainStep:
 
         def apply_step(params, buffers, opt_state, acc, lr, step_no, rng_key,
                        *batch):
-            remember_trace(step_self, "_jitted", params, buffers,
-                           opt_state, acc, lr, step_no, rng_key, *batch)
+            step_self._count_trace()
             loss, new_bufs, grads = fwd_bwd(params, buffers, lr, step_no,
                                             rng_key, *batch)
             with jax.named_scope("grad_accumulate"):
@@ -823,20 +820,22 @@ class ParallelTrainStep:
                 step_no = jnp.asarray(
                     self.update_count + (1 if micro else 0), jnp.float32)
             with _span("train.step.enqueue", cat="train", step=n):
+                prog = self._jitted_acc if micro else self._jitted
+                args = (self.params, self.buffers, self.opt_state,
+                        *((self.acc_grads,) if k > 1 else ()),
+                        lr, step_no, rng_key, *raw_batch)
                 if micro:
-                    loss, self.buffers, self.acc_grads = self._jitted_acc(
-                        self.params, self.buffers, self.opt_state,
-                        self.acc_grads, lr, step_no, rng_key, *raw_batch)
+                    loss, self.buffers, self.acc_grads = prog(*args)
                 elif k > 1:
                     (loss, self.params, self.buffers, self.opt_state,
-                     self.acc_grads) = self._jitted(
-                        self.params, self.buffers, self.opt_state,
-                        self.acc_grads, lr, step_no, rng_key, *raw_batch)
+                     self.acc_grads) = prog(*args)
                 else:
                     (loss, self.params, self.buffers,
-                     self.opt_state) = self._jitted(
-                        self.params, self.buffers, self.opt_state, lr,
-                        step_no, rng_key, *raw_batch)
+                     self.opt_state) = prog(*args)
+            if self._trace_count != self._published_at:     # it compiled
+                publish_step_program(
+                    self, "accumulate" if micro else "step", prog, args)
+            del args        # the donated arrays
             with _span("train.step.post", cat="train", step=n):
                 lr_sched = getattr(self.optimizer, "_learning_rate", None)
                 if not micro and self.auto_lr_step \
@@ -957,16 +956,19 @@ class ParallelTrainStep:
             with _span("train.step.enqueue", cat="train", step=n), \
                     _quiet_unused_donation():
                 if self.accumulate_steps > 1:
+                    args = (self.params, self.buffers, self.opt_state,
+                            self.acc_grads, base_key, lrs, step_nos, counts,
+                            upd, *raw_batch)
                     (losses, self.params, self.buffers, self.opt_state,
-                     self.acc_grads) = prog(
-                        self.params, self.buffers, self.opt_state,
-                        self.acc_grads, base_key, lrs, step_nos, counts,
-                        upd, *raw_batch)
+                     self.acc_grads) = prog(*args)
                 else:
+                    args = (self.params, self.buffers, self.opt_state,
+                            base_key, lrs, step_nos, counts, *raw_batch)
                     (losses, self.params, self.buffers,
-                     self.opt_state) = prog(
-                        self.params, self.buffers, self.opt_state,
-                        base_key, lrs, step_nos, counts, *raw_batch)
+                     self.opt_state) = prog(*args)
+                if self._trace_count != self._published_at:
+                    publish_step_program(self, "scan", prog, args)
+                del args
         # one stacked-loss scan per WINDOW when the nan flag is armed —
         # the fused loop's supervision cost is 1 sync / K steps
         # (check_numerics takes the raw jax array, same as __call__)

@@ -8,9 +8,10 @@ from .api import (InputSpec, StaticFunction, TranslatedLayer, ignore_module,
                   load, not_to_static, save, to_static, enable_to_static,
                   set_verbosity, set_code_level)
 from .functional import functional_call, load_state, raw_state
-from .training import TrainStep
+from .training import StepProgram, TrainStep, last_step_program
 
 __all__ = ["to_static", "not_to_static", "ignore_module", "InputSpec",
            "StaticFunction", "save", "load", "TranslatedLayer",
            "functional_call", "raw_state", "load_state", "TrainStep",
+           "StepProgram", "last_step_program",
            "enable_to_static", "set_verbosity", "set_code_level"]

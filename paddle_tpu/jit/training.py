@@ -13,6 +13,7 @@ over a Mesh.
 from __future__ import annotations
 
 import contextlib
+import time
 import warnings
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -28,7 +29,7 @@ from ..framework import random as _rng
 from ..obs.trace import span as _span
 from .functional import functional_call, load_state, raw_state, _wrap
 
-__all__ = ["TrainStep"]
+__all__ = ["TrainStep", "StepProgram", "last_step_program"]
 
 
 def _as_tuple(x):
@@ -210,45 +211,161 @@ def make_scan_window(fwd, optimizer, k, on_trace, post_update=None):
     return scan_window
 
 
-def remember_trace(step, attr: str, *args) -> None:
-    """Called inside a per-step program's traced body (so once per
-    actual trace): tick the step's trace count and remember which
-    program traced (the attribute that holds it) and at which shapes,
-    as ``ShapeDtypeStruct``s. `op_scopes_of` lowers from them; nothing
-    else reads them. ``step`` is a :class:`TrainStep` or a
-    ``distributed.ParallelTrainStep``."""
-    step._trace_count += 1
-    step._last_traced = (attr, jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args))
+class StepProgram:
+    """What a trainer knows of the per-step program it compiled last,
+    as host data: ``program`` ("step" | "accumulate" | "scan"),
+    ``trainer`` (the class's name), ``traces`` (the trainer's
+    ``_trace_count`` when the program had traced), ``publish_s`` (host
+    seconds the compiling call spent making this record, nearly all of
+    it the runtime's copy of the module) and the optimized HLO module,
+    from which `hlo_text` and `op_scopes` are made when first asked and
+    kept.
+
+    It holds no trainer, model, optimizer, parameter, buffer, device
+    array or executable: dropping the trainer frees what it held, and
+    the record goes on answering."""
+
+    __slots__ = ("program", "trainer", "traces", "publish_s", "_modules",
+                 "_text", "_table")
+
+    def __init__(self, program: str, trainer: str, traces: int, modules,
+                 publish_s: float = 0.0):
+        self.program, self.trainer, self.traces = program, trainer, int(traces)
+        self.publish_s = publish_s
+        self._modules, self._text, self._table = modules, None, None
+
+    def hlo_text(self) -> str:
+        """The optimized module as text, ``op_name`` metadata and all."""
+        if self._text is None:
+            self._text = "\n\n".join(m.to_string() for m in self._modules)
+            self._modules = None
+        return self._text
+
+    def op_scopes(self) -> Dict[str, str]:
+        """{HLO instruction name: ``op_name`` path}
+        (``analysis.runtime_profile.hlo_op_scopes`` of `hlo_text`):
+        the table that maps a device operation in a profiler's trace to
+        the scope of the program it came from (`read_scope` reads a
+        path, `by_scope` sums a trace by it). Parsed on the first call
+        (1.2 - 1.9 s for the 32,000 - 49,000 instructions of the
+        benchmark's unrolled sparse steps), kept for the next."""
+        if self._table is None:
+            from ..analysis.runtime_profile import hlo_op_scopes
+            self._table = hlo_op_scopes(self.hlo_text())
+        return dict(self._table)
+
+    def __repr__(self):
+        return (f"StepProgram(program={self.program!r}, "
+                f"trainer={self.trainer!r}, traces={self.traces})")
+
+
+# the record of the per-step program that compiled last, in any trainer
+# of this process (as ``F.last_attention_dispatch()`` for attention and
+# ``last_moe_dispatch()`` for the expert layer)
+_last_program: Optional[StepProgram] = None
+
+
+def last_step_program() -> Optional[StepProgram]:
+    """The `StepProgram` of the per-step program (of a `TrainStep`, a
+    ``distributed.ParallelTrainStep`` or either's ``scan_steps``) that
+    compiled last in this process; ``None`` before any has. It outlives
+    its trainer: a reader that holds a profiler's trace of the step and
+    no trainer (the benchmark's readers after ``Session.release()``, an
+    operator with a capture of ``/admin/trace?profile=1``) breaks the
+    trace down by the program's own scopes through it. A second program
+    replaces the first; no history is kept."""
+    return _last_program
+
+
+def _aval_of(x):
+    """The abstract value a jit call keyed its caches on for ``x``: a
+    committed array's sharding is part of the key, an uncommitted
+    one's is not. Read from a donated (deleted) array as from a live
+    one: shape, type and sharding outlive the buffer."""
+    if not isinstance(x, jax.Array):            # numpy, a Python scalar
+        x = np.asarray(x)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype)
+    # (a typed PRNG key array has neither attribute of its own)
+    return jax.ShapeDtypeStruct(
+        x.shape, x.dtype, weak_type=getattr(x, "weak_type", False),
+        sharding=x.sharding if getattr(x, "committed", False) else None)
+
+
+def _executable_that_ran(step, prog, args):
+    """The ``jax.stages.Compiled`` that a call of ``prog`` with ``args``
+    has just run, found without compiling or loading anything; raises
+    where it cannot be.
+
+    A program `TrainStep.warm` installed (an ``AotProgram`` that has not
+    fallen back to its jit wrapper) holds it. A jit wrapper keeps it in
+    its own caches: after a call, ``prog.lower(avals)`` at that call's
+    abstract values re-runs no Python of the step and returns the
+    lowering the call compiled, executable and all (one sub-millisecond
+    ``jaxpr_trace`` event fires for the cache lookup). Where the lookup
+    misses instead (the trace hook ticked, or the lowering holds no
+    executable: an argument this rule reads otherwise than jit did),
+    nothing is compiled here for the record's sake."""
+    from ..compilation.store import AotProgram
+    if isinstance(prog, AotProgram):
+        if not prog._use_fallback:
+            return prog.compiled
+        prog = prog.fallback
+    count = step._trace_count
+    lowered = prog.lower(*jax.tree_util.tree_map(_aval_of, tuple(args)))
+    if step._trace_count != count:
+        raise LookupError("the step traced again at the call's abstract "
+                          "values: jit's caches were keyed otherwise")
+    if getattr(getattr(lowered, "_lowering", None), "_executable",
+               None) is None:
+        raise LookupError("jit holds no executable for this lowering")
+    return lowered.compile()
+
+
+def publish_step_program(step, program: str, prog, args=()) -> None:
+    """Make ``step``'s `StepProgram` of the program ``prog`` that has
+    just compiled (in a call with ``args``, or in `TrainStep.warm`), and
+    put it where `last_step_program` finds it. Called by the trainers
+    after the enqueue of the one call on which ``_trace_count`` moved;
+    every other call pays one integer comparison.
+
+    No trace, lowering, compile or load (`_executable_that_ran`); what
+    costs is the copy of the executable's ``hlo_modules()`` out of the
+    runtime, made here, once: 0.04 - 0.63 s on the chip for the
+    benchmark's steps (PERF.md section 6, PR 38). A failure here never
+    fails the training step, and is not tried again."""
+    global _last_program
+    t0 = time.perf_counter()
+    try:
+        modules = _executable_that_ran(
+            step, prog, args).runtime_executable().hlo_modules()
+    except Exception as e:      # noqa: BLE001 — observability only
+        warnings.warn(f"{type(step).__name__}: the step program's record "
+                      f"was not made ({type(e).__name__}: {e})")
+        return
+    finally:
+        step._published_at = step._trace_count      # asked once
+    step._step_program = _last_program = StepProgram(
+        program, type(step).__name__, step._trace_count, modules,
+        time.perf_counter() - t0)
 
 
 def op_scopes_of(step) -> Dict[str, str]:
     """{HLO instruction name: ``op_name`` path} of the per-step program
-    that traced last, at the shapes it traced at: the table that maps a
-    device operation in a profiler's trace to the scope of the program
-    it came from (``analysis.runtime_profile``: `read_scope` reads a
-    path, `by_scope` sums a trace by it).
+    of ``step`` that compiled last, at the shapes it compiled at:
+    `StepProgram.op_scopes` of the record the trainer published on that
+    call, which is the one source of the table.
 
-    Costs nothing until called. Called, it costs one trace of the step
-    and one compile, which the persistent compilation cache turns into
-    a load where it is on; the table is kept for the next call at the
-    same shapes. No step, update or trace counter, learning rate or RNG
-    state moves, and nothing runs on the device."""
-    last = step._last_traced
-    if last is None:
+    Costs nothing until called, and then no trace, lowering or compile:
+    the first call parses the module's text (1.9 s for the 49,000
+    instructions of a six-layer unrolled sparse step), later calls copy
+    the kept table. No step, update or trace counter, learning rate or
+    RNG state moves, and nothing runs on the device."""
+    record = step._step_program
+    if record is None:
         raise RuntimeError(
             "op_scopes(): no per-step program has traced yet; run one "
-            "step (or warm()) first")
-    if step._op_scopes is None or step._op_scopes[0] is not last:
-        from ..analysis.runtime_profile import hlo_op_scopes
-        attr, avals = last
-        count = step._trace_count
-        try:
-            text = getattr(step, attr).lower(*avals).compile().as_text()
-        finally:            # lowering may re-run the traced body's hook
-            step._trace_count, step._last_traced = count, last
-        step._op_scopes = (last, hlo_op_scopes(text))
-    return dict(step._op_scopes[1])
+            "step first")
+    return record.op_scopes()
 
 
 class TrainStep:
@@ -305,11 +422,10 @@ class TrainStep:
         # tests assert a drifting-length fused epoch compiles exactly 2
         # programs (scanned window + trailing per-step)
         self._trace_count = 0
-        # (attribute of the per-step program that traced last, its
-        # arguments' ShapeDtypeStructs): what `op_scopes` lowers, and
-        # (that pair, the table made from it) of its last call
-        self._last_traced = None
-        self._op_scopes = None
+        # `_trace_count` when this step last published its program's
+        # record, and that record (`publish_step_program`)
+        self._published_at = 0
+        self._step_program = None
 
     # ------------------------------------------------------------------
     def _make_step_fn(self):
@@ -357,8 +473,7 @@ class TrainStep:
         if k == 1:
             def full_step(params, buffers, opt_state, lr, step_no, rng_key,
                           *batch):
-                remember_trace(step_self, "_jitted", params, buffers,
-                               opt_state, lr, step_no, rng_key, *batch)
+                step_self._count_trace()
                 loss, new_bufs, grads = step_fn(params, buffers, opt_state,
                                                 lr, step_no, rng_key, *batch)
                 with jax.named_scope("optimizer"):
@@ -374,8 +489,7 @@ class TrainStep:
         # (call_count % k), so no in-program branch is needed
         def acc_step(params, buffers, opt_state, acc, lr, step_no, rng_key,
                      *batch):
-            remember_trace(step_self, "_jitted_acc", params, buffers,
-                           opt_state, acc, lr, step_no, rng_key, *batch)
+            step_self._count_trace()
             loss, new_bufs, grads = step_fn(params, buffers, opt_state,
                                             lr, step_no, rng_key, *batch)
             with jax.named_scope("grad_accumulate"):
@@ -384,8 +498,7 @@ class TrainStep:
 
         def apply_step(params, buffers, opt_state, acc, lr, step_no, rng_key,
                        *batch):
-            remember_trace(step_self, "_jitted", params, buffers,
-                           opt_state, acc, lr, step_no, rng_key, *batch)
+            step_self._count_trace()
             loss, new_bufs, grads = step_fn(params, buffers, opt_state,
                                             lr, step_no, rng_key, *batch)
             with jax.named_scope("grad_accumulate"):
@@ -424,20 +537,22 @@ class TrainStep:
                 step_no = jnp.asarray(
                     self.update_count + (1 if micro else 0), jnp.float32)
             with _span("train.step.enqueue", cat="train", step=n):
+                prog = self._jitted_acc if micro else self._jitted
+                args = (self.params, self.buffers, self.opt_state,
+                        *((self.acc_grads,) if k > 1 else ()),
+                        lr, step_no, rng_key, *raw_batch)
                 if micro:
-                    loss, self.buffers, self.acc_grads = self._jitted_acc(
-                        self.params, self.buffers, self.opt_state,
-                        self.acc_grads, lr, step_no, rng_key, *raw_batch)
+                    loss, self.buffers, self.acc_grads = prog(*args)
                 elif k > 1:
                     (loss, self.params, self.buffers, self.opt_state,
-                     self.acc_grads) = self._jitted(
-                        self.params, self.buffers, self.opt_state,
-                        self.acc_grads, lr, step_no, rng_key, *raw_batch)
+                     self.acc_grads) = prog(*args)
                 else:
                     (loss, self.params, self.buffers,
-                     self.opt_state) = self._jitted(
-                        self.params, self.buffers, self.opt_state, lr,
-                        step_no, rng_key, *raw_batch)
+                     self.opt_state) = prog(*args)
+            if self._trace_count != self._published_at:     # it compiled
+                publish_step_program(
+                    self, "accumulate" if micro else "step", prog, args)
+            del args        # the donated arrays
             with _span("train.step.post", cat="train", step=n):
                 if not micro and self.auto_lr_step:
                     lr_sched = getattr(self.optimizer, "_learning_rate",
@@ -539,16 +654,19 @@ class TrainStep:
             with _span("train.step.enqueue", cat="train", step=n), \
                     _quiet_unused_donation():
                 if self.accumulate_steps > 1:
+                    args = (self.params, self.buffers, self.opt_state,
+                            self.acc_grads, base_key, lrs, step_nos, counts,
+                            upd, *raw_batch)
                     (losses, self.params, self.buffers, self.opt_state,
-                     self.acc_grads) = prog(
-                        self.params, self.buffers, self.opt_state,
-                        self.acc_grads, base_key, lrs, step_nos, counts,
-                        upd, *raw_batch)
+                     self.acc_grads) = prog(*args)
                 else:
+                    args = (self.params, self.buffers, self.opt_state,
+                            base_key, lrs, step_nos, counts, *raw_batch)
                     (losses, self.params, self.buffers,
-                     self.opt_state) = prog(
-                        self.params, self.buffers, self.opt_state,
-                        base_key, lrs, step_nos, counts, *raw_batch)
+                     self.opt_state) = prog(*args)
+                if self._trace_count != self._published_at:
+                    publish_step_program(self, "scan", prog, args)
+                del args
             with _span("train.step.post", cat="train", step=n):
                 out = Tensor(losses)
         return out
@@ -585,7 +703,9 @@ class TrainStep:
         moves. On a store-warm machine the first `fit` step then
         dispatches a deserialized executable with ZERO XLA compiles
         (tests/test_compilation.py::TestFitWarmStart asserts exactly
-        this). Returns the compile-log records."""
+        this; tests/test_train_tracing.py that the step program's
+        record, made here of each program, costs none either). Returns
+        the compile-log records."""
         from ..compilation import log as _clog
         from ..compilation import prime_helper_ops
         from ..compilation.store import AotProgram, aot_compile
@@ -600,11 +720,14 @@ class TrainStep:
         recs = []
         k = self.accumulate_steps
 
-        def _warm_site(name, prog, args):
+        def _warm_site(name, prog, args, program="step"):
             rec = {"site": name}
             aot = aot_compile(name, prog, args, store=store,
                               log_record=rec, static_key=static)
             recs.append(_clog.record(rec))
+            # the record of what was compiled or loaded, made from it
+            # here: the first step then has nothing to publish
+            publish_step_program(self, program, aot)
             return aot
 
         if not isinstance(self._jitted, AotProgram):
@@ -617,7 +740,8 @@ class TrainStep:
                 acc_args = (self.params, self.buffers, self.opt_state,
                             self.acc_grads, lr, step_no, key) + raw_batch
                 self._jitted_acc = _warm_site(
-                    "train_step_acc", self._jitted_acc, acc_args)
+                    "train_step_acc", self._jitted_acc, acc_args,
+                    "accumulate")
                 self._jitted = _warm_site(
                     "train_step_apply", self._jitted, acc_args)
         if scan_k is not None and scan_k > 1:
@@ -639,7 +763,7 @@ class TrainStep:
                             base_key, lrs, step_nos, counts) + sb
                 with _quiet_unused_donation():
                     aot = _warm_site(f"train_step_scan_k{scan_k}",
-                                     prog, args)
+                                     prog, args, "scan")
                 self._scan_progs[(int(scan_k), len(raw_batch))] = aot
         return recs
 

@@ -11,8 +11,10 @@ of float32 sums alone: 1e-5 relative on outputs, losses and gradients, with
 of either sign. The splash kernel
 keeps float32 scores and accumulators in interpret mode: 2e-5.
 """
+import contextlib
 import importlib
 import math
+import re
 
 import numpy as np
 import pytest
@@ -565,6 +567,80 @@ def test_every_new_layer_registers_its_scope():
                   "post_attention_layernorm", "pre_mlp_layernorm",
                   "post_mlp_layernorm", "head_loss", "optimizer"):
         assert f"/{scope}/" in paths or f"({scope})" in paths, scope
+
+
+def _compiled_step(recompute=True):
+    """(the table, the opcodes of every instruction) of a tiny
+    ``AfmoeForCausalLM`` step compiled on this backend."""
+    import collections
+    from paddle_tpu.analysis.hlo_cost import parse_hlo_module
+    moe = importlib.import_module("paddle_tpu.distributed.moe")
+    moe._traced_once.cache_clear()      # a path is traced once a process
+    model, _, _ = _program(recompute)
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                 parameters=model.parameters())
+    step = TrainStep(model, model.make_loss_fn(), opt)
+    ids = np.random.default_rng(0).integers(0, 64, (2, 16))
+    step(paddle.to_tensor(ids), paddle.to_tensor(ids))
+    rec = step._step_program
+    module = parse_hlo_module(rec.hlo_text())
+    return rec.op_scopes(), collections.Counter(
+        (i.opcode, i.attrs.get("custom_call_target", ""))
+        for c in module.computations.values() for i in c.instrs)
+
+
+def test_expert_layer_names_its_sort_dispatch_products_and_combine(
+        monkeypatch):
+    """The four scopes inside ``experts`` reach the compiled step's table,
+    forward and transposed, through the conditional's branches and the
+    paths' shared ``jax.jit``; and they are names alone: without them the
+    step compiles to the same instructions."""
+    from paddle_tpu.analysis import runtime_profile as rp
+    table, opcodes = _compiled_step()
+    read = [rp.read_scope(p, n) for n, p in table.items()
+            if "/experts/" in p]
+    inside = {}
+    for r in read:
+        names = r["scope"].split("/")
+        for name in ("sort", "dispatch", "products", "combine"):
+            if name in names:
+                assert "experts" in names[:names.index(name)]
+                inside.setdefault(name, set()).add(r["pass"])
+    # the recomputed block keeps the layer's result, so its forward
+    # conditional has no reader and is gone: the sort alone is recomputed
+    assert inside["sort"] == {"forward", "recompute"}
+    # (the dense path recomputes each expert's products: its own
+    # ``jax.checkpoint``)
+    assert inside["dispatch"] == inside["combine"] == {"forward", "backward"}
+    assert inside["products"] == {"forward", "backward", "recompute"}
+    # the gather's transpose is the backward scatter-add, under `dispatch`;
+    # the combine's scatter-add runs forward and, in the backward
+    # conditional's second run of the rung, backward
+    def ops_under(name, pass_):
+        return {re.sub(r"[.\d]+$", "", n) for n, p in table.items()
+                if f"/{name}/" in p and "routed_sorted" in p
+                and rp.read_scope(p)["pass"] == pass_}
+    assert any("scatter" in n for n in ops_under("dispatch", "backward"))
+    assert any("scatter" in n for n in ops_under("combine", "forward"))
+    assert any("transpose(jvp(jit(routed_sorted)))" in p
+               for p in table.values())
+    # every operation of the sorted path is under one of the three: what
+    # is left to `experts` itself is the conditional and its plumbing
+    inner = {m.group(1) for p in table.values() for m in [re.search(
+        r"jit\(routed_sorted\)\)*/([\w\-]+)/", p)] if m}
+    assert inner == {"dispatch", "products", "combine"}, inner
+
+    real = jax.named_scope
+    monkeypatch.setattr(jax, "named_scope", lambda name: (
+        contextlib.nullcontext() if name in (
+            "sort", "dispatch", "products", "combine") else real(name)))
+    bare_table, bare_opcodes = _compiled_step()
+    monkeypatch.undo()
+    importlib.import_module(
+        "paddle_tpu.distributed.moe")._traced_once.cache_clear()
+    assert not any(re.search(r"/(dispatch|products|combine)/", p)
+                   for p in bare_table.values())
+    assert bare_opcodes == opcodes and len(bare_table) == len(table)
 
 
 def test_default_layer_kinds_follow_the_published_pattern():

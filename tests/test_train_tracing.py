@@ -3,8 +3,11 @@ every layer, loss and optimizer that reaches the compiled text; the rule
 that reads a scope path; `TrainStep.op_scopes()`; `by_scope`; the
 `train.step*` spans that `TrainStep` records about itself; one span
 primitive; compile seconds by phase in the ring. All CPU, tiny sizes."""
+import contextlib
+import gc
 import os
 import re
+import weakref
 
 import numpy as np
 import pytest
@@ -16,7 +19,8 @@ from paddle_tpu.analysis import runtime_profile as rp
 from paddle_tpu.analysis.hlo_cost import parse_hlo_module
 from paddle_tpu.compilation import counters
 from paddle_tpu.framework import random as _rng
-from paddle_tpu.jit import TrainStep
+from paddle_tpu.jit import TrainStep, last_step_program
+from paddle_tpu.jit import training
 from paddle_tpu.models import GPTConfig, GPTForCausalLM
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -71,8 +75,7 @@ def _runs_on_its_own(text: str):
 # ---------------------------------------------------------------- scopes
 def test_scopes_reach_the_compiled_text(ran):
     scan, step, ids, table = ran
-    attr, avals = step._last_traced
-    text = getattr(step, attr).lower(*avals).compile().as_text()
+    text = step._step_program.hlo_text()
     ops = _runs_on_its_own(text)
     # of the instructions that came from an operation of the program
     # (the rest the compiler made from constants and loop counters)
@@ -104,8 +107,7 @@ def test_scopes_reach_the_compiled_text(ran):
 
 def test_op_scopes_names_the_compiled_instructions(ran):
     _, step, ids, table = ran
-    attr, avals = step._last_traced
-    text = getattr(step, attr).lower(*avals).compile().as_text()
+    text = step._step_program.hlo_text()
     names = set(re.findall(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = ", text, re.M))
     assert set(table) == names
     assert sum(1 for p in table.values() if p) > len(names) // 2
@@ -122,9 +124,9 @@ def test_op_scopes_moves_nothing():
                 np.asarray(jax.random.key_data(_rng.get_rng_state())).tolist())
     before = state()
     appended = obs.recorder.appended
-    table = step.op_scopes()
-    with counters.CompileTracker() as t:
-        assert step.op_scopes() == table    # kept: no second compile
+    with counters.CompileTracker() as t:    # the record's: no compile
+        table = step.op_scopes()
+        assert step.op_scopes() == table
     assert t.backend_compiles == 0 and t.traces == 0
     assert state() == before
     assert not [e for e in _since(appended)
@@ -139,18 +141,266 @@ def test_accumulating_step_has_its_scope_and_program():
     step, ids = _tiny_step(False, accumulate_steps=2)
     mark = obs.recorder.appended
     step(ids, ids)                      # micro-step: accumulate
-    assert step._last_traced[0] == "_jitted_acc"
+    assert step._step_program.program == "accumulate"
     regions = {rp.read_scope(p)["region"]
                for p in step.op_scopes().values() if p}
     assert "grad_accumulate" in regions and "optimizer" not in regions
     step(ids, ids)                      # update
-    assert step._last_traced[0] == "_jitted"
+    assert step._step_program.program == "step"
+    assert step._step_program is last_step_program()
     regions = {rp.read_scope(p)["region"]
                for p in step.op_scopes().values() if p}
     assert {"grad_accumulate", "optimizer"} <= regions
     progs = [e["args"]["program"] for e in _since(mark)
              if e["name"] == "train.step"]
     assert progs == ["accumulate", "step"]
+
+
+# ------------------------------------------------- the program's record
+@contextlib.contextmanager
+def _compile_events():
+    """[(event, seconds)] of jax's trace, lowering and backend-compile
+    events fired inside the block."""
+    got, on = [], [True]
+
+    def listen(event, secs, **kw):
+        if on[0] and event in counters._SPAN_OF:
+            got.append((counters._SPAN_OF[event], secs))
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        yield got
+    finally:
+        on[0] = False
+
+
+def _args_like(step, ids):
+    """A call's arguments again, at the avals the step ran at."""
+    import jax.numpy as jnp
+    return (step.params, step.buffers, step.opt_state,
+            jnp.asarray(1e-3, jnp.float32), jnp.asarray(1.0, jnp.float32),
+            _rng.default_generator().fold_in(1), ids.value, ids.value)
+
+
+def test_the_first_step_publishes_its_program_and_later_steps_nothing():
+    step, ids = _tiny_step(False)
+    assert step._step_program is None
+    step(ids, ids)
+    rec = last_step_program()
+    assert rec is step._step_program
+    assert (rec.program, rec.trainer, rec.traces) == ("step", "TrainStep", 1)
+    assert 0 < rec.publish_s < 5
+    for _ in range(9):
+        step(ids, ids)
+    assert last_step_program() is rec and step._step_program is rec
+    assert step._trace_count == step._published_at == 1
+    # a second shape compiles a second program: its record replaces the
+    # first, which goes on answering whoever kept it
+    table = rec.op_scopes()
+    half = paddle.to_tensor(np.asarray(ids.value)[:1])
+    step(half, half)
+    again = last_step_program()
+    assert again is not rec and again is step._step_program
+    assert again.traces == step._trace_count == 2
+    assert again.op_scopes() != table and rec.op_scopes() == table
+
+
+def test_publishing_traces_lowers_and_compiles_nothing():
+    step, ids = _tiny_step(False)
+    step(ids, ids)
+    first = last_step_program()
+    step(ids, ids)
+    args = _args_like(step, ids)
+    count = step._trace_count
+    with counters.CompileTracker() as t, _compile_events() as evs:
+        training.publish_step_program(step, "step", step._jitted, args)
+    assert last_step_program() is not first     # published again
+    assert step._trace_count == count           # the step's Python did not run
+    assert t.backend_compiles == 0 and t.persistent_cache_hits == 0
+    # jit's cache lookup fires its trace event, a hit; nothing lowers
+    assert [n for n, _ in evs if n != "compile.trace"] == []
+    assert len(evs) <= 1
+    assert last_step_program().op_scopes() == first.op_scopes()
+    # and a later step pays an integer comparison: no event at all
+    with _compile_events() as evs:
+        step(ids, ids)
+    assert evs == [] and last_step_program().traces == count
+
+
+def test_the_record_outlives_its_trainer():
+    step, ids = _tiny_step(False)
+    step(ids, ids)
+    table = step.op_scopes()
+    dead, model = weakref.ref(step), weakref.ref(step.model)
+    arrays = [weakref.ref(v) for v in step.params.values()]
+    del step
+    gc.collect()
+    assert dead() is None and model() is None
+    assert all(a() is None for a in arrays)
+    rec = last_step_program()
+    assert rec.op_scopes() == table
+    assert "op_name=" in rec.hlo_text()
+    # host data alone: no attribute of the record is a device array, a
+    # trainer or an executable
+    held = [getattr(rec, n) for n in rec.__slots__]
+    assert all(isinstance(v, (str, int, float, dict, type(None)))
+               for v in held), held
+
+
+@pytest.mark.parametrize("kind", ["accumulate", "scan", "scan_accumulate"])
+def test_every_per_step_program_publishes_alike(kind):
+    step, ids = _tiny_step(False, accumulate_steps=1 if kind == "scan"
+                           else 2)
+    if kind == "accumulate":
+        step(ids, ids)
+        assert last_step_program().program == "accumulate"
+        acc = last_step_program()
+        step(ids, ids)
+        rec = last_step_program()
+        assert rec is not acc and rec.program == "step" and rec.traces == 2
+    else:
+        window = paddle.to_tensor(np.stack([np.asarray(ids.value)] * 2))
+        with _compile_events() as evs:
+            step.scan_steps(2, window, window)
+        rec = last_step_program()
+        assert rec.program == "scan" and rec.traces == 1
+        assert sum(n == "compile.backend" for n, _ in evs) >= 1
+        with _compile_events() as evs:
+            step.scan_steps(2, window, window)
+        assert evs == [] and last_step_program() is rec
+    assert rec is step._step_program and rec.trainer == "TrainStep"
+    regions = {rp.read_scope(p)["region"] for p in rec.op_scopes().values()
+               if p}
+    assert "optimizer" in regions and "head_loss" in regions
+    assert ("grad_accumulate" in regions) == (kind != "scan")
+
+
+def test_a_failed_publish_fails_no_step():
+    step, ids = _tiny_step(False)
+
+    class NoLower:
+        def __init__(self, fn):
+            self.fn = fn
+
+        def __call__(self, *a):
+            return self.fn(*a)
+
+        def lower(self, *a):
+            raise ValueError("not here")
+    step._build()
+    step._jitted = NoLower(step._jitted)
+    before = last_step_program()
+    with pytest.warns(UserWarning, match="record was not made"):
+        loss = float(step(ids, ids))
+    assert np.isfinite(loss) and last_step_program() is before
+    with pytest.raises(RuntimeError, match="no per-step program"):
+        step.op_scopes()
+    float(step(ids, ids))               # asked once, not on every step
+    assert step._published_at == step._trace_count == 1
+
+
+def test_a_lookup_that_misses_compiles_nothing_for_the_record():
+    """The record takes the executable jit already holds and never makes
+    one: where the look-up misses, it warns, and the miss shows."""
+    step, ids = _tiny_step(False)
+    step(ids, ids)
+    rec = last_step_program()
+    # (a) abstract values jit never saw: the step's Python runs again
+    half = paddle.to_tensor(np.asarray(ids.value)[:1])
+    args = _args_like(step, half)
+    with _compile_events() as evs, \
+            pytest.warns(UserWarning, match="traced again"):
+        training.publish_step_program(step, "step", step._jitted, args)
+    assert "compile.backend" not in [n for n, _ in evs]
+    assert last_step_program() is rec
+    assert step._trace_count == step._published_at == 2     # not hidden
+    # (b) a lowering nobody compiled (what a store hit leaves behind)
+    fresh, _ = _tiny_step(False)
+    fresh._build()
+    args = _args_like(fresh, ids)
+    fresh._jitted.lower(*args)
+    with _compile_events() as evs, \
+            pytest.warns(UserWarning, match="holds no executable"):
+        training.publish_step_program(fresh, "step", fresh._jitted, args)
+    assert "compile.backend" not in [n for n, _ in evs]
+    assert fresh._step_program is None and last_step_program() is rec
+    assert fresh._published_at == fresh._trace_count == 1
+
+
+_STORE_WARM = """
+import json, sys
+import numpy as np, jax
+import paddle_tpu as paddle
+from paddle_tpu import nn
+from paddle_tpu.compilation import counters
+from paddle_tpu.compilation.store import ExecutableStore
+from paddle_tpu.jit import TrainStep, last_step_program
+
+store = ExecutableStore(root=sys.argv[1], enabled=True)
+events = []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda event, secs, **kw: events.append(event))
+x = np.ones((8, 16), np.float32)
+y = np.ones((8, 4), np.float32)
+out = []
+for _ in range(2):          # the second trainer finds the first's entries
+    paddle.seed(0)
+    net = nn.Sequential(nn.Linear(16, 32), nn.ReLU(), nn.Linear(32, 4))
+    step = TrainStep(net, lambda o, t: ((o - t) ** 2).mean(),
+                     paddle.optimizer.AdamW(learning_rate=1e-3,
+                                            parameters=net.parameters()))
+    del events[:]
+    with counters.CompileTracker() as warm:
+        recs = step.warm(x, y, scan_k=2, store=store)
+    warm_events = list(events)
+    rec = last_step_program()
+    del events[:]
+    with counters.CompileTracker() as first:
+        loss = float(step(paddle.to_tensor(x), paddle.to_tensor(y)))
+        step.scan_steps(2, paddle.to_tensor(np.stack([x, x])),
+                        paddle.to_tensor(np.stack([y, y])))
+    out.append({
+        "sources": [r["source"] for r in recs], "loss": loss,
+        "warm_backend": warm.backend_compiles,
+        "warm_events": warm_events, "first_events": list(events),
+        "first": [first.backend_compiles, first.persistent_cache_hits,
+                  first.traces],
+        "same_record": last_step_program() is rec,
+        "record": [rec.program, rec.trainer, rec.traces],
+        "published_at": step._published_at, "traces": step._trace_count,
+        "optimizer_ops": sum("optimizer" in p
+                             for p in rec.op_scopes().values())})
+print(json.dumps(out))
+"""
+
+
+def test_a_store_warm_first_step_compiles_and_loads_nothing(tmp_path):
+    """`warm()` makes the record of each program from the executable it
+    compiled or LOADED; the first step and the first window then fire no
+    trace, lowering, compile or cache load, for the record or otherwise.
+    On one CPU device in a process of its own: the virtual mesh of these
+    tests cannot run a loaded executable."""
+    import json
+    import subprocess
+    import sys
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
+    done = subprocess.run(
+        [sys.executable, "-c", _STORE_WARM, str(tmp_path / "exec")],
+        env=env, capture_output=True, text=True, timeout=240)
+    assert done.returncode == 0, done.stderr[-2000:]
+    cold, hit = json.loads(done.stdout.strip().splitlines()[-1])
+    assert cold["sources"] == ["compiled", "compiled"]
+    assert hit["sources"] == ["store", "store"]
+    for got in (cold, hit):
+        assert got["first_events"] == [] and got["first"] == [0, 0, 0]
+        assert got["same_record"]
+        assert got["record"] == ["scan", "TrainStep", 2]
+        assert got["published_at"] == got["traces"] == 2
+        assert got["optimizer_ops"] > 0
+    # a hit: `aot_compile` traces and lowers for its key, nothing more
+    assert hit["warm_backend"] == 0
+    assert not [e for e in hit["warm_events"] if "backend_compile" in e]
+    assert hit["loss"] == cold["loss"]
 
 
 # ------------------------------------------------------------- the rule
@@ -228,6 +478,21 @@ RULE_CASES = {
     "a_loss_layer_is_the_trainers_scope": (
         J + "jvp(head_loss)/crossentropyloss/reduce_sum", "forward",
         "head_loss/crossentropyloss", "head_loss"),
+    "expert_layer_dispatch_inside_a_branch": (
+        J + "jvp(afmoeforcausallm)/model/block_1/mlp/experts/cond/"
+        "branch_0_fun/jit(routed_sorted)/dispatch/gather", "forward",
+        "afmoeforcausallm/model/block/mlp/experts/dispatch", "dispatch"),
+    "expert_layer_gathers_transpose_is_the_backward_scatter_add": (
+        J + "transpose(jvp(afmoeforcausallm))/model/jvp(afmoeforcausallm)/"
+        "model/checkpoint/block_2/mlp/experts/cond/branch_1_fun/"
+        "transpose(jvp(jit(routed_sorted)))/dispatch/scatter-add",
+        "backward", "afmoeforcausallm/model/afmoeforcausallm/model/block/"
+        "mlp/experts/dispatch", "dispatch"),
+    "expert_layer_sort_recomputed": (
+        J + "transpose(jvp(afmoeforcausallm))/model/checkpoint/"
+        "rematted_computation/block_2/mlp/experts/sort/jit(argsort)/sort",
+        "recompute", "afmoeforcausallm/model/block/mlp/experts/sort",
+        "sort"),
     "nothing_of_the_program": (
         J + "jit(_where)/select_n", "forward", "", "unscoped"),
     "an_argument": ("params['gpt.ln_f.weight']", "forward", "",
@@ -561,10 +826,27 @@ def test_parallel_step_names_and_times_itself_alike():
         assert [e["args"]["step"] for e in evs if e["name"] == "train.step"] \
             == [1, 2]
         count = step._trace_count
-        read = [rp.read_scope(p) for p in step.op_scopes().values() if p]
+        rec = last_step_program()
+        assert rec is step._step_program
+        assert (rec.program, rec.trainer, rec.traces) == (
+            "step", "ParallelTrainStep", count)
+        with counters.CompileTracker() as t:
+            read = [rp.read_scope(p) for p in step.op_scopes().values()
+                    if p]
+        assert t.backend_compiles == 0 and t.traces == 0
         assert step._trace_count == count and step.step_count == 2
         assert {"optimizer", "head_loss", "sequential"} <= {
             r["region"] for r in read}
         assert {"forward", "backward", "update"} <= {r["pass"] for r in read}
+        # its fused window publishes as the single-chip trainer's does
+        step.scan_steps(2, np.stack([x, x]), np.stack([x, x]))
+        window = last_step_program()
+        assert window is step._step_program and window is not rec
+        assert (window.program, window.trainer, window.traces) == (
+            "scan", "ParallelTrainStep", count + 1)
+        step.scan_steps(2, np.stack([x, x]), np.stack([x, x]))
+        assert last_step_program() is window
+        assert "optimizer" in {rp.read_scope(p)["region"]
+                               for p in window.op_scopes().values() if p}
     finally:
         dist.set_mesh(None)
