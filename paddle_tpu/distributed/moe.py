@@ -206,7 +206,7 @@ class MoELayer(Layer):
 # what the last traced ``TokenChoiceMoE`` call did (as
 # ``F.last_attention_dispatch()`` for attention): {"kernel",
 # "experts_held", "experts_published", "top_k", "rows_ladder", "rows_bound",
-# "tiling", "activation", "score", "router_input"}
+# "tiling", "combine", "activation", "score", "router_input"}
 _last_moe = {}
 
 
@@ -221,7 +221,10 @@ def last_moe_dispatch() -> dict:
     more assignments than that landing here take the dense path, none is
     dropped), ``tiling`` (the tiles of the forward products by w1 and w3
     and by w2 at ``rows_bound``, ``_gmm_tiles``; a lower rung takes its
-    own by the same rule),
+    own by the same rule), ``combine`` (what sums the sorted rows by
+    token, in the combine and in the transpose of the dispatch's gather
+    alike: ``kernel`` and ``token_tile``, the tokens a group of its
+    grouped product, ``_rows_to_tokens``),
     ``activation`` (the experts' gate: "silu" | "relu"), ``score`` (the
     router's rule: "sigmoid" | "softmax_of_chosen") and ``router_input``
     ("expert_input": the layer routed on the tensor its experts read;
@@ -260,15 +263,17 @@ def _gmm_tiles(rows: int, k: int, n: int) -> tuple:
 # the gathers, masks and elementwise passes round the products cost by the
 # rung taken: a balanced routing (0.99 - 1.02 shares a layer on every seed
 # of the SmallThinker cell, PERF.md section 2) pays for 1.25, a drifting
-# layer for the top. (The two scatter-adds a layer and step, the combine
-# and the gather's transpose, do not follow: on the chip they take 8 - 9 ms
-# a call at any rung, PERF.md section 6, PR 37.) The top is three shares
-# because of what landed on the chip (PERF.md section 6, PR 30): a router
+# layer for the top. (So do the two sums of rows by token a layer and step,
+# the combine and the gather's transpose, since they are a gather of the
+# rung's rows and a grouped product over them, ``_rows_to_tokens``; as
+# scatter-adds the chip took 8 - 9 ms a call at any rung, PERF.md section
+# 6, PRs 37 and 39.) The top is three shares because of what landed on the
+# chip (PERF.md section 6, PR 30): a router
 # trained from a random start without a warm-up put up to 2.2 even shares
 # on one layer's held experts within 25 steps, and with a top of 2 two
 # seeds of nine took the dense path for some steps. Before the ladder
 # every call paid for the top. Two rungs and no third between them: every
-# rung is twelve more kernels a layer in the step's program, 3 s of every
+# rung is fourteen more kernels a layer in the step's program, 3 s of every
 # warm set-up on the chip, and a rung at 2 shares saved the one cell whose
 # layers drift 0.4% of a step
 _ROWS_OVER_EVEN = (1.25, 3)
@@ -343,6 +348,118 @@ def _sorted_weights_bwd(pos, d_rows):
 _sorted_weights.defvjp(_sorted_weights_fwd, _sorted_weights_bwd)
 
 
+# tokens a tile of ``_rows_to_tokens``' product
+_TOKEN_TILE = 256
+
+
+def _token_tile(tokens: int) -> int:
+    """How many consecutive tokens share a group of ``_rows_to_tokens``'
+    grouped product, from the shapes alone: 256, or every token (in whole
+    sublanes) where there are fewer. The one-hot operand is [rows, tile]
+    and the product costs 2 * rows * tile * d operations, so a smaller tile
+    is less work and more groups: on the chip 128 read within 0.02 ms of
+    256 and 512 up to 0.08 ms slower, of 0.36 - 0.56 a call (PERF.md
+    section 6, PR 39). The product's result is
+    [tokens / tile, tile, d]: a tile that made it the shape of the experts'
+    stack [held, d_expert, d] would have the benchmark's readers count
+    these kernels among the experts' products (they find those by
+    shape)."""
+    return _TOKEN_TILE if tokens >= _TOKEN_TILE else -(-tokens // 8) * 8
+
+
+def _by_token_tiles(rows: int, tile: int, y) -> tuple:
+    """(rows, tokens, columns) a tile of ``_rows_to_tokens``' product on
+    the chip: 256 rows (a tile of 256 tokens owns 384 landed rows of an
+    even routing in the SmallThinker cell, so a longer tile of rows
+    straddles more groups), and the whole width where the kernel's
+    buffers for it (the float32 accumulator, two result blocks, two
+    blocks of rows) stay within 10 MiB of the 16 it may take, else as
+    ``_gmm_tiles``. Read on the chip (PERF.md section 6, PR 39): at
+    [30720, 2560] (256, 256, 2560) 0.56 ms against (512, 256, 640) 0.83;
+    (1024, 256, 2560) and (512, 512, 2560) do not fit."""
+    tm = 256 if rows % 256 == 0 else min(_GMM_ROWS, rows)
+    d, size = y.shape[1], y.dtype.itemsize
+    fits = d * (4 * tile + 2 * size * (tile + tm)) <= 10 * 2 ** 20
+    return tm, tile, d if fits else _gmm_tiles(rows, tile, d)[2]
+
+
+def _rows_to_tokens(y, back, tokens):
+    """out[t] = the sum of the sorted rows y[r] whose token is t, [tokens,
+    d], summed in float32. ``back``: ``perm`` and ``col`` [rows], the
+    sorted row and the place within its tile of tokens of each landed
+    assignment in TOKEN order, ``tile_sizes``, the landed rows of each tile
+    of tokens, and ``landed`` (``_sorted_index``).
+
+    The rows are put in token order (a gather of rows) and summed by ONE
+    grouped product whose groups are the tiles of tokens: a one-hot
+    [rows, tile], one at (r, col[r]) for a landed row, times the rows
+    [rows, d], contracted over each tile's own rows. On the chip the
+    library's megablox ``tgmm`` (the kernel of the experts' weight
+    gradients), which visits the tiles of rows that hold landed rows and no
+    others and writes noughts for a tile of tokens that owns none; elsewhere
+    XLA's dot ragged in its contracted dimension. As a scatter-add of
+    model-width rows the chip sorted the token indices, permuted the rows
+    and added them one at a time: 8 - 9 ms a call whatever the rows, where
+    this gather takes 1.2 and the product under one (PERF.md section 6,
+    PR 39)."""
+    perm, col, tile_sizes, landed = back
+    rows, tiles = y.shape[0], tile_sizes.shape[0]
+    tile = _token_tile(tokens)
+    live = jnp.arange(rows) < landed
+    # slots past what landed name no row: their one-hot row is noughts
+    # (and neither backend reads rows past the groups' sum)
+    onehot = ((col[:, None] == jnp.arange(tile, dtype=col.dtype))
+              & live[:, None]).astype(y.dtype)
+    by_token = y[jnp.where(live, perm, 0)]
+    if _on_tpu():
+        out = _megablox().tgmm(onehot.swapaxes(0, 1), by_token, tile_sizes,
+                               y.dtype, _by_token_tiles(rows, tile, y), None,
+                               tiles)
+    else:
+        out = lax.ragged_dot_general(
+            onehot, by_token, tile_sizes, lax.RaggedDotDimensionNumbers(
+                (([0], [0]), ([], [])), [0], []),
+            preferred_element_type=jnp.float32).astype(y.dtype)
+    return out.reshape(tiles * tile, -1)[:tokens]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _tokens_to_rows(tokens, x, tok, back):
+    """x [tokens, d] -> the sorted rows' inputs x[tok]. Its transpose is
+    the sum of the rows by token, written as ``_rows_to_tokens`` and not
+    as the scatter-add that differentiating the gather would give."""
+    return x[tok]
+
+
+def _tokens_to_rows_fwd(tokens, x, tok, back):
+    return x[tok], back
+
+
+def _tokens_to_rows_bwd(tokens, back, d_rows):
+    return _rows_to_tokens(d_rows, back, tokens), None, None
+
+
+_tokens_to_rows.defvjp(_tokens_to_rows_fwd, _tokens_to_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _combine(tokens, y, tok, back):
+    """The sorted rows y back to their tokens, ``_rows_to_tokens``; its
+    transpose is the gather it is."""
+    return _rows_to_tokens(y, back, tokens)
+
+
+def _combine_fwd(tokens, y, tok, back):
+    return _rows_to_tokens(y, back, tokens), tok
+
+
+def _combine_bwd(tokens, tok, d_out):
+    return d_out[tok], None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 # the gate's activation of a gated expert, by its name in the layer's
 # settings: SwiGLU (arXiv:2002.05202) or its ReLU form, ReGLU
 _GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
@@ -357,16 +474,39 @@ def _sorted_index(local, here, held):
     ``order`` [T * k], the assignments sorted by the held expert they chose
     (a stable sort; those held elsewhere last), ``pos`` [T, k], where each
     assignment stands in that order, ``sizes`` [held], the rows of each
-    expert's group, and ``landed``, their sum. Made once a call, outside
-    the conditional, so that it waits for nothing but the routing."""
+    expert's group; for ``_rows_to_tokens``, ``perm`` and ``col``
+    [T * k], the sorted row and the place within its tile of tokens of
+    each landed assignment in TOKEN order, and ``tile_sizes``, the landed
+    assignments of each tile of tokens; and ``landed``, their sum. Made
+    once a call, outside the conditional, so that it waits for nothing but
+    the routing.
+
+    Three sorts and no gather: on the chip a sort of these 98,304 keys
+    with what it carries along takes 0.2 ms and a gather of as many
+    integers 0.5 - 0.75 (PERF.md section 6, PR 39), so whatever follows a
+    permutation rides the sort as a further operand. The first gives the
+    sorted keys for the groups' bounds beside ``order``; ``order`` sorted
+    again is ``pos``; and the landed rows sorted by their assignment's
+    number are in token order (a token's assignments are numbered
+    together), carrying their own number, ``perm``, and their token's
+    place in its tile, ``col``."""
     with jax.named_scope("sort"):
-        key = jnp.where(here, local, held).reshape(-1)
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        bounds = jnp.searchsorted(key[order], jnp.arange(held + 1,
-                                                         dtype=key.dtype))
+        tokens, k = here.shape
+        count = jnp.arange(tokens * k, dtype=jnp.int32)
+        key = jnp.where(here, local, held).reshape(-1).astype(jnp.int32)
+        key, order = lax.sort((key, count), num_keys=1, is_stable=True)
+        bounds = jnp.searchsorted(key, jnp.arange(held + 1, dtype=key.dtype))
         sizes = jnp.diff(bounds).astype(jnp.int32)
-        pos = jnp.argsort(order).astype(jnp.int32).reshape(here.shape)
-        return order, pos, sizes, bounds[held]
+        landed = bounds[held]
+        pos = lax.sort((order, count), num_keys=1)[1].reshape(here.shape)
+        tile = _token_tile(tokens)
+        _, perm, col = lax.sort(
+            (jnp.where(count < landed, order, tokens * k), count,
+             order // k % tile), num_keys=1)
+        tile_sizes = jnp.sum(
+            jnp.pad(here, ((0, -tokens % tile), (0, 0))).reshape(-1, tile * k),
+            axis=1, dtype=jnp.int32)
+        return order, pos, sizes, perm, col, tile_sizes, landed
 
 
 def _routed_sorted(x, w1, w3, w2, wgt, here, index, rows, act):
@@ -374,29 +514,33 @@ def _routed_sorted(x, w1, w3, w2, wgt, here, index, rows, act):
     them, which must hold every assignment that landed here. ``index``:
     ``_sorted_index`` of the routing, of which this takes the first
     ``rows``."""
-    order, pos, sizes, landed = index
+    order, pos, sizes, perm, col, tile_sizes, landed = index
     T, k = here.shape
     with jax.named_scope("dispatch"):
         # where each assignment stands in the sorted order; `rows` (the
         # row of noughts) for one that is held elsewhere
         pos = jnp.where(here & (pos < rows), pos, rows)
-        slot = jnp.pad(order, (0, max(0, rows - T * k)))[:rows]
+        first = lambda a: jnp.pad(a, (0, max(0, rows - T * k)))[:rows]
+        slot = first(order)
         tok = slot // k
+        back = (first(perm), first(col), tile_sizes, landed)
         # rows past the groups are written by no product, forward or
         # backward, and hold whatever the buffer held: nought on both
         # sides
         live = (jnp.arange(rows) < landed)[:, None]
-        rows_in = jnp.where(live, x[tok], 0)
+        rows_in = jnp.where(live, _tokens_to_rows(T, x, tok, back), 0)
     with jax.named_scope("products"):
         y = _glu(rows_in, w1, w3, w2,
                  functools.partial(_grouped_dot, sizes=sizes), act)
     with jax.named_scope("combine"):
         y = jnp.where(live, y, 0)
         y = y * _sorted_weights(wgt, slot, pos)[:, None].astype(y.dtype)
-        # each row back to its token. (The chip adds rows of the model
-        # width one at a time, 3.0 ms for 32,768 of them, PERF.md PR 30;
-        # a gather of [T, k] rows with a sum over k takes 6.1 ms.)
-        return jnp.zeros_like(x).at[tok].add(y)
+        # each row back to its token: the rows in token order, then one
+        # grouped product by tiles of tokens. (A gather of [T, k] rows
+        # through `pos` with a sum over k reads every assignment of every
+        # token where a share of them lands: 6.1 ms for 3.0 on the chip,
+        # PERF.md PR 30.)
+        return _combine(T, y, tok, back)
 
 
 def _rung(landed, ladder):
@@ -622,6 +766,9 @@ class _Experts(Layer):
             experts_held=self.held, experts_published=self.published,
             top_k=self.top_k, rows_ladder=ladder, rows_bound=ladder[-1],
             activation=self.activation,
+            combine={"kernel": "megablox_tgmm_by_token_tile" if _on_tpu()
+                     else "xla_ragged_dot_by_token_tile",
+                     "token_tile": _token_tile(int(x.shape[0]))},
             tiling={"w1_w3": _gmm_tiles(ladder[-1], *self.w1.shape[1:]),
                     "w2": _gmm_tiles(ladder[-1], *self.w2.shape[1:])})
 

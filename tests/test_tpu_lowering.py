@@ -466,13 +466,17 @@ def test_expert_ladder_is_one_conditional_for_v5e(one_chip,
                                                   chip_like_config):
     """The SmallThinker cell's expert share as the layer calls it.
     Forward: the compiled program holds ONE conditional, of the ladder's
-    two rungs and the dense path, three grouped products a rung, and
-    the index work (the stable sort by expert) outside its branches,
-    where it waits for the routing alone. (The sorts left inside are the
-    compiler's own, of each rung's scatter-add.) The gradient of the
-    recomputed share: TWO, the forward's and the backward's, whose rung
-    runs its three products again and their six transposes; the
-    recomputed forward's has no reader and is gone."""
+    two rungs and the dense path, three grouped products a rung and the
+    combine's product by tiles of tokens, and the index work (the stable
+    sorts by expert and by token) outside its branches, where it waits
+    for the routing alone: no sort and no scatter is left inside, since
+    the rows go back to their tokens by a gather and a grouped product
+    (as scatter-adds the compiler sorted each one's token indices). The
+    gradient of the recomputed share: TWO, the forward's and the
+    backward's, whose rung runs its three products again, their six
+    transposes and the product that transposes the dispatch's gather (the
+    second run's combine has no reader and is gone, as is the recomputed
+    forward's conditional)."""
     import importlib
     moe = importlib.import_module("paddle_tpu.distributed.moe")
     ladder = (30720, 73728)
@@ -491,20 +495,49 @@ def test_expert_ladder_is_one_conditional_for_v5e(one_chip,
     def kernels(branch):
         return sum(body.count('custom_call_target="tpu_custom_call"')
                    for body in reached(branch).values())
+
+    def kernel_scopes(branch):
+        # (the scope, the library kernel) of every kernel a branch runs,
+        # by the path JAX left on it: ``benchmark/step_scopes.py``'s
+        # shares of the expert layer read the same paths
+        return sorted(re.findall(
+            r'custom_call_target="tpu_custom_call"[^\n]*op_name="[^"]*?'
+            r'(\w+\(jvp\(|jvp\(|)jit\(routed_sorted\)\)*/(\w+)/jit\((\w+)\)',
+            "\n".join(reached(branch).values())))
+
+    def wide_scatters(bodies):
+        # the scatters left are the library kernels' own bookkeeping, a
+        # vector of tile counts each: none updates rows of a matrix
+        return [line for body in bodies for line in body.splitlines()
+                if " scatter(" in line
+                and not re.search(r"= \w+\[\d+\]\{", line)]
     conds, reached, blocks = _conditionals(compiled(part))
     assert [len(c) for c in conds] == [len(ladder) + 1]
     inside = set().union(*(reached(b) for b in conds[0]))
     sorts = {name for name, body in blocks.items()
-             if re.search(r" sort\([^\n]*argsort", body)}
+             if re.search(r" sort\(", body)}
     assert sorts and not sorts & inside
-    assert [kernels(b) for b in conds[0]] == [3, 3, 0]
+    assert not wide_scatters(blocks[name] for name in inside)
+    assert [kernels(b) for b in conds[0]] == [4, 4, 0]
+    assert kernel_scopes(conds[0][0]) == [("", "combine", "tgmm")] + [
+        ("", "products", "gmm")] * 3
 
     grad = jax.grad(jax.checkpoint(
         lambda *a: (part(*a).astype(jnp.float32) ** 2).sum()),
         argnums=(0, 1, 2, 3, 4))
     conds, reached, _ = _conditionals(compiled(grad))
     assert sorted([kernels(b) for b in c] for c in conds) == [
-        [3, 3, 0], [9, 9, 0]]
+        [4, 4, 0], [10, 10, 0]]
+    backward = max(conds, key=lambda c: kernels(c[0]))[0]
+    assert kernel_scopes(backward) == [
+        ("jvp(", "products", "gmm")] * 3 + [
+        ("transpose(jvp(", "dispatch", "tgmm")] + [
+        ("transpose(jvp(", "products", "gmm")] * 3 + [
+        ("transpose(jvp(", "products", "tgmm")] * 3
+    for c in conds:
+        bodies = [body for b in c for body in reached(b).values()]
+        assert not wide_scatters(bodies)
+        assert not any(" sort(" in body for body in bodies)
 
 
 # -- whole train steps (slow: minutes of compile each, and tier-1 has a
