@@ -112,7 +112,7 @@ def _xfer_factor(op: str, n: int) -> float:
 def _subjaxprs(params: dict):
     """Yield every Jaxpr/ClosedJaxpr nested in an eqn's params (pjit,
     scan, while, cond branches, custom_jvp/vjp, remat, shard_map...)."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     for v in params.values():
         vals = v if isinstance(v, (tuple, list)) else (v,)
         for x in vals:

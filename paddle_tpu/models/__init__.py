@@ -13,6 +13,9 @@ from .deepseek_v3 import (DeepseekV3Attention, DeepseekV3Block,
 from .smallthinker import (SmallThinkerAttention, SmallThinkerBlock,
                            SmallThinkerConfig, SmallThinkerForCausalLM,
                            SmallThinkerModel)
+from .olmo_hybrid import (GatedDeltaNet, OlmoHybridAttention,
+                          OlmoHybridBlock, OlmoHybridConfig,
+                          OlmoHybridForCausalLM, OlmoHybridModel)
 from .bert import (BertConfig, BertModel, BertForSequenceClassification,
                    BertForMaskedLM, ErnieModel, bert_tiny, bert_base,
                    ernie_3_tiny, ernie_3_base)
@@ -29,6 +32,8 @@ __all__ = ["GPTConfig", "GPTModel", "GPTForCausalLM",
            "SmallThinkerConfig", "SmallThinkerAttention",
            "SmallThinkerBlock", "SmallThinkerModel",
            "SmallThinkerForCausalLM",
+           "OlmoHybridConfig", "GatedDeltaNet", "OlmoHybridAttention",
+           "OlmoHybridBlock", "OlmoHybridModel", "OlmoHybridForCausalLM",
            "BertConfig", "BertModel", "BertForSequenceClassification",
            "BertForMaskedLM", "ErnieModel", "bert_tiny", "bert_base",
            "ernie_3_tiny", "ernie_3_base"]

@@ -6,4 +6,5 @@ from .pooling import *  # noqa: F401,F403
 from .norm import *  # noqa: F401,F403
 from .loss import *  # noqa: F401,F403
 from .flash_attention import *  # noqa: F401,F403
+from .linear_attention import *  # noqa: F401,F403
 from .extras import *  # noqa: F401,F403

@@ -335,6 +335,25 @@ def _expert_share_args(width, top_k, hidden):
             _sd((16384, top_k), F32), _sd((16384, top_k), I32)]
 
 
+def _gated_delta(bwd, chunk=128):
+    """kernels/gated_delta.py at the Olmo Hybrid cell's operands: one row
+    of 8,192 positions, 30 heads, q and k of 96 and v of 192 (no multiple
+    of the 128 lanes), the forward kernel alone or the forward and the
+    backward kernel under ``jax.grad``."""
+    from paddle_tpu.kernels.gated_delta import gated_delta_pallas
+
+    def fwd(*a):
+        return gated_delta_pallas(*a, chunk=chunk)
+
+    def loss(*a):
+        return fwd(*a).astype(jnp.float32).sum()
+    rows = (1, 30, 8192)
+    return (jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if bwd else fwd,
+            [_sd(rows + (96,), BF16), _sd(rows + (96,), BF16),
+             _sd(rows + (192,), BF16), _sd(rows, F32), _sd(rows, F32)],
+            2 if bwd else 1)
+
+
 def _slot_write(dtype, tail):
     from paddle_tpu.kernels import fused_slot_write
     return (fused_slot_write,
@@ -412,6 +431,8 @@ CHIP_COMPILE_CASES = {
         lambda: _library_flash_head_major_grouped(None),
     "grouped_experts_smallthinker_share": lambda: _grouped_experts(
         768, 6, 30720, hidden=2560, gate="relu"),
+    "gated_delta_fwd_8k_96_192": lambda: _gated_delta(False),
+    "gated_delta_bwd_8k_96_192": lambda: _gated_delta(True),
     "fused_slot_write_bf16": lambda: _slot_write(BF16, (_H, _D)),
     "fused_slot_write_int8": lambda: _slot_write(I8, (_H, _D)),
     "fused_slot_write_scale": lambda: _slot_write(F32, (_H,)),
@@ -648,6 +669,113 @@ def test_gpt1p3b_width_step_carries_its_scopes_for_v5e(one_chip,
     assert sorted(p["pass"] for p in products
                   if p["region"] == "head_loss") == [
         "backward", "backward", "forward"]
+
+
+def _compile_olmo_hybrid_step_for_v5e(one_chip, monkeypatch, **override):
+    """The Olmo Hybrid cell's step (benchmark/drivers/
+    train_steps_olmo_hybrid.py's composition, sizes and job from the cell's
+    files; ``override`` cuts the layers or the sequence) compiled for the
+    described chip WITHOUT making a weight: an abstract model, its copies
+    and the optimizer's state taken as shapes. Returns (compiled, memory
+    analysis)."""
+    import importlib
+    import paddle_tpu as paddle
+    import paddle_tpu.framework.random as _rng
+    from benchmark.run import find_cell, load_json
+    from paddle_tpu.jit import TrainStep, training
+    from paddle_tpu.models import OlmoHybridConfig, OlmoHybridForCausalLM
+    fa = importlib.import_module("paddle_tpu.nn.functional.flash_attention")
+    la = importlib.import_module("paddle_tpu.nn.functional.linear_attention")
+    for mod in (fa, la):
+        monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+    monkeypatch.setattr(la, "_interpret", lambda: False)
+    cell = find_cell(load_json("BENCHMARK.json"), "train-olmo-hybrid-8k")
+    arch, job, traffic = cell["config"], cell["config"]["job"], \
+        cell["traffic"]
+    layers = override.get("layers", arch["num_hidden_layers"])
+    seq = override.get("seq", traffic["seq"])
+    kinds = [arch["layer_types"][i] for i in arch["layers_kept"]][:layers]
+    keys = ("vocab_size", "hidden_size", "intermediate_size",
+            "num_attention_heads", "num_key_value_heads",
+            "linear_num_key_heads", "linear_num_value_heads",
+            "linear_key_head_dim", "linear_value_head_dim",
+            "linear_conv_kernel_dim", "linear_allow_neg_eigval",
+            "rms_norm_eps", "initializer_range")
+    cfg = OlmoHybridConfig(
+        num_hidden_layers=layers, layer_types=kinds,
+        recompute=job["recompute"], recompute_policy=job["recompute_policy"],
+        fused_loss_chunk=job["fused_loss_chunk"],
+        **{k: arch[k] for k in keys})
+    with paddle.LazyGuard():
+        model = OlmoHybridForCausalLM(cfg)
+    model.bfloat16()
+    opt = _cell_optimizer(paddle, job, model)
+    init = type(opt).init.__get__(opt)
+    with mock.patch.object(training.jnp, "copy", lambda x: x), \
+            mock.patch.object(opt, "init",
+                              lambda p: jax.eval_shape(init, p)):
+        step = TrainStep(model, model.make_loss_fn(), opt)
+    step._build()
+    aval = functools.partial(jax.tree_util.tree_map, lambda x:
+                             jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                  sharding=one_chip))
+    scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    key = aval(jax.eval_shape(
+        lambda: _rng.default_generator().fold_in(1)))
+    ids = jax.ShapeDtypeStruct((traffic["batch"], seq), jnp.int32,
+                               sharding=one_chip)
+    compiled = step._jitted.lower(
+        aval(step.params), aval(step.buffers), aval(step.opt_state),
+        scalar, scalar, key, ids, ids).compile()
+    assert la.last_linear_attention_dispatch()["backend"] == "pallas"
+    return compiled, compiled.memory_analysis()
+
+
+def _kernel_paths(text):
+    """The op_name path of every Pallas kernel of a compiled program."""
+    from paddle_tpu.analysis import runtime_profile as rp
+    table = rp.hlo_op_scopes(text)
+    return [table[n] for n in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)]
+
+
+def test_olmo_hybrid_width_step_carries_its_scopes_for_v5e(
+        one_chip, chip_like_config, monkeypatch):
+    """One linear and one full layer at the Olmo Hybrid cell's widths and
+    1,024 positions, recomputed as the cell runs them: the delta-rule
+    kernel is under ``linear_attn/delta_rule`` in each of its three passes
+    (forward, recomputed, backward), and the mixer's other children are on
+    the program's fusions."""
+    compiled, _ = _compile_olmo_hybrid_step_for_v5e(
+        one_chip, monkeypatch, layers=1, seq=1024)
+    text = compiled.as_text()
+    paths = [p for p in _kernel_paths(text) if "delta_rule" in p]
+    assert len(paths) == 3 and all("linear_attn/delta_rule" in p
+                                   for p in paths)
+    assert sum("rematted_computation" in p for p in paths) == 1
+    assert sum(p.startswith("jit(full_step)/transpose(") for p in paths) == 2
+    from paddle_tpu.analysis import runtime_profile as rp
+    every = set(rp.hlo_op_scopes(text).values())
+    for child in ("short_conv", "gated_norm"):
+        assert any(f"linear_attn/{child}" in p for p in every), child
+
+
+@pytest.mark.slow
+@pytest.mark.timeout(1800)
+def test_olmo_hybrid_train_step_compiles_for_v5e(one_chip, chip_like_config,
+                                                 monkeypatch):
+    """train-olmo-hybrid-8k's whole step: 3 linear + 1 full layer at every
+    published width, 1 x 8,192 positions, per-block recomputation, chunked
+    head/loss, bf16 weights without master: it fits the chip (PR 40: 9.29 G
+    of arguments and 3.45 G of temporaries, 11.86 GiB) and carries the
+    delta-rule kernel three times a linear layer and the attention kernel
+    forward and backward once."""
+    compiled, mem = _compile_olmo_hybrid_step_for_v5e(one_chip, monkeypatch)
+    paths = _kernel_paths(compiled.as_text())
+    assert sum("linear_attn/delta_rule" in p for p in paths) == 9
+    assert sum("block_3/attn" in p for p in paths) == 2
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+    print("Olmo Hybrid one-chip step:", mem)
 
 
 @pytest.mark.parametrize("workload", ["train-gpt-125m", "train-gpt-1.3b"])
