@@ -18,11 +18,16 @@ and ``H = S^T`` [dk, dv] the state the chunk starts from:
 Every exponent is of a difference that is never positive, so no decay
 overflows however strong. ``T`` comes from the block formula
 ``[[X, 0], [Y, Z]]^-1 = [[X^-1, 0], [-Z^-1 Y X^-1, Z^-1]]`` applied at
-block sizes 1, 2, 4, ... in whole C x C products (``_unit_lower_inverse``):
-no power of ``A`` is formed, so nothing cancels. The backward pass is the
-same chunk run backwards by hand (``_chunk_bwd``) from the float32 state
-each chunk started from, which the forward pass writes out, the chunks
-taken last to first with the state's gradient carried between them.
+block sizes 1, 2, 4, ... in C x C products (``_unit_lower_inverse``; from
+block 8 on only the rows a merge changes): no power of ``A`` is formed, so
+nothing cancels. Products run in float32 at full precision; where one
+operand is q, k, v or the output's gradient as it came in bfloat16, the
+float32 one is split exactly into three bfloat16 parts (``_mm``): three
+MXU passes for full precision's six, the other three being zero. The
+backward pass is the same chunk run backwards by hand (``_chunk_bwd``)
+from the float32 state each chunk started from, which the forward pass
+writes out, the chunks taken last to first with the state's gradient
+carried between them.
 
 One body per pass serves both backends: ``gated_delta_pallas`` runs it as a
 Pallas TPU kernel, a grid of (batch, head, chunk) with the state in VMEM
@@ -49,15 +54,61 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["gated_delta_pallas", "gated_delta_xla"]
 
 _HIGHEST = lax.Precision.HIGHEST
+_SPLIT = {jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)}
+
+_tally = [0]            # products split so far, as traced (``_mm``)
+_split_traced: dict = {}   # (pass, q, k, v dtypes) -> products split
+
+
+def _parts(x):
+    """float32 -> (lo, mid, hi) bfloat16 with lo + mid + hi == x exactly."""
+    hi = x.astype(jnp.bfloat16)
+    r = x - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    return (r - mid.astype(jnp.float32)).astype(jnp.bfloat16), mid, hi
 
 
 def _mm(a, b, ta=False, tb=False):
     """a @ b in float32 (a^T with ``ta``, b^T with ``tb``); float32
-    operands at full precision."""
+    operands at full precision. A float32 operand against a bfloat16 one
+    (q, k, v or the output's gradient as they came in) is split exactly
+    into three bfloat16 parts: three one-pass products, which are the terms
+    of full precision's six that are not zero."""
     dims = (((0 if ta else 1,), (1 if tb else 0,)), ((), ()))
+    dot = functools.partial(lax.dot_general, dimension_numbers=dims,
+                            preferred_element_type=jnp.float32)
+    if {a.dtype, b.dtype} == _SPLIT:
+        _tally[0] += 1
+        if a.dtype == jnp.float32:
+            lo, mid, hi = (dot(x, b) for x in _parts(a))
+        else:
+            lo, mid, hi = (dot(a, x) for x in _parts(b))
+        return (lo + mid) + hi
     prec = _HIGHEST if jnp.float32 in (a.dtype, b.dtype) else None
-    return lax.dot_general(a, b, dims, precision=prec,
-                           preferred_element_type=jnp.float32)
+    return dot(a, b, precision=prec)
+
+
+def _tallied(pass_):
+    """Record, as a chunk body is traced, how many of its products ``_mm``
+    split, by pass and by the dtypes of q, k and v."""
+    def wrap(body):
+        @functools.wraps(body)
+        def traced(*args):
+            before = _tally[0]
+            out = body(*args)
+            key = (pass_,) + tuple(jnp.dtype(x.dtype).name for x in args[:3])
+            _split_traced[key] = _tally[0] - before
+            return out
+        return traced
+    return wrap
+
+
+def split_products(q_dtype, k_dtype, v_dtype) -> dict:
+    """Products of each chunk body that took the three-pass form, as last
+    traced with q, k and v of these dtypes (0 where not traced)."""
+    dts = tuple(jnp.dtype(d).name for d in (q_dtype, k_dtype, v_dtype))
+    return {p: _split_traced.get((p,) + dts, 0)
+            for p in ("forward", "backward")}
 
 
 def _iota(c):
@@ -80,7 +131,10 @@ def _to_row(col):
 def _unit_lower_inverse(a):
     """(I + a)^-1 for a strictly lower [C, C], C a power of two: the
     inverse at block size 2m from the one at m, ``B - B Y B``, Y the part
-    of ``a`` that joins two blocks of m inside one of 2m."""
+    of ``a`` that joins two blocks of m inside one of 2m. Y has rows in the
+    lower half of each 2m block alone and B is block-diagonal at m, so
+    ``B Y B`` is zero elsewhere: from m = 8 on (a slice along whole
+    sublane tiles) only those rows are computed."""
     c = a.shape[0]
     t, s = _iota(c)
     x = t ^ s               # t and s share a block of m iff x < m
@@ -88,7 +142,15 @@ def _unit_lower_inverse(a):
     m = 2
     while m < c:
         y = jnp.where((x >= m) & (x < 2 * m), a, 0.0)
-        inv = inv - _mm(_mm(inv, y), inv)
+        if m < 8:
+            inv = inv - _mm(_mm(inv, y), inv)
+        else:
+            halves = inv.reshape(c // (2 * m), 2, m, c)
+            low = halves[:, 1].reshape(c // 2, c)
+            low = low - _mm(_mm(low, y), inv)
+            inv = jnp.concatenate(
+                [halves[:, :1], low.reshape(c // (2 * m), 1, m, c)],
+                axis=1).reshape(c, c)
         m *= 2
     return inv
 
@@ -98,28 +160,36 @@ def _prepare(k, g_row, b_row):
     t, s = _iota(k.shape[0])
     low, strict = s <= t, s < t
     gam = jnp.sum(jnp.where(low, g_row, 0.0), axis=1, keepdims=True)
-    e = jnp.exp(jnp.where(low, gam - _to_row(gam), -jnp.inf))
+    gam_row = _to_row(gam)
+    e = jnp.exp(jnp.where(low, gam - gam_row, -jnp.inf))
     gc = jnp.sum(g_row, axis=1, keepdims=True)                  # (1, 1)
     b = _to_col(b_row)
     kk = _mm(k, k, tb=True)
     a = jnp.where(strict, b * kk * e, 0.0)
     return dict(low=low, strict=strict, gam=gam, e=e, gc=gc, b=b, kk=kk,
                 tinv=_unit_lower_inverse(a), decay=jnp.exp(gam),
-                to_end=jnp.exp(gc - gam))
+                bd_row=b_row * jnp.exp(gam_row), to_end=jnp.exp(gc - gam))
 
 
+# In both bodies q, k, v and do enter the products as they came in, each
+# alone on its side; a row or column scaling of theirs (b, decay, to_end)
+# goes onto the other operand or onto the result (``_mm`` splits the
+# float32 side when they came in bfloat16).
+
+@_tallied("forward")
 def _chunk_fwd(q, k, v, g_row, b_row, h):
     """One chunk: (o [C, dv] float32, the state after it [dk, dv])."""
     p = _prepare(k, g_row, b_row)
-    kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
-    w = _mm(p["tinv"], p["b"] * p["decay"] * kf)
-    u = _mm(p["tinv"], p["b"] * vf) - _mm(w, h)
+    tinv = p["tinv"]
+    w = _mm(tinv * p["bd_row"], k)                # T diag(b decay) K
+    u = _mm(tinv * b_row, v) - _mm(w, h)          # T diag(b) V - W H
     qk = jnp.where(p["low"], _mm(q, k, tb=True) * p["e"], 0.0)
-    o = p["decay"] * _mm(q.astype(jnp.float32), h) + _mm(qk, u)
-    h_next = jnp.exp(p["gc"]) * h + _mm(p["to_end"] * kf, u, ta=True)
+    o = p["decay"] * _mm(q, h) + _mm(qk, u)
+    h_next = jnp.exp(p["gc"]) * h + _mm(k, p["to_end"] * u, ta=True)
     return o, h_next
 
 
+@_tallied("backward")
 def _chunk_bwd(q, k, v, g_row, b_row, h, do, dh):
     """One chunk's gradients from the state it started from ``h``, the
     output's gradient ``do`` and the gradient of the state after it
@@ -127,21 +197,20 @@ def _chunk_bwd(q, k, v, g_row, b_row, h, do, dh):
     ``h``)."""
     p = _prepare(k, g_row, b_row)
     low, strict, e, b = p["low"], p["strict"], p["e"], p["b"]
-    decay, to_end, tinv = p["decay"], p["to_end"], p["tinv"]
-    qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
-    do = do.astype(jnp.float32)
-    kg, vb = b * decay * kf, b * vf
-    w = _mm(tinv, kg)
-    u = _mm(tinv, vb) - _mm(w, h)
+    decay, to_end, tinv, bd_row = p["decay"], p["to_end"], p["tinv"], \
+        p["bd_row"]
+    qf, kf, vf, dof = (x.astype(jnp.float32) for x in (q, k, v, do))
+    w = _mm(tinv * bd_row, k)
+    u = _mm(tinv * b_row, v) - _mm(w, h)
     qk = _mm(q, k, tb=True)
     pm = jnp.where(low, qk * e, 0.0)
     egc = jnp.exp(p["gc"])
 
     # O = decay * (Q H) + P U;  H' = exp(gc) H + (to_end * K)^T U
-    du = _mm(pm, do, ta=True) + _mm(to_end * kf, dh)
+    du = _mm(pm, do, ta=True) + to_end * _mm(k, dh)
     dp = jnp.where(low, _mm(do, u, tb=True), 0.0)
     dq = decay * _mm(do, h, tb=True)
-    d_decay = jnp.sum(_mm(qf, h) * do, axis=1, keepdims=True)
+    d_decay = jnp.sum(_mm(q, h) * dof, axis=1, keepdims=True)
     dkd = _mm(u, dh, tb=True)
     dk = to_end * dkd
     r = to_end * jnp.sum(kf * dkd, axis=1, keepdims=True)     # d(gc - gam)
@@ -152,7 +221,7 @@ def _chunk_bwd(q, k, v, g_row, b_row, h, do, dh):
     dh_prev = egc * dh + _mm(decay * qf, do, ta=True) - _mm(w, du, ta=True)
     # U = T Vb - W H;  W = T Kg
     dw = -_mm(du, h, tb=True)
-    dt = _mm(dw, kg, tb=True) + _mm(du, vb, tb=True)
+    dt = _mm(dw, k, tb=True) * bd_row + _mm(du, v, tb=True) * b_row
     dkg, dvb = _mm(tinv, dw, ta=True), _mm(tinv, du, ta=True)
     # T = (I + A)^-1;  A = b * (K K^T) * E, strictly lower
     da = jnp.where(strict, -_mm(_mm(tinv, dt, ta=True), tinv, tb=True), 0.0)
@@ -162,8 +231,8 @@ def _chunk_bwd(q, k, v, g_row, b_row, h, do, dh):
     # P = (Q K^T) * E, lower
     dqk = dp * e
     de = de + dp * qk
-    dq = dq + _mm(dqk, kf)
-    dk = dk + _mm(dqk, qf, ta=True) + _mm(dkk, kf) + _mm(dkk, kf, ta=True)
+    dq = dq + _mm(dqk, k)
+    dk = dk + _mm(dqk, q, ta=True) + _mm(dkk, k) + _mm(dkk, k, ta=True)
     # Kg = b * decay * K;  Vb = b * V
     dk = dk + b * decay * dkg
     t1 = jnp.sum(kf * dkg, axis=1, keepdims=True)

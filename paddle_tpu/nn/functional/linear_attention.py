@@ -12,8 +12,12 @@ its end with positions that change nothing before them (k = v = 0, no
 decay).
 
 ``last_linear_attention_dispatch()`` says what the last traced call did:
-``backend`` ("pallas" | "xla"), ``reason``, ``chunk``, ``heads``, ``dk``
-and ``dv``.
+``backend`` ("pallas" | "xla"), ``reason``, ``chunk``, ``heads``, ``dk``,
+``dv`` and ``split_products``: how many products of the forward and of the
+backward chunk body took the three-pass form (a float32 operand split
+against a bfloat16 q, k, v or output gradient), counted as the bodies were
+traced with operands of the call's dtypes: ``{"forward": 4, "backward":
+14}`` on bfloat16 operands, zeros on float32 ones.
 """
 from __future__ import annotations
 
@@ -21,17 +25,22 @@ import jax
 import jax.numpy as jnp
 
 from ...autograd.tape import apply
-from ...kernels.gated_delta import gated_delta_pallas, gated_delta_xla
+from ...kernels.gated_delta import (gated_delta_pallas, gated_delta_xla,
+                                    split_products)
 
 __all__ = ["gated_delta_rule", "short_convolution", "l2_normalize",
            "last_linear_attention_dispatch"]
 
 _last_dispatch: dict = {}
+_last_dtypes: list = []     # q, k, v of the last call
 
 
 def last_linear_attention_dispatch() -> dict:
     """The most recent ``gated_delta_rule`` dispatch decision."""
-    return dict(_last_dispatch)
+    rec = dict(_last_dispatch)
+    if _last_dtypes:
+        rec["split_products"] = split_products(*_last_dtypes)
+    return rec
 
 
 def _on_tpu() -> bool:
@@ -55,6 +64,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 128):
                           chunk=chunk, heads=heads, dk=dk, dv=v.shape[-1])
 
     def f(q, k, v, g, beta):
+        _last_dtypes[:] = [q.dtype, k.dtype, v.dtype]
         pad = -seq % chunk
         if pad:
             widen = lambda x: jnp.pad(

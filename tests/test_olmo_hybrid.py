@@ -8,7 +8,13 @@ paddle_tpu).
 
 Tolerances: everything here runs in float32 at ``highest`` matmul precision
 (tests/conftest.py). The chunked rule and the recurrence sum in different
-orders: 2e-5 of the output's largest entry, 5e-5 of each gradient's. The
+orders: 2e-5 of the output's largest entry, 5e-5 of each gradient's; on
+bfloat16 operands the output and the gradients of q, k and v come back in
+bfloat16, whose rounding (2^-8 of an entry) adds to that. Fed the same
+values as bfloat16 (a float32 operand split into three bfloat16 parts
+against them) and as float32 (full precision everywhere), the chunk bodies
+agree to float32 rounding: 1e-6 of each array's largest entry (they read
+4.4e-7 at most; a split that dropped its low part reads 3e-6 - 7e-6). The
 model and the reference then differ by float32 sums alone: 1e-5 relative on
 logits and losses, 1e-4 on gradients (the recurrence's sums), and every
 planted fault moves a gradient's norm by more than 100 times that.
@@ -75,26 +81,120 @@ def _value_and_grads(f, args, co):
         argnums=(0, 1, 2, 3, 4))(*args), f(*args)
 
 
+def _as_bf16(args, co):
+    """q, k, v and the output's cotangent rounded to bfloat16, g and beta
+    as they are: the values both dtypes of a comparison are fed."""
+    q, k, v, g, b = args
+    r = lambda x: x.astype(jnp.bfloat16)
+    return (r(q), r(k), r(v), g, b), r(co).astype(jnp.float32)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("chunk", [8, 16, 64, 128])
 @pytest.mark.parametrize("path", ["xla", "pallas"])
 @pytest.mark.parametrize("beta", [(0.0, 2.0), (1.0, 2.0)])
-def test_chunked_rule_matches_the_recurrence(path, chunk, beta):
+def test_chunked_rule_matches_the_recurrence(path, chunk, beta, dtype):
     """Output and the gradients of q, k, v, g and beta, at chunks of 8 to
     64, with beta over (0, 2) and over (1, 2), where the transition's
-    eigenvalue 1 - beta is negative (``allow_neg_eigval``)."""
+    eigenvalue 1 - beta is negative (``allow_neg_eigval``); q, k, v in
+    float32 (every product at full precision) or in bfloat16 (the split
+    products), the recurrence fed the same values in float32."""
     args, co = _operands(beta=beta)
+    rounding = 0.0              # of the results that come back in q's dtype
+    if dtype == "bfloat16":
+        args, co = _as_bf16(args, co)
+        rounding = 2.0 ** -8
     rule = (lambda *a: G.gated_delta_xla(*a, chunk=chunk)) if path == "xla" \
         else (lambda *a: G.gated_delta_pallas(*a, chunk=chunk,
                                               interpret=True))
     (_, grads), out = _value_and_grads(rule, args, co)
-    (_, want_g), want = _value_and_grads(_recurrence, args, co)
+    wide = tuple(x.astype(jnp.float32) for x in args)
+    (_, want_g), want = _value_and_grads(_recurrence, wide, co)
+    assert out.dtype == args[2].dtype
     scale = float(jnp.max(jnp.abs(want)))
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               atol=2e-5 * scale)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want),
+                               atol=(2e-5 + rounding) * scale)
     for name, a, b in zip("qkvgb", grads, want_g):
+        ulp = rounding if name in "qkv" else 0.0
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b),
+            atol=(5e-5 + ulp) * float(jnp.max(jnp.abs(b))), err_msg=name)
+
+
+def _bodies(args, co, chunk):
+    """The chunk bodies over the sequence as the XLA path runs them, with
+    their float32 results kept: (o, dq, dk, dv, dg, db)."""
+    q, k, v, g, b = args
+    fwd, bwd = (jax.vmap(jax.vmap(f)) for f in (G._chunk_fwd, G._chunk_bwd))
+
+    def forward(h, xs):
+        qc, kc, vc, gc, bc = xs
+        o, h_next = fwd(qc, kc, vc, gc[:, :, None], bc[:, :, None], h)
+        return h_next, (o, h)
+
+    def backward(dh, xs):
+        qc, kc, vc, gc, bc, hc, doc = xs
+        *grads, dh_prev = bwd(qc, kc, vc, gc[:, :, None], bc[:, :, None],
+                              hc, doc, dh)
+        return dh_prev, tuple(grads)
+
+    xs = tuple(G._chunks(x, chunk) for x in args)
+    h0 = jnp.zeros(q.shape[:2] + (q.shape[-1], v.shape[-1]), jnp.float32)
+    _, (o, states) = jax.lax.scan(forward, h0, xs)
+    _, grads = jax.lax.scan(backward, h0, xs + (
+        states, G._chunks(co.astype(v.dtype), chunk)), reverse=True)
+    return tuple(G._unchunks(x) for x in (o,) + grads)
+
+
+def _dots(jaxpr):
+    """Every ``dot_general`` of a jaxpr, through its sub-jaxprs."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _dots(sub)
+
+
+@pytest.mark.parametrize("chunk", [16, 128])
+def test_split_products_are_full_precision_on_bf16_operands(chunk):
+    """The same values fed once as bfloat16 (``_mm`` splits the float32
+    side of 4 products forward and 14 backward) and once upcast to float32
+    (every product at ``HIGHEST``, as the jaxpr shows): the output and
+    all five gradients of the chunk bodies agree to float32 rounding."""
+    args, co = _as_bf16(*_operands(S=256))
+    wide = tuple(x.astype(jnp.float32) for x in args)
+    narrow = _bodies(args, co, chunk)
+    full = _bodies(wide, co, chunk)
+    assert G.split_products(*(x.dtype for x in args[:3])) == {
+        "forward": 4, "backward": 14}
+    assert G.split_products(*(x.dtype for x in wide[:3])) == {
+        "forward": 0, "backward": 0}
+    precisions = {e.params["precision"] for e in _dots(
+        jax.make_jaxpr(lambda *a: _bodies(a[:5], a[5], chunk))(*wide, co))}
+    assert precisions == {(jax.lax.Precision.HIGHEST,) * 2}, precisions
+    for name, a, b in zip(["o", "dq", "dk", "dv", "dg", "db"], narrow, full):
+        assert a.dtype == b.dtype == jnp.float32, name
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=5e-5 * float(jnp.max(jnp.abs(b))),
-                                   err_msg=name)
+                                   atol=1e-6 * float(jnp.max(jnp.abs(b))),
+                                   rtol=0, err_msg=name)
+
+
+@pytest.mark.parametrize("c", [8, 16, 64, 128])
+def test_unit_lower_inverse(c):
+    """``_unit_lower_inverse`` of A as ``_prepare`` builds it from
+    unit-norm keys, beta in (0, 2) and the decays ``_operands`` draws:
+    (I + A) T = I, and T is numpy's inverse in float64 (the half-row merges
+    from block 8 on leave the other rows as they are)."""
+    (_, k, _, g, b), _ = _operands(S=c, seed=3)
+    p = G._prepare(k[0, 0], g[0, 0][None], b[0, 0][None])
+    a = np.where(np.asarray(p["strict"]),
+                 np.asarray(p["b"] * p["kk"] * p["e"], np.float64), 0.0)
+    t = np.asarray(p["tinv"], np.float64)
+    eye = np.eye(c)
+    np.testing.assert_allclose((eye + a) @ t, eye, atol=1e-5, rtol=0)
+    want = np.linalg.inv(eye + a)
+    np.testing.assert_allclose(t, want, atol=1e-5 * np.abs(want).max(),
+                               rtol=0)
 
 
 def test_strong_decay_stays_finite():
@@ -108,22 +208,35 @@ def test_strong_decay_stays_finite():
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("on_tpu", [False, True])
-def test_functional_dispatches_and_pads(on_tpu, monkeypatch):
+def test_functional_dispatches_and_pads(on_tpu, dtype, monkeypatch):
     """``F.gated_delta_rule``: Pallas where ``_on_tpu`` (interpreted here),
     else the XLA path with a reason; a sequence that is no multiple of the
-    chunk is padded and cut back; the record names chunk, heads, dk, dv."""
+    chunk is padded and cut back; the record names chunk, heads, dk, dv
+    and, once the gradient is traced too, the products each body split:
+    none on float32 operands, 4 and 14 on bfloat16 ones."""
     monkeypatch.setattr(la, "_on_tpu", lambda: on_tpu)
-    (q, k, v, g, b), _ = _operands(S=100)
-    out = F.gated_delta_rule(*(Tensor(x) for x in (q, k, v, g, b)),
-                             chunk=32)
+    (q, k, v, g, b), co = _operands(S=100)
+    split, rounding = {"forward": 0, "backward": 0}, 0.0
+    if dtype == "bfloat16":
+        (q, k, v, g, b), co = _as_bf16((q, k, v, g, b), co)
+        split, rounding = {"forward": 4, "backward": 14}, 2.0 ** -8
+    ts = [paddle.to_tensor(np.asarray(x)) for x in (q, k, v, g, b)]
+    for t in ts:
+        t.stop_gradient = False
+    out = F.gated_delta_rule(*ts, chunk=32)
+    (out.astype("float32") * paddle.to_tensor(np.asarray(co))).sum() \
+        .backward()
     rec = F.last_linear_attention_dispatch()
     assert rec == {"backend": "pallas" if on_tpu else "xla",
                    "reason": "ok" if on_tpu else "not on TPU", "chunk": 32,
-                   "heads": 2, "dk": 16, "dv": 32}
+                   "heads": 2, "dk": 16, "dv": 32, "split_products": split}
+    assert ts[0].grad is not None
+    want = _recurrence(*(x.astype(jnp.float32) for x in (q, k, v, g, b)))
     np.testing.assert_allclose(
-        np.asarray(out.value),
-        np.asarray(_recurrence(q, k, v, g, b)), atol=2e-5)
+        np.asarray(out.value, np.float32), np.asarray(want),
+        atol=2e-5 + rounding * float(jnp.max(jnp.abs(want))))
 
 
 def test_rule_refuses_a_chunk_that_is_no_power_of_two():
