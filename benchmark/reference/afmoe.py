@@ -53,9 +53,9 @@ import numpy as np
 
 from benchmark.reference.gpt import HIGHEST, MATMULS, _adamw, _dot, seed_key
 
-__all__ = ["seed_key", "canonical_weights", "init_params", "leaf_norms",
-           "train_readings", "loss_whole", "moe_forward", "sizes",
-           "layer_kinds"]
+__all__ = ["seed_key", "canonical_weights", "draw_leaves", "init_params",
+           "leaf_norms", "train_readings", "loss_whole", "moe_forward",
+           "sizes", "layer_kinds"]
 
 # canonical leaves: name -> (shape in terms of the sizes, kind), by group
 _COMMON = (
@@ -143,23 +143,36 @@ def leaf_shapes(arch: dict) -> dict:
     return out
 
 
-def canonical_weights(arch: dict, key, dtype):
-    """Every leaf from the key, traceable: Normal(0, initializer_range)
-    matrices and unit gains, drawn in float32 and rounded once to
-    ``dtype``. The router keeps its published width; the expert leaves
-    hold the experts held here."""
+def draw_leaves(arch: dict, key, dtype, shapes: dict, kinds: dict) -> dict:
+    """Every leaf from the key, traceable: Normal(0, std) matrices and
+    unit gains, drawn in float32 and rounded once to ``dtype``; std is
+    ``initializer_range``, or the leaf's own entry in ``start_ranges``
+    where the configuration gives one (the start a cell trains from: its
+    file says why). A name there that is no matrix drawn from a normal is
+    refused. The one draw rule of the families whose leaves are matrices
+    and gains alone."""
     std = float(arch["initializer_range"])
-    shapes = leaf_shapes(arch)
-    kinds = {n: k for n, _, k in _TOP + _COMMON + _DENSE + _MOE}
+    own = arch.get("start_ranges", {})
+    drawn = {n for n in shapes if kinds[n] == "normal"}
+    if set(own) - drawn:
+        raise ValueError(f"start_ranges names no leaf drawn from a normal: "
+                         f"{sorted(set(own) - drawn)}")
     out = {}
     for i, name in enumerate(sorted(shapes)):
         if kinds[name] == "normal":
-            v = std * jax.random.normal(jax.random.fold_in(key, i),
-                                        shapes[name], jnp.float32)
+            v = float(own.get(name, std)) * jax.random.normal(
+                jax.random.fold_in(key, i), shapes[name], jnp.float32)
         else:
             v = jnp.ones(shapes[name], jnp.float32)
         out[name] = v.astype(dtype)
     return out
+
+
+def canonical_weights(arch: dict, key, dtype):
+    """Every leaf from the key by ``draw_leaves``. The router keeps its
+    published width; the expert leaves hold the experts held here."""
+    return draw_leaves(arch, key, dtype, leaf_shapes(arch),
+                       {n: k for n, _, k in _TOP + _COMMON + _DENSE + _MOE})
 
 
 def init_params(arch: dict, seed: int, dtype=jnp.bfloat16) -> dict:

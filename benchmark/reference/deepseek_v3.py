@@ -51,8 +51,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmark.reference.afmoe import (_rms, _rope, _swiglu, bias_update,
-                                       head_loss_sum, leaf_norms,
-                                       moe_forward)
+                                       draw_leaves, head_loss_sum,
+                                       leaf_norms, moe_forward)
 from benchmark.reference.gpt import HIGHEST, MATMULS, _adamw, _dot, seed_key
 
 __all__ = ["seed_key", "canonical_weights", "init_params", "leaf_norms",
@@ -135,22 +135,10 @@ def leaf_shapes(arch: dict) -> dict:
 
 
 def canonical_weights(arch: dict, key, dtype):
-    """Every leaf from the key, traceable: Normal(0, initializer_range)
-    matrices and unit gains, drawn in float32 and rounded once to
-    ``dtype``. The router keeps its published width; the expert leaves
-    hold the experts held here."""
-    std = float(arch["initializer_range"])
-    shapes = leaf_shapes(arch)
-    kinds = {n: k for n, _, k in _TOP + _COMMON + _DENSE + _MOE}
-    out = {}
-    for i, name in enumerate(sorted(shapes)):
-        if kinds[name] == "normal":
-            v = std * jax.random.normal(jax.random.fold_in(key, i),
-                                        shapes[name], jnp.float32)
-        else:
-            v = jnp.ones(shapes[name], jnp.float32)
-        out[name] = v.astype(dtype)
-    return out
+    """Every leaf from the key by ``afmoe.draw_leaves``. The router keeps
+    its published width; the expert leaves hold the experts held here."""
+    return draw_leaves(arch, key, dtype, leaf_shapes(arch),
+                       {n: k for n, _, k in _TOP + _COMMON + _DENSE + _MOE})
 
 
 def init_params(arch: dict, seed: int, dtype=jnp.bfloat16) -> dict:

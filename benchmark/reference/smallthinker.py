@@ -57,8 +57,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark.reference.afmoe import (_attention_row, _rms, head_loss_sum,
-                                       leaf_norms)
+from benchmark.reference.afmoe import (_attention_row, _rms, draw_leaves,
+                                       head_loss_sum, leaf_norms)
 from benchmark.reference.gpt import MATMULS, _adamw, _dot, seed_key
 
 __all__ = ["seed_key", "canonical_weights", "init_params", "leaf_norms",
@@ -144,27 +144,11 @@ def leaf_shapes(arch: dict) -> dict:
 
 
 def canonical_weights(arch: dict, key, dtype):
-    """Every leaf from the key, traceable: Normal(0, std) matrices and
-    unit gains, drawn in float32 and rounded once to ``dtype``; std is
-    ``initializer_range``, or the leaf's own entry in ``start_ranges``
-    where the configuration gives one (the start a cell trains from: its
-    file says why). The router keeps its published width; the expert
-    leaves hold the experts held here."""
-    std = float(arch["initializer_range"])
-    own = arch.get("start_ranges", {})
-    shapes = leaf_shapes(arch)
-    if set(own) - set(shapes):
-        raise ValueError(f"start_ranges names no leaf: {set(own) - set(shapes)}")
-    kinds = {n: k for n, _, k in _TOP + _LAYER}
-    out = {}
-    for i, name in enumerate(sorted(shapes)):
-        if kinds[name] == "normal":
-            v = float(own.get(name, std)) * jax.random.normal(
-                jax.random.fold_in(key, i), shapes[name], jnp.float32)
-        else:
-            v = jnp.ones(shapes[name], jnp.float32)
-        out[name] = v.astype(dtype)
-    return out
+    """Every leaf from the key by ``afmoe.draw_leaves`` (``start_ranges``:
+    the start a cell trains from). The router keeps its published width;
+    the expert leaves hold the experts held here."""
+    return draw_leaves(arch, key, dtype, leaf_shapes(arch),
+                       {n: k for n, _, k in _TOP + _LAYER})
 
 
 def init_params(arch: dict, seed: int, dtype=jnp.bfloat16) -> dict:

@@ -399,3 +399,59 @@ def test_the_cells_files_say_what_the_issue_asks():
     shapes = R.leaf_shapes(arch)
     n = sum(int(np.prod(s)) for s in shapes.values())
     assert n == 705_473_792             # 705M: ISSUE 30's arithmetic
+
+
+def test_the_start_draws_each_leaf_at_its_own_range():
+    """``start_ranges``: the leaves it names are drawn at their own std
+    from the same key, every other leaf as without it; a name that is no
+    matrix drawn from a normal is refused; the cell's file names the
+    embedding alone and says why."""
+    arch = config()
+    assert arch["start_ranges"] == {"wte": 0.5}
+    assert "start_ranges" in arch["assumed"]
+    small = dict(TINY, hidden_size=64, vocab_size=512)
+    key = R.seed_key(2**31 + 5)
+    plain = R.canonical_weights(small, key, jnp.float32)
+    own = R.canonical_weights(
+        dict(small, start_ranges={"wte": 0.5, "o_w": 0.01}), key,
+        jnp.float32)
+    assert float(jnp.std(own["wte"])) == pytest.approx(0.5, rel=0.05)
+    np.testing.assert_allclose(own["wte"], plain["wte"] * 5.0, rtol=1e-6)
+    np.testing.assert_allclose(own["o_w"], plain["o_w"] * 0.1, rtol=1e-6)
+    for name in set(plain) - {"wte", "o_w"}:
+        np.testing.assert_array_equal(own[name], plain[name])
+    for bad in ({"wq": 0.1}, {"q_norm_g": 2.0}):
+        with pytest.raises(ValueError):
+            R.canonical_weights(dict(small, start_ranges=bad), key,
+                                jnp.float32)
+
+
+def test_the_start_keeps_the_routers_inputs_apart():
+    """Why the cell's start is not 0.02 throughout: every branch adds unit
+    rms through its post-norm, attention with random q and k averages v
+    over the keys, so most of what it adds is shared by every position,
+    and the routers put the tokens on the same few experts; an embedding
+    well above the branches keeps every layer's counts near even. A small
+    model at the cell's ranges times sqrt(2048 / 128) (the same rms after
+    the embedding's sqrt(H), the same spread of router scores), the
+    forward pass alone."""
+    arch = dict(config(), hidden_size=128, num_attention_heads=4,
+                num_key_value_heads=2, head_dim=32, intermediate_size=384,
+                moe_intermediate_size=64, num_experts=16,
+                num_experts_published=16, expert_offset=0,
+                num_experts_per_tok=2, vocab_size=4096, sliding_window=256,
+                initializer_range=0.08)
+    ids = np.random.default_rng(0).integers(0, 4096, (1, 1024))
+    cfg = R.settings(arch)
+    bias = jnp.zeros((cfg["L"] - cfg["Ld"], cfg["E"]), jnp.float32)
+
+    def counts(start):
+        a = dict(arch, start_ranges=start)
+        _, c = R.loss_whole(R.init_params(a, 2**31 + 3, jnp.float32), bias,
+                            ids, a)
+        return np.asarray(c, np.float64)
+    plain = counts({})
+    start = counts({"wte": 0.5 * 4})
+    assert (plain.max(-1) / plain.mean(-1)).max() > 2.0
+    assert np.all(start.max(-1) / start.mean(-1) < 1.6)
+    assert np.all(start.sum(-1) == 1024 * 2)            # all held here

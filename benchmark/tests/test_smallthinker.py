@@ -269,6 +269,38 @@ def test_the_start_keeps_the_routers_inputs_apart():
     assert np.all(start < 1.6)
 
 
+def test_the_shared_draw_gives_the_leaves_of_the_loop_it_replaced():
+    """``afmoe.draw_leaves``, the one draw rule of the sparse families,
+    draws the cell's start bit for bit as this family's own loop did
+    before it was shared (written out here), at a small size with the
+    cell's ``start_ranges``; and refuses a range for a gain leaf."""
+    small = dict(TINY, hidden_size=64, vocab_size=512,
+                 initializer_range=0.02,
+                 start_ranges=config()["start_ranges"])
+    key = R.seed_key(2**31 + 5)
+    shapes = R.leaf_shapes(small)
+    kinds = {n: k for n, _, k in R._TOP + R._LAYER}
+    for dtype in (jnp.float32, jnp.bfloat16):
+        was = {}
+        for i, name in enumerate(sorted(shapes)):
+            if kinds[name] == "normal":
+                v = float(small["start_ranges"].get(name, 0.02)) \
+                    * jax.random.normal(jax.random.fold_in(key, i),
+                                        shapes[name], jnp.float32)
+            else:
+                v = jnp.ones(shapes[name], jnp.float32)
+            was[name] = v.astype(dtype)
+        now = R.canonical_weights(small, key, dtype)
+        assert set(now) == set(was)
+        for name in was:
+            assert now[name].dtype == was[name].dtype
+            np.testing.assert_array_equal(np.asarray(now[name], np.float32),
+                                          np.asarray(was[name], np.float32))
+    with pytest.raises(ValueError):
+        R.canonical_weights(dict(small, start_ranges={"ln1_g": 0.5}), key,
+                            jnp.float32)
+
+
 def test_published_is_the_catalogs_row():
     if not os.path.exists(CATALOG):
         pytest.skip("no catalog beside the model-configs guide here")

@@ -217,6 +217,7 @@ def test_benchmark_json_lists_the_readers():
         assert m["layer"] == ("expert layer" if name in MOE_READERS
                               else "model step")
         want = sparse if name in MOE_READERS else (
-            ["train-gpt-1.3b"] + sparse if name == "recompute_time_pct"
+            ["train-gpt-1.3b"] + sparse + ["train-olmo-hybrid-8k"]
+            if name == "recompute_time_pct"
             else None)
         assert m.get("workloads") == want
