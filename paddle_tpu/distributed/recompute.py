@@ -19,6 +19,7 @@ import jax
 from ..autograd import tape as _tape
 from ..core.tensor import Tensor
 from ..jit.functional import functional_call, raw_state, _wrap
+from ..kernels.gated_delta import DELTA_RULE_RESIDUAL
 from ..nn.functional.flash_attention import ATTENTION_RESIDUAL
 from ..nn.layer_base import Layer
 from .moe import EXPERTS_RESULT
@@ -36,6 +37,13 @@ __all__ = ["recompute", "recompute_sequential"]
 # pass reads it (moe.EXPERTS_RESULT, one [tokens, d] activation a layer):
 # its conditional runs its path again inside its own backward branch, so
 # a recomputed forward conditional would be a third run.
+# And they keep the delta rule's output and float32 chunk states
+# (gated_delta.DELTA_RULE_RESIDUAL: one [b, h, s, d_v] activation and one
+# f32 [b, h, s / chunk, d_k, d_v] a linear-attention layer, 0.24 GB at
+# 30 heads of 96 x 192 and 8,192 positions), which its backward kernel
+# reads: a few hundred float32 operations a kept byte at a sixth to a
+# third of the MXU's bf16 rate, the forward kernel's whole run (6.3 ms a
+# layer on a v5e at those sizes).
 # "full" keeps the block's input and those, and recomputes everything
 # else; "dots" keeps matmul outputs besides (recomputes only
 # elementwise/norm ops — trades HBM for a ~1/3 cut in recompute FLOPs).
@@ -58,7 +66,8 @@ def resolve_checkpoint_policy(policy):
             f"recompute policy {policy!r} not in {sorted(_POLICIES)} "
             "(or pass a jax.checkpoint_policies callable)") from None
     cp = jax.checkpoint_policies
-    keep = cp.save_only_these_names(ATTENTION_RESIDUAL, EXPERTS_RESULT)
+    keep = cp.save_only_these_names(ATTENTION_RESIDUAL, EXPERTS_RESULT,
+                                    DELTA_RULE_RESIDUAL)
     return cp.save_from_both_policies(getattr(cp, name), keep) if name \
         else keep
 

@@ -34,6 +34,12 @@ Pallas TPU kernel, a grid of (batch, head, chunk) with the state in VMEM
 across the chunk axis (``interpret=True`` runs the same grid off the chip);
 ``gated_delta_xla`` runs it under ``lax.scan`` over the chunks. Both are
 one ``custom_vjp`` whose residuals are the inputs and the chunk states.
+Its forward rule names the output and the chunk states
+``DELTA_RULE_RESIDUAL`` (``jax.ad_checkpoint.checkpoint_name``) on every
+backend: a ``jax.checkpoint`` policy that keeps the name
+(``distributed.recompute``'s "full" and "dots") hands both to the backward
+pass, and a recomputed block never runs the forward pass a second time.
+Outside a checkpoint the name lowers to nothing.
 
 Layout: q, k [B, H, S, dk]; v [B, H, S, dv]; g (log of the decay, <= 0)
 and b [B, H, S] float32; S a multiple of the chunk. The caller puts the
@@ -48,10 +54,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["gated_delta_pallas", "gated_delta_xla"]
+__all__ = ["gated_delta_pallas", "gated_delta_xla", "DELTA_RULE_RESIDUAL"]
+
+# the checkpoint_name of the forward rule's output [B, H, S, dv] and float32
+# chunk states [B, H, S // chunk, dk, dv]; why the named policies keep it:
+# distributed/recompute.py
+DELTA_RULE_RESIDUAL = "delta_rule_residual"
 
 _HIGHEST = lax.Precision.HIGHEST
 _SPLIT = {jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)}
@@ -403,6 +415,7 @@ def _rule_fwd(q, k, v, g, b, chunk, backend):
     else:
         o, states = _pallas_fwd(q, k, v, g, b, chunk,
                                 interpret=backend == "interpret")
+    o, states = (checkpoint_name(x, DELTA_RULE_RESIDUAL) for x in (o, states))
     return o, (q, k, v, g, b, states)
 
 

@@ -17,7 +17,9 @@ decay).
 backward chunk body took the three-pass form (a float32 operand split
 against a bfloat16 q, k, v or output gradient), counted as the bodies were
 traced with operands of the call's dtypes: ``{"forward": 4, "backward":
-14}`` on bfloat16 operands, zeros on float32 ones.
+14}`` on bfloat16 operands, zeros on float32 ones; and ``residual``, the
+name (``DELTA_RULE_RESIDUAL``) under which the forward rule hands its output
+and chunk states to recomputation, on both backends.
 """
 from __future__ import annotations
 
@@ -25,8 +27,8 @@ import jax
 import jax.numpy as jnp
 
 from ...autograd.tape import apply
-from ...kernels.gated_delta import (gated_delta_pallas, gated_delta_xla,
-                                    split_products)
+from ...kernels.gated_delta import (DELTA_RULE_RESIDUAL, gated_delta_pallas,
+                                    gated_delta_xla, split_products)
 
 __all__ = ["gated_delta_rule", "short_convolution", "l2_normalize",
            "last_linear_attention_dispatch"]
@@ -61,7 +63,8 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 128):
     _last_dispatch.clear()
     _last_dispatch.update(backend="pallas" if pallas else "xla",
                           reason="ok" if pallas else "not on TPU",
-                          chunk=chunk, heads=heads, dk=dk, dv=v.shape[-1])
+                          chunk=chunk, heads=heads, dk=dk, dv=v.shape[-1],
+                          residual=DELTA_RULE_RESIDUAL)
 
     def f(q, k, v, g, beta):
         _last_dtypes[:] = [q.dtype, k.dtype, v.dtype]

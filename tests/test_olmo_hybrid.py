@@ -215,7 +215,8 @@ def test_functional_dispatches_and_pads(on_tpu, dtype, monkeypatch):
     else the XLA path with a reason; a sequence that is no multiple of the
     chunk is padded and cut back; the record names chunk, heads, dk, dv
     and, once the gradient is traced too, the products each body split:
-    none on float32 operands, 4 and 14 on bfloat16 ones."""
+    none on float32 operands, 4 and 14 on bfloat16 ones; and the name under
+    which recomputation keeps the rule's output and chunk states."""
     monkeypatch.setattr(la, "_on_tpu", lambda: on_tpu)
     (q, k, v, g, b), co = _operands(S=100)
     split, rounding = {"forward": 0, "backward": 0}, 0.0
@@ -231,7 +232,8 @@ def test_functional_dispatches_and_pads(on_tpu, dtype, monkeypatch):
     rec = F.last_linear_attention_dispatch()
     assert rec == {"backend": "pallas" if on_tpu else "xla",
                    "reason": "ok" if on_tpu else "not on TPU", "chunk": 32,
-                   "heads": 2, "dk": 16, "dv": 32, "split_products": split}
+                   "heads": 2, "dk": 16, "dv": 32, "split_products": split,
+                   "residual": G.DELTA_RULE_RESIDUAL}
     assert ts[0].grad is not None
     want = _recurrence(*(x.astype(jnp.float32) for x in (q, k, v, g, b)))
     np.testing.assert_allclose(
@@ -289,10 +291,11 @@ def _config(**kw):
     return OlmoHybridConfig(**base)
 
 
-def _program(recompute=False, fused_loss_chunk=32, seed=5):
+def _program(recompute=False, fused_loss_chunk=32, seed=5, policy="full"):
     from benchmark.drivers.train_steps_olmo_hybrid import program_layout
     model = OlmoHybridForCausalLM(_config(
-        recompute=recompute, fused_loss_chunk=fused_loss_chunk))
+        recompute=recompute, recompute_policy=policy,
+        fused_loss_chunk=fused_loss_chunk))
     leaves = R.init_params(ARCH, seed, jnp.float32)
     layout = program_layout(R.layer_kinds(ARCH))
     assert set(layout) == {n for n, _ in model.named_parameters()}
@@ -375,6 +378,81 @@ def test_model_two_train_steps_match_the_reference():
     for leaf, want in ref["change_norms"].items():
         np.testing.assert_allclose(change[leaf], want, rtol=2e-3,
                                    err_msg=leaf)
+
+
+def _step_gradient(policy):
+    """A recomputed step's gradient (the model under ``policy``, a name of
+    ``distributed.recompute`` or a ``jax.checkpoint_policies`` callable,
+    with its chunked loss), the parameters to take it at, and the
+    reference's gradients by program name."""
+    from paddle_tpu.jit.functional import functional_call, raw_state
+    model, leaves, layout = _program(recompute=True, policy=policy)
+    params, buffers = raw_state(model)
+    ids = jnp.asarray(np.random.default_rng(0).integers(0, 256, (1, SEQ)))
+    loss_fn = model.make_loss_fn()
+
+    def loss(params):
+        out, _ = functional_call(model, params, buffers, ids, training=True)
+        return loss_fn(tuple(Tensor(x) for x in out), Tensor(ids)).value
+
+    def reference():
+        grads = jax.jit(jax.grad(lambda p: R.loss_whole(p, ids, ARCH)))(
+            leaves)
+        return {prog: grads[leaf] if at is None else grads[leaf][at]
+                for prog, (leaf, at) in layout.items()}
+    return jax.grad(loss), params, reference
+
+
+def _kernels(jaxpr, found=None):
+    """Pallas kernels of a jaxpr by name, through its sub-jaxprs."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = found.get(eqn.params["name"], 0) + 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kernels(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("policy", ["full", "dots"])
+def test_recomputed_step_names_the_delta_rules_residual(policy):
+    """Both named policies see the rule's output and chunk states under
+    ``DELTA_RULE_RESIDUAL`` in the gradient of a recomputed step."""
+    grad, params, _ = _step_gradient(policy)
+    assert G.DELTA_RULE_RESIDUAL in str(jax.make_jaxpr(grad)(params))
+
+
+@pytest.mark.parametrize("policy,forwards",
+                         [("full", 1), ("dots", 1), ("nothing", 2)])
+def test_recomputed_step_runs_the_forward_kernel_once_a_layer(
+        policy, forwards, monkeypatch):
+    """Under the named policies the gradient of a recomputed step holds the
+    forward kernel once for each of the three linear layers (its output
+    and chunk states are kept for the backward kernel), under
+    ``nothing_saveable`` twice; the backward kernel once either way."""
+    monkeypatch.setattr(la, "_on_tpu", lambda: True)
+    if policy == "nothing":
+        policy = jax.checkpoint_policies.nothing_saveable
+    grad, params, _ = _step_gradient(policy)
+    assert _kernels(jax.make_jaxpr(grad)(params).jaxpr) == {
+        "gated_delta_fwd": 3 * forwards, "gated_delta_bwd": 3}
+
+
+@pytest.mark.parametrize("policy", ["full", "dots"])
+@pytest.mark.parametrize("on_tpu", [False, True])
+def test_kept_residual_gives_the_references_gradients(on_tpu, policy,
+                                                      monkeypatch):
+    """With the rule's output and chunk states kept, not recomputed, the
+    step's gradients on both paths and under both named policies are the
+    reference's to the 1e-4 that holds the train steps to it above."""
+    monkeypatch.setattr(la, "_on_tpu", lambda: on_tpu)
+    grad, params, reference = _step_gradient(policy)
+    got, want = jax.jit(grad)(params), reference()
+    assert set(got) == set(want)
+    for n, w in want.items():
+        w = np.asarray(w)
+        np.testing.assert_allclose(np.asarray(got[n]), w, rtol=1e-4,
+                                   atol=1e-4 * np.abs(w).max(), err_msg=n)
 
 
 @pytest.mark.parametrize("fault", ["beta_single", "no_decay", "no_l2norm",
