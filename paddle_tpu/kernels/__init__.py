@@ -14,9 +14,11 @@ from .flash_block import (  # noqa: F401
 from .fused_ce import ce_bwd, ce_fwd, online_lse  # noqa: F401
 from .gated_delta import gated_delta_pallas, gated_delta_xla  # noqa: F401
 from .mega_decode import mega_decode_step  # noqa: F401
+from .short_conv import short_conv_pallas, short_conv_xla  # noqa: F401
 
 __all__ = ["flash_block_attention", "flash_block_attention_bwd",
            "flash_attention_lse", "merge_lse_blocks", "compute_delta",
            "fused_slot_write", "fused_paged_write",
            "ce_fwd", "ce_bwd", "online_lse", "mega_decode_step",
-           "gated_delta_pallas", "gated_delta_xla"]
+           "gated_delta_pallas", "gated_delta_xla", "short_conv_pallas",
+           "short_conv_xla"]

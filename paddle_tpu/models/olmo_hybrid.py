@@ -22,9 +22,10 @@ norm(mlp(h))``, the MLP SwiGLU. Untied head, no biases, no dropout.
   (``rope_theta`` null), through ``head_major_attention``.
 
 Scopes: a linear layer's mixer is ``linear_attn`` with children
-``short_conv``, ``delta_rule`` (the kernel, every pass) and
-``gated_norm``; a full layer's is ``attn``. Layers are unrolled;
-``recompute`` checkpoints each block.
+``short_conv`` (the convolutions with q's and k's L2 norms: on the chip
+the kernels of ``kernels/short_conv.py``, every pass), ``delta_rule`` (the
+kernel, every pass) and ``gated_norm``; a full layer's is ``attn``. Layers
+are unrolled; ``recompute`` checkpoints each block.
 """
 from __future__ import annotations
 
@@ -150,12 +151,11 @@ class GatedDeltaNet(Layer):
 
     def forward(self, x):
         q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
-        with jax.named_scope("short_conv"):
-            q = F.short_convolution(q, self.q_conv)
-            k = F.short_convolution(k, self.k_conv)
+        with jax.named_scope("short_conv"):     # with q's and k's L2 norms
+            q = F.short_convolution(q, self.q_conv, True, self.eps,
+                                    self.dk ** -0.5)
+            k = F.short_convolution(k, self.k_conv, True, self.eps)
             v = F.short_convolution(v, self.v_conv)
-        q = F.l2_normalize(q, self.eps, self.dk ** -0.5)
-        k = F.l2_normalize(k, self.eps)
 
         def gates(a, b, a_log, dt_bias):
             a, b = a.astype(jnp.float32), b.astype(jnp.float32)

@@ -1,6 +1,6 @@
 """Linear-attention functionals: the gated delta rule (Gated DeltaNet,
-arXiv:2412.06464) and the two pieces that feed it, the causal depthwise
-short convolution and the per-head L2 norm.
+arXiv:2412.06464) and what feeds it, the causal depthwise short
+convolution with SiLU and the per-head L2 norm.
 
 ``gated_delta_rule`` takes the kernel's own head-major layout, q and k
 [B, H, S, dk], v [B, H, S, dv], g (log of the decay) and beta [B, H, S],
@@ -20,6 +20,15 @@ traced with operands of the call's dtypes: ``{"forward": 4, "backward":
 14}`` on bfloat16 operands, zeros on float32 ones; and ``residual``, the
 name (``DELTA_RULE_RESIDUAL``) under which the forward rule hands its output
 and chunk states to recomputation, on both backends.
+
+``short_convolution`` takes the projection's head-major [B, H, S, d] and
+runs the causal depthwise convolution, SiLU and, with ``normalize``, the
+per-head L2 norm and a scale as one chain in float32 with one rounding: on
+a TPU the two Pallas kernels of ``kernels/short_conv.py``, elsewhere the
+same chain in ``jax.numpy``. Its record is the record's ``conv``:
+``backend``, ``reason`` and ``tile`` (None on the XLA path) of the last
+call, and ``l2``, each width called -> whether its last call took the L2
+epilogue (q and k 96: True, v 192: False in the Olmo Hybrid mixer).
 """
 from __future__ import annotations
 
@@ -29,12 +38,17 @@ import jax.numpy as jnp
 from ...autograd.tape import apply
 from ...kernels.gated_delta import (DELTA_RULE_RESIDUAL, gated_delta_pallas,
                                     gated_delta_xla, split_products)
+from ...kernels.short_conv import HALO, short_conv_pallas, short_conv_xla
 
-__all__ = ["gated_delta_rule", "short_convolution", "l2_normalize",
+__all__ = ["gated_delta_rule", "short_convolution",
            "last_linear_attention_dispatch"]
 
 _last_dispatch: dict = {}
 _last_dtypes: list = []     # q, k, v of the last call
+_last_conv: dict = {}       # the last short convolution's, and l2 by width
+
+# the short convolution's sequence tile (kernels/short_conv.py)
+CONV_TILE = 1024
 
 
 def last_linear_attention_dispatch() -> dict:
@@ -42,6 +56,8 @@ def last_linear_attention_dispatch() -> dict:
     rec = dict(_last_dispatch)
     if _last_dtypes:
         rec["split_products"] = split_products(*_last_dtypes)
+    if _last_conv:
+        rec["conv"] = dict(_last_conv, l2=dict(_last_conv["l2"]))
     return rec
 
 
@@ -81,31 +97,33 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 128):
     return apply(f, q, k, v, g, beta, _op_name="gated_delta_rule")
 
 
-def short_convolution(x, weight):
+def short_convolution(x, weight, normalize: bool = False,
+                      epsilon: float = 1e-6, scale: float = 1.0):
     """Causal depthwise convolution along the sequence, zero-padded at its
-    start and without bias, then SiLU: x [B, H, S, d] head-major, weight
-    [H * d, K] (channel c = h * d + i, tap j reads position t - K + 1 + j),
-    computed in float32."""
+    start and without bias, then SiLU and, with ``normalize``, ``x *
+    rsqrt(sum(x^2) + epsilon) * scale`` over each head: x [B, H, S, d]
+    head-major, weight [H * d, K] (channel c = h * d + i, tap j reads
+    position t - K + 1 + j), computed in float32 and rounded once to x's
+    dtype. A sequence that is no multiple of the kernel's tile is padded at
+    its end (nothing before a position reads it) and cut back."""
+    _, heads, seq, width = x.shape
+    pallas = _on_tpu()
+    tile = min(CONV_TILE, -(-seq // HALO) * HALO) if pallas else None
+    _last_conv.update(backend="pallas" if pallas else "xla",
+                      reason="ok" if pallas else "not on TPU", tile=tile)
+    _last_conv.setdefault("l2", {})[width] = normalize
+
     def f(xv, w):
-        heads, width = xv.shape[1], xv.shape[3]
         taps = w.shape[-1]
-        w = w.astype(jnp.float32).reshape(heads, width, taps)
-        xf = jnp.pad(xv.astype(jnp.float32),
-                     [(0, 0), (0, 0), (taps - 1, 0), (0, 0)])
-        seq = xv.shape[2]
-        y = sum(xf[:, :, j:j + seq] * w[None, :, None, :, j]
-                for j in range(taps))
-        return jax.nn.silu(y).astype(xv.dtype)
+        w = jnp.swapaxes(w.reshape(heads, width, taps), 1, 2)   # [H, K, d]
+        if not pallas:
+            return short_conv_xla(xv, w, normalize, epsilon, scale)
+        pad = -seq % tile
+        if pad:
+            xv = jnp.pad(xv, [(0, 0), (0, 0), (0, pad), (0, 0)])
+        y = short_conv_pallas(xv, w, tile, normalize, epsilon, scale,
+                              _interpret())
+        return y[:, :, :seq]
 
     return apply(f, x, weight, _op_name="short_convolution")
 
-
-def l2_normalize(x, epsilon: float = 1e-6, scale: float = 1.0):
-    """x * rsqrt(sum(x^2) + epsilon) over the last axis (each head of a
-    head-major array), times ``scale``, in float32."""
-    def f(xv):
-        xf = xv.astype(jnp.float32)
-        n = jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + epsilon)
-        return (xf * (n * scale)).astype(xv.dtype)
-
-    return apply(f, x, _op_name="l2_normalize")

@@ -1,6 +1,7 @@
 """The Olmo Hybrid family (allenai/Olmo-Hybrid-7B) for training: the gated
 delta rule's chunked kernel (``kernels/gated_delta.py``) on both paths of
-``F.gated_delta_rule``, against the recurrence token by token, and
+``F.gated_delta_rule``, against the recurrence token by token; the short
+convolution's kernels (``kernels/short_conv.py``) against its XLA path; and
 ``OlmoHybridForCausalLM`` through ``jit.TrainStep`` against the plain
 float32 reference that the benchmark keeps
 (``benchmark/reference/olmo_hybrid.py``, which imports nothing of
@@ -258,9 +259,68 @@ def test_short_convolution_and_l2_norm():
         np.testing.assert_allclose(np.asarray(y[:, :, t]),
                                    np.asarray(jax.nn.silu(acc)), rtol=1e-5,
                                    atol=1e-6)
-    n = F.l2_normalize(Tensor(x), 1e-6, 0.5).value
-    np.testing.assert_allclose(np.asarray(jnp.linalg.norm(n, axis=-1)), 0.5,
-                               rtol=1e-5)
+    n = F.short_convolution(Tensor(x), Tensor(w), True, 1e-6, 0.5).value
+    np.testing.assert_allclose(np.asarray(n), np.asarray(
+        0.5 * y / jnp.sqrt(jnp.sum(y * y, axis=-1, keepdims=True) + 1e-6)),
+        rtol=1e-5, atol=1e-6)
+
+
+def _conv_chain(x, w, co, normalize, scale, recompute):
+    """``F.short_convolution`` as the mixer calls it (optionally inside a
+    checkpoint under the "full" policy): output, dx and the taps' gradient
+    of a weighted sum of the output."""
+    from paddle_tpu.distributed.recompute import resolve_checkpoint_policy
+
+    def f(x, w):
+        return F.short_convolution(Tensor(x), Tensor(w), normalize, 1e-6,
+                                   scale).value
+    if recompute:
+        f = jax.checkpoint(f, policy=resolve_checkpoint_policy("full"))
+    y, vjp = jax.vjp(f, x, w)
+    return (y,) + vjp(co.astype(y.dtype))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("width,normalize,scale,batch,seq,recompute", [
+    (96, True, 96 ** -0.5, 1, 80, False),   # q: the L2 epilogue and scale
+    (96, True, 1.0, 2, 64, False),          # k
+    (192, False, 1.0, 2, 80, False),        # v
+    (96, True, 96 ** -0.5, 2, 80, True),
+    (192, False, 1.0, 1, 64, True),
+])
+def test_short_convolution_kernels_match_the_xla_path(
+        width, normalize, scale, batch, seq, recompute, dtype, monkeypatch):
+    """The Pallas path (interpreted here, tiles of 32 rows, so that 80
+    positions are padded to three tiles and the rows the taps reach cross
+    tile edges) against the XLA path: the output, dx and the taps'
+    gradient, at the mixer's two widths, with and without the L2 epilogue
+    and q's scale, at batch 1 and 2, and under per-block recomputation.
+    Both compute the chain in float32: they differ by the order of float32
+    sums (1e-6 of each array's largest entry), and on bfloat16 operands by
+    one rounding of the output and of dx (2^-8)."""
+    ks = jax.random.split(jax.random.PRNGKey(width + seq), 3)
+    x = jax.random.normal(ks[0], (batch, 2, seq, width)).astype(dtype)
+    w = jax.random.uniform(ks[1], (2 * width, 4), minval=-0.5, maxval=0.5)
+    co = jax.random.normal(ks[2], (batch, 2, seq, width))
+    monkeypatch.setattr(la, "CONV_TILE", 32)
+    got = {}
+    for on_tpu in (False, True):
+        monkeypatch.setattr(la, "_on_tpu", lambda: on_tpu)
+        monkeypatch.setattr(la, "_last_conv", {})
+        got[on_tpu] = _conv_chain(x, w, co, normalize, scale, recompute)
+        assert F.last_linear_attention_dispatch()["conv"] == {
+            "backend": "pallas" if on_tpu else "xla",
+            "reason": "ok" if on_tpu else "not on TPU",
+            "tile": 32 if on_tpu else None,
+            "l2": {width: normalize}}
+    rounding = 2.0 ** -8 if dtype == "bfloat16" else 0.0
+    for name, a, b, ulp in zip(("y", "dx", "dw"), got[True], got[False],
+                               (rounding, rounding, 0.0)):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        b = np.asarray(b, np.float32)
+        np.testing.assert_allclose(np.asarray(a, np.float32), b,
+                                   atol=(1e-6 + ulp) * np.abs(b).max(),
+                                   err_msg=name)
 
 
 # ----------------------------------------------------------------- model
@@ -324,14 +384,19 @@ def test_defaults_are_the_published_config_and_its_count():
 
 @pytest.mark.parametrize("on_tpu", [False, True])
 def test_logits_match_the_reference(on_tpu, monkeypatch):
-    """Both paths of the delta rule (the kernel interprets here)."""
+    """Both paths of the delta rule and of the short convolution (the
+    kernels interpret here); the record names the convolutions' widths, q's
+    and k's with the L2 epilogue and v's without."""
     monkeypatch.setattr(la, "_on_tpu", lambda: on_tpu)
+    monkeypatch.setattr(la, "_last_conv", {})
     model, leaves, _ = _program()
     model.eval()
     ids = np.random.default_rng(1).integers(0, 256, (2, SEQ))
     logits = model(paddle.to_tensor(ids))
-    assert F.last_linear_attention_dispatch()["backend"] == (
+    rec = F.last_linear_attention_dispatch()
+    assert rec["backend"] == rec["conv"]["backend"] == (
         "pallas" if on_tpu else "xla")
+    assert rec["conv"]["l2"] == {16: True, 32: False}
     cfg = R.settings(ARCH)
 
     @jax.jit
@@ -429,13 +494,17 @@ def test_recomputed_step_runs_the_forward_kernel_once_a_layer(
     """Under the named policies the gradient of a recomputed step holds the
     forward kernel once for each of the three linear layers (its output
     and chunk states are kept for the backward kernel), under
-    ``nothing_saveable`` twice; the backward kernel once either way."""
+    ``nothing_saveable`` twice; the backward kernel once either way. The
+    short convolution's forward kernel runs in the forward and the
+    recomputed pass under every policy (q, k and v of three layers), its
+    backward kernel once a call."""
     monkeypatch.setattr(la, "_on_tpu", lambda: True)
     if policy == "nothing":
         policy = jax.checkpoint_policies.nothing_saveable
     grad, params, _ = _step_gradient(policy)
     assert _kernels(jax.make_jaxpr(grad)(params).jaxpr) == {
-        "gated_delta_fwd": 3 * forwards, "gated_delta_bwd": 3}
+        "gated_delta_fwd": 3 * forwards, "gated_delta_bwd": 3,
+        "short_conv_fwd": 2 * 9, "short_conv_bwd": 9}
 
 
 @pytest.mark.parametrize("policy", ["full", "dots"])

@@ -354,6 +354,36 @@ def _gated_delta(bwd, chunk=128):
             2 if bwd else 1)
 
 
+def _short_conv(width, normalize, bwd):
+    """kernels/short_conv.py at the Olmo Hybrid cell's operands: one row of
+    8,192 positions, 30 heads of 96 (q, k: with the L2 epilogue) or 192 (v:
+    without), at the tile ``F.short_convolution`` uses; the forward kernel
+    alone or both under ``jax.value_and_grad`` (``jax.grad`` alone needs
+    no forward kernel: the backward kernel reads x)."""
+    from paddle_tpu.kernels.short_conv import short_conv_pallas
+    from paddle_tpu.nn.functional.linear_attention import CONV_TILE
+
+    def fwd(x, w):
+        return short_conv_pallas(x, w, CONV_TILE, normalize, 1e-6,
+                                 width ** -0.5)
+
+    def loss(x, w):
+        return fwd(x, w).astype(jnp.float32).sum()
+    return (jax.value_and_grad(loss, argnums=(0, 1)) if bwd else fwd,
+            [_sd((1, 30, 8192, width), BF16), _sd((30, 4, width), F32)],
+            2 if bwd else 1)
+
+
+@pytest.mark.parametrize("width,normalize", [(96, True), (192, False)])
+def test_short_conv_lowers_for_tpu_one_kernel_a_call(width, normalize):
+    """The short convolution's chain lowers for TPU at the cell's widths as
+    ONE kernel forward and one more backward: the L2 epilogue, SiLU and the
+    taps' gradient are inside the kernels."""
+    for bwd, kernels in ((False, 1), (True, 2)):
+        fn, args, _ = _short_conv(width, normalize, bwd)
+        assert _tpu_mlir(fn, *args).count("tpu_custom_call") == kernels
+
+
 def _slot_write(dtype, tail):
     from paddle_tpu.kernels import fused_slot_write
     return (fused_slot_write,
@@ -433,6 +463,10 @@ CHIP_COMPILE_CASES = {
         768, 6, 30720, hidden=2560, gate="relu"),
     "gated_delta_fwd_8k_96_192": lambda: _gated_delta(False),
     "gated_delta_bwd_8k_96_192": lambda: _gated_delta(True),
+    "short_conv_fwd_8k_96_l2": lambda: _short_conv(96, True, False),
+    "short_conv_bwd_8k_96_l2": lambda: _short_conv(96, True, True),
+    "short_conv_fwd_8k_192": lambda: _short_conv(192, False, False),
+    "short_conv_bwd_8k_192": lambda: _short_conv(192, False, True),
     "fused_slot_write_bf16": lambda: _slot_write(BF16, (_H, _D)),
     "fused_slot_write_int8": lambda: _slot_write(I8, (_H, _D)),
     "fused_slot_write_scale": lambda: _slot_write(F32, (_H,)),
@@ -755,6 +789,13 @@ def test_olmo_hybrid_width_step_carries_its_scopes_for_v5e(
                                    for p in paths)
     assert sum("rematted_computation" in p for p in paths) == 0
     assert sum(p.startswith("jit(full_step)/transpose(") for p in paths) == 1
+    # the short convolution of q, k and v: its forward kernel in the
+    # forward and the recomputed pass, its backward kernel once
+    conv = [p for p in _kernel_paths(text) if "short_conv" in p]
+    assert len(conv) == 9 and all("linear_attn/short_conv" in p
+                                  for p in conv)
+    assert sum("rematted_computation" in p for p in conv) == 3
+    assert sum("short_conv_bwd" in p for p in conv) == 3
     from paddle_tpu.analysis import runtime_profile as rp
     every = set(rp.hlo_op_scopes(text).values())
     for child in ("short_conv", "gated_norm"):
@@ -768,8 +809,9 @@ def test_olmo_hybrid_train_step_compiles_for_v5e(one_chip, chip_like_config,
     """train-olmo-hybrid-8k's whole step: 3 linear + 1 full layer at every
     published width, 1 x 8,192 positions, per-block recomputation, chunked
     head/loss, bf16 weights without master: it fits the chip (9.29 G of
-    arguments and 3.74 G of temporaries, 12.13 GiB; 3.49 G before the
-    output and chunk states were kept) and carries the
+    arguments and 4.06 G of temporaries, 12.43 GiB; 3.49 G before the
+    output and chunk states were kept, 3.74 G before the short
+    convolution's kernels) and carries the
     delta-rule kernel twice a linear layer (forward and backward: the
     recomputed block keeps the forward kernel's output and chunk states)
     and the attention kernel forward and backward once."""
